@@ -21,6 +21,7 @@ import signal
 import sys
 
 from .cluster import NodeSpec, parse_node_arg, start_node, start_single
+from .object.codec import data_path_line
 from .s3.credentials import Credentials, global_credentials
 
 
@@ -532,6 +533,7 @@ def main(argv: list[str] | None = None) -> int:
           f"EC:{node.parity}; {info['online_disks']} online / "
           f"{info['offline_disks']} offline drives")
     print(f"S3 endpoint: {node.url}  (access key {creds.access_key})")
+    print(data_path_line(), flush=True)
     return _serve_until_signal(node.shutdown)
 
 

@@ -35,7 +35,7 @@ from .s3.credentials import Credentials
 from .s3.server import S3Server
 from .storage import errors as serr
 from .storage.xl_storage import XLStorage
-from .utils import ellipses, knobs
+from .utils import device, ellipses, knobs
 
 
 @dataclasses.dataclass
@@ -179,6 +179,10 @@ class ClusterNode:
             ns_lock = NSLockMap()
 
         # -- cross-request device batch former + RAM-budgeted admission ----
+        # the data path is decided HERE, once, before the first jit
+        # (and the compile cache placed with it): every later routing
+        # and kernel-flavour choice reads this probe
+        device.probe()
         from .parallel import pipeline as _pipeline
         from .parallel.scheduler import BatchScheduler, requests_budget
         self.scheduler = BatchScheduler()
@@ -330,6 +334,12 @@ class ClusterNode:
             incidents.RECORDER.attach(
                 os.path.join(self.spec.drives[0], ".minio.sys",
                              "incidents"))
+        if device.probe().reason:
+            # a CPU-only host is a supported deployment — it just says
+            # so, once, in the journal as on the boot banner
+            eventlog.emit_once("device.decline", stage="boot",
+                               reason="no-device",
+                               detail=device.probe().reason)
         if knobs.get_bool("MINIO_TPU_SLO"):
             slo.ENGINE.ensure_started()
         incidents.RECORDER.add_provider(

@@ -135,8 +135,16 @@ def for_each_disk_quorum(disks: Sequence[Optional[StorageAPI]],
                          = None
                          ) -> tuple[list, list[Optional[Exception]]]:
     """for_each_disk with quorum-ack semantics: returns once every
-    drive finished OR `quorum` successes are in and the laggards have
-    outlived `stall_s` (measured from fan-out start). Stragglers keep
+    drive finished OR `quorum` successes are in and every laggard has
+    outlived its grace. A laggard is a drive that is slow where its
+    PEERS are not, so the grace is `stall_s` or, when larger,
+    MINIO_TPU_WRITE_STALL_K × the median run time of the tasks of
+    this very fan-out that finished — and it runs from when the
+    laggard's own task STARTED. A fused device batch hands every
+    coalesced stream its shards at the same instant: fan-outs then
+    queue on the drive-io pool and every write runs slow together,
+    which says nothing about any one drive (on the chip the absolute
+    rule left acked 12+4 objects with 12 shards). Stragglers keep
     running on the drive-io pool — the bounded background lane — and
     are reported as serr.StorageStalled so the caller's quorum reduce
     counts them as missed writes (the MRF degraded-write feed).
@@ -154,11 +162,14 @@ def for_each_disk_quorum(disks: Sequence[Optional[StorageAPI]],
     import time as _time
     from concurrent.futures import FIRST_COMPLETED
     from concurrent.futures import wait as _fwait
-    from ..utils import healthtrack, telemetry
+    from ..utils import healthtrack, knobs, telemetry
 
     results: list = [None] * len(disks)
     errs: list[Optional[Exception]] = [None] * len(disks)
     settled = [False] * len(disks)
+    started: list[Optional[float]] = [None] * len(disks)
+    ran: list[Optional[float]] = [None] * len(disks)
+    k_peers = knobs.get_float("MINIO_TPU_WRITE_STALL_K")
     futs: dict = {}
     traced = telemetry.current_span() is not None
     if traced:
@@ -170,24 +181,38 @@ def for_each_disk_quorum(disks: Sequence[Optional[StorageAPI]],
             continue
 
         def run(i=i):
-            return fn(i, disks[i])
+            started[i] = _time.monotonic()
+            try:
+                return fn(i, disks[i])
+            finally:
+                ran[i] = _time.monotonic() - started[i]
 
         fut = _POOL.submit(contextvars.copy_context().run, run) \
             if traced else _POOL.submit(run)
         futs[fut] = i
-    deadline = _time.monotonic() + stall_s
     while futs:
         ok = sum(1 for i in range(len(disks))
                  if settled[i] and errs[i] is None)
-        remaining = deadline - _time.monotonic()
-        if ok >= quorum and remaining <= 0:
-            break
         # below quorum the wait is unbounded — quorum durability is
         # the correctness line; each task is itself bounded by its
         # drive/RPC deadline, so this cannot hang past the slowest
         # drive's own timeout
+        remaining = None
+        if ok >= quorum:
+            now = _time.monotonic()
+            peers = sorted(ran[i] for i in range(len(disks))
+                           if settled[i] and errs[i] is None
+                           and ran[i] is not None)
+            grace = max(stall_s, k_peers * peers[len(peers) // 2]) \
+                if peers else stall_s
+            remaining = max(
+                grace if started[i] is None
+                else started[i] + grace - now
+                for i in futs.values())
+            if remaining <= 0:
+                break
         done, _ = _fwait(set(futs), return_when=FIRST_COMPLETED,
-                         timeout=remaining if ok >= quorum else None)
+                         timeout=remaining)
         for f in done:
             i = futs.pop(f)
             settled[i] = True

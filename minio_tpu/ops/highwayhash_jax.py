@@ -40,6 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..utils import device
+
 _MUL0 = (0xdbe6d5d5fe4cce2f, 0xa4093822299f31d0,
          0x13198a2e03707344, 0x243f6a8885a308d3)
 _MUL1 = (0x3bd39e10cb0ef593, 0xc0acf169b5f18a8c,
@@ -53,19 +55,11 @@ _GROUPS_CPU = 1
 
 
 def _unroll() -> int:
-    try:
-        return _UNROLL_TPU if jax.default_backend() == "tpu" \
-            else _UNROLL_CPU
-    except Exception:
-        return _UNROLL_CPU
+    return _UNROLL_TPU if device.probe().is_tpu else _UNROLL_CPU
 
 
 def _groups() -> int:
-    try:
-        return _GROUPS_TPU if jax.default_backend() == "tpu" \
-            else _GROUPS_CPU
-    except Exception:
-        return _GROUPS_CPU
+    return _GROUPS_TPU if device.probe().is_tpu else _GROUPS_CPU
 
 
 U32 = jnp.uint32

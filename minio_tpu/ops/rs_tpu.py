@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import device
 from . import rs_matrix
 
 
@@ -167,20 +168,7 @@ def recover_missing(shards, present_mask: int, data_shards: int,
     return apply_matrix(np.asarray(r), shards, use_pallas=use_pallas)
 
 
-_DEFAULT_USE_PALLAS: bool | None = None
-
-
 def default_use_pallas() -> bool:
-    """Pallas path on real TPU; XLA path on CPU (tests / virtual mesh)."""
-    global _DEFAULT_USE_PALLAS
-    if _DEFAULT_USE_PALLAS is None:
-        try:
-            _DEFAULT_USE_PALLAS = jax.devices()[0].platform == "tpu"
-        except Exception:
-            _DEFAULT_USE_PALLAS = False
-    return _DEFAULT_USE_PALLAS
-
-
-def set_default_use_pallas(v: bool | None) -> None:
-    global _DEFAULT_USE_PALLAS
-    _DEFAULT_USE_PALLAS = v
+    """Pallas kernel on a real TPU; the XLA matmul elsewhere (Mosaic
+    lowers for TPU only — CPU tests and the virtual mesh ride XLA)."""
+    return device.probe().is_tpu

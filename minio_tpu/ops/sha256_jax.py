@@ -25,6 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..utils import device
+
 U32 = jnp.uint32
 
 _K = np.array([
@@ -66,10 +68,7 @@ def _unrolled() -> bool:
     """Unroll the 112 per-block inner steps on TPU (loop trip overhead
     costs ~70 ms/batch otherwise); keep fori_loops on the CPU backend
     where each unrolled op is real single-core LLVM compile time."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return device.probe().is_tpu
 
 
 def _one_round(abcdefgh, wi, ki):

@@ -25,11 +25,13 @@ Cross-host traffic (remote drives) stays on the gRPC/HTTP data plane
 (storage/), mirroring the reference's DCN split.
 
 Serving integration (VERDICT r4 #1): object/codec.py dispatches its
-fused put/get/heal batches through the `mesh_*` helpers below whenever
-more than one device is visible (real TPU pool, or the virtual CPU mesh
-under MINIO_TPU_MESH=1). Shard-row counts that don't divide the sp axis
+fused put/get/heal batches through the `mesh_*` helpers below when
+MINIO_TPU_MESH=1 asks for it and more than one device is visible (the
+virtual CPU mesh in tests; opt-in on real chips until the route passes
+there — PERF.md, PR 21). Shard-row counts that don't divide the sp axis
 are zero-padded for the digest reshard (pad-row digests are dropped
-before returning), so every erasure geometry rides any mesh shape.
+before returning); byte columns must divide sp exactly, and a batch
+whose S does not is declined with a `device.decline stage=mesh` event.
 """
 
 from __future__ import annotations
@@ -44,18 +46,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                      # jax >= 0.8
-    from jax import shard_map as _shard_map_raw
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_rep)
-except ImportError:                       # older jax: check_rep kwarg
-    from jax.experimental.shard_map import shard_map
-
 from ..ops import rs_matrix, rs_tpu
 from ..models import pipeline
-from ..utils import lockcheck
+from ..utils import device, eventlog, lockcheck
 
 
 def make_mesh(n_devices: int | None = None, devices=None,
@@ -88,11 +81,7 @@ def default_mesh() -> Optional[Mesh]:
     process lifetime, and the jitted step caches key on the mesh."""
     global _DEFAULT_MESH
     if _DEFAULT_MESH is None:
-        try:
-            devs = jax.devices()
-        except Exception:  # noqa: BLE001 — no backend at all
-            devs = []
-        _DEFAULT_MESH = make_mesh(devices=devs) if len(devs) > 1 else False
+        _DEFAULT_MESH = make_mesh() if device.probe().count > 1 else False
     return _DEFAULT_MESH or None
 
 
@@ -148,11 +137,11 @@ def sharded_put_step(mesh: Mesh, k: int, m: int,
             jax.lax.psum(jnp.sum(parity.astype(jnp.int32) & 1), "sp"), "dp")
         return parity, digests, total
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P("dp", None, "sp"),),
         out_specs=(P("dp", None, "sp"), P("dp", "sp", None), P()),
-        check_rep=False)
+        check_vma=False)
     jitted = jax.jit(fn)
 
     def run(data):
@@ -186,11 +175,11 @@ def sharded_get_step(mesh: Mesh, k: int, m: int, present_mask: int,
         digests = _digest_reshard(survivors, k, sp_size, shard_len, algo)
         return out, digests
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P("dp", None, "sp"),),
         out_specs=(P("dp", None, "sp"), P("dp", "sp", None)),
-        check_rep=False)
+        check_vma=False)
     jitted = jax.jit(fn)
 
     def run(survivors):
@@ -228,11 +217,11 @@ def sharded_heal_step(mesh: Mesh, k: int, m: int, present_mask: int,
                                   algo)
         return out, digests
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P("dp", None, "sp"),),
         out_specs=(P("dp", None, "sp"), P("dp", "sp", None)),
-        check_rep=False)
+        check_vma=False)
     jitted = jax.jit(fn)
 
     def run(survivors):
@@ -310,6 +299,8 @@ def _shardable(mesh: Mesh, b: int, s: int) -> Optional[tuple[int, int]]:
     short batches are padded up to dp by the callers."""
     dp, sp = mesh.devices.shape
     if s == 0 or s % sp:
+        eventlog.emit_once("device.decline", stage="mesh",
+                           reason="unshardable")
         return None
     return dp, sp
 

@@ -113,11 +113,12 @@ class ScanEngine:
         """Returns the device-served frame iterator, or raises Decline.
         Everything that could change the response happens BEFORE the
         first frame is yielded, so a decline is always clean."""
-        if not kernels.device_allowed():
+        reason = kernels.decline_reason()
+        if reason:
             # gate BEFORE the decompress/tokenize work: on a host with
             # no device every Select would otherwise pay the full page
             # build only to decline at submit time and re-parse on CPU
-            raise Decline("no-device")
+            raise Decline(reason)
         try:
             q = _sql.parse(req.expression)
         except _sql.SQLError:
@@ -150,8 +151,6 @@ class ScanEngine:
             if out is None:
                 raise Decline("declined")
             return out
-        if not kernels.device_allowed():
-            raise Decline("no-device")
         return kernels.run_batch(pages.plan, pages.arrays)
 
     # -- byte-identical emission -------------------------------------------

@@ -28,9 +28,11 @@ cache sees a handful of shapes, not one per request size.
 
 Env:
   MINIO_TPU_SCAN_DEVICE=on|off|force   "on" (default) rides the device
-      only when a TPU (or forced mesh) is present — the erasure verbs'
-      discipline; "force" runs the kernels on any XLA backend (tests,
-      benches); "off" disables the device path entirely.
+      where it reproduces the evaluator: an XLA backend with real
+      float64 that the erasure verbs route to (a forced mesh); on a TPU
+      it declines up front (`no-f64`, see decline_reason). "force" runs
+      the kernels on any XLA backend (tests, benches); "off" disables
+      the device path entirely.
 """
 
 from __future__ import annotations
@@ -39,9 +41,11 @@ import collections
 import threading
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from ..utils import knobs
+from ..utils import device, knobs
 
 _COMPILE_MU = threading.Lock()
 # (signature, shape) -> jitted fn. Bounded LRU: the signature bakes in
@@ -51,25 +55,30 @@ _KERNELS: collections.OrderedDict = collections.OrderedDict()
 _KERNEL_CACHE_CAP = knobs.get_int("MINIO_TPU_SCAN_KERNEL_CACHE")
 
 
-def device_allowed() -> bool:
-    """Same decline discipline as the erasure verbs: no device, no
-    reason to pay the dispatch seam — unless forced (tests/bench)."""
+def decline_reason() -> str:
+    """Why the scan plane declines up front on this process ("" when it
+    serves) — the erasure verbs' discipline: no device, no reason to
+    pay the dispatch seam, unless forced (tests/bench)."""
     mode = knobs.get_str("MINIO_TPU_SCAN_DEVICE").lower()
     if mode in ("off", "0", "false", "no"):
-        return False
-    try:
-        from jax.experimental import enable_x64  # noqa: F401
-    except Exception:  # noqa: BLE001 — no x64 scope, no exact floats
-        return False
+        return "no-device"
     if mode == "force":
-        return True
+        return ""
+    if device.probe().is_tpu:
+        # the kernels need IEEE float64 to agree with the evaluator
+        # and the TPU has none: XLA emulates f64 there, not exactly —
+        # on a v5e, ulp-equality, `a * 3 <= c` and `b % 7 = 3`
+        # predicates selected different rows than the CPU evaluator
+        # (chip run, PR 21). Keep/fix/delete is ROADMAP A10's call.
+        return "no-f64"
     from ..object.codec import _device_is_tpu, _mesh_active
-    return _device_is_tpu() or _mesh_active() is not None
+    if not _device_is_tpu() and _mesh_active() is None:
+        return "no-device"
+    return ""
 
 
-def _x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
+def device_allowed() -> bool:
+    return not decline_reason()
 
 
 # -- trace-time helpers -----------------------------------------------------
@@ -296,8 +305,6 @@ def _kernel_for(plan, shape: tuple):
         if fn is not None:
             _KERNELS.move_to_end(key)
             return fn
-        import jax
-        import jax.numpy as jnp
         prog = plan.prog
 
         def run(num, ok, null, sb, slen, rowvalid):
@@ -339,7 +346,7 @@ def run_batch(plan, arrays: dict) -> np.ndarray:
         cap *= 2
     padded = _pad_batch(arrays, cap)
     shape = tuple(padded["num"].shape) + (padded["sb"].shape[-1],)
-    with _x64():
+    with jax.enable_x64():
         fn = _kernel_for(plan, shape)
         mask = fn(*[padded[k] for k in _ARRAY_ORDER])
         out = np.asarray(mask)

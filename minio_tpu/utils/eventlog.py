@@ -152,9 +152,11 @@ define("crashpoint.armed", _S, "warn", ("point", "nth"),
        "process)")
 
 _S = "device"
-define("device.decline", _S, "info", ("stage", "reason"),
-       "A device-path dispatch declined to CPU fallback "
-       "(scheduler/scan/SSE)")
+define("device.decline", _S, "info", ("stage", "reason", "detail"),
+       "Work left the device path for the host: no accelerator at boot "
+       "(`reason=no-device`, the backend's own words in `detail`), a "
+       "dispatch that raised (`reason=error`), a shape the mesh cannot "
+       "shard, or a scheduler/scan/SSE decline")
 
 _S = "fsck"
 define("fsck.complete", _S, "info",

@@ -179,9 +179,10 @@ define("MINIO_TPU_DEVICE_MIN_BYTES", "int", 8 << 20,
        "batch bytes below which the codec stays on the host path", _S,
        display="8 MiB")
 define("MINIO_TPU_MESH", "str", "",
-       "`1` forces mesh dispatch on any multi-device backend, `0` "
-       "disables; default meshes only multi-device TPU pools", _S,
-       display="auto")
+       "`1` dispatches the fused batches over a (dp, sp) mesh of every "
+       "visible device; default is one device, whatever the count (the "
+       "mesh route has not passed on real chips — PERF.md)", _S,
+       display="off")
 define("MINIO_TPU_DIRECT_IO", "bool", False,
        "`on` = O_DIRECT shard writes (page-cache bypass; buffered "
        "fallback where the filesystem refuses)", _S)
@@ -324,7 +325,9 @@ define("MINIO_TPU_QUORUM_ACK", "bool", True,
        "again instead of acking at write quorum and abandoning "
        "laggards to the MRF-fed background lane", _S)
 define("MINIO_TPU_WRITE_STALL_K", "float", 4.0,
-       "write-straggler grace = healthy write p95 × this", _S)
+       "write-straggler grace = healthy write p95 × this — or, when "
+       "larger, × the median run time of the same fan-out's finished "
+       "writes (a host where every write runs slow has no laggard)", _S)
 define("MINIO_TPU_WRITE_STALL_FLOOR_S", "float", 0.5,
        "write-straggler grace floor, seconds", _S)
 define("MINIO_TPU_WRITE_STALL_CEIL_S", "float", 10.0,

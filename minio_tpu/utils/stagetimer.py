@@ -1,7 +1,7 @@
 """Per-stage wall-time accounting for the live-server data path.
 
 The reference answers "where does a PUT spend its time" with pprof; this
-build needs the same answer without a profiler attached: bench_e2e.py
+build needs the same answer without a profiler attached: a bench
 enables the collector, the hot path marks stages (auth, hash-reader,
 split, encode, shard write, commit, lock), and the bench prints the
 aggregate breakdown. Disabled (the default) the cost is one dict lookup

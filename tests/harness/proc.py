@@ -89,10 +89,6 @@ class ProcNode:
             "PYTHONPATH": REPO + (os.pathsep + env["PYTHONPATH"]
                                   if env.get("PYTHONPATH") else ""),
             "MINIO_TPU_FSYNC": "on" if self.fsync else "off",
-            # persistent jit cache keeps per-process XLA compiles off
-            # the matrix's wall clock
-            "JAX_COMPILATION_CACHE_DIR": os.path.join(REPO,
-                                                      ".jax_cache"),
         })
         env.pop("MINIO_TPU_CRASHPOINT", None)
         if crashpoint:

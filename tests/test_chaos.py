@@ -603,7 +603,7 @@ def test_chaos_bitrot_on_encrypted_shards(tmp_path, monkeypatch, device):
     from minio_tpu.object import engine as engine_mod
 
     if device:
-        monkeypatch.setattr(codec_mod, "_IS_TPU", True)
+        monkeypatch.setattr(codec_mod, "_device_is_tpu", lambda: True)
         monkeypatch.setattr(codec_mod, "DEVICE_MIN_BYTES", 0)
         monkeypatch.setenv("MINIO_TPU_SSE_DEVICE_MIN_BYTES", "0")
     seed = chaos_seed(7801)

@@ -49,7 +49,8 @@ EVENT_MATRIX = {
     "net.heal": {"peers": "a|b"},
     "registry.fork": {"epoch": 7, "forks": 1},
     "crashpoint.armed": {"point": "put.meta.before_rename", "nth": 1},
-    "device.decline": {"stage": "scheduler", "reason": "no-device"},
+    "device.decline": {"stage": "boot", "reason": "no-device",
+                       "detail": "RuntimeError: no backend"},
     "fsck.complete": {"findings": 1, "repaired": 1, "unrepaired": 0},
     "fsck.unrepaired": {"findings": 1},
     "rebalance.checkpoint": {"pool": 0, "objects": 10},

@@ -168,6 +168,48 @@ def test_stall_mid_put_quorum_ack(tmp_path):
     sets.close()
 
 
+def test_pool_queueing_is_not_a_stalled_drive(monkeypatch):
+    """A fused device batch hands every coalesced stream its shards at
+    once, so shard-write fan-outs QUEUE on the drive-io pool. A task
+    that waited its turn and then ran well inside the grace is a
+    healthy drive, and so is one that ran slow while all its peers
+    did: the grace runs from the task's own start and scales with the
+    fan-out's own median (on the chip the absolute rule left acked
+    12+4 objects with 12 shards under an 8-stream PUT wave)."""
+    from concurrent.futures import ThreadPoolExecutor
+    from minio_tpu.object import metadata as meta
+    from minio_tpu.storage import errors as serr
+
+    task_s, grace_s, n, quorum = 0.4, 0.6, 16, 12
+    pool = ThreadPoolExecutor(max_workers=quorum)
+    monkeypatch.setattr(meta, "_POOL", pool)
+    try:
+        # 12 run at once, 4 wait ~task_s for a worker and finish at
+        # ~2·task_s: past the grace counted from fan-out start, well
+        # inside it counted from their own start
+        _, errs = meta.for_each_disk_quorum(
+            [object()] * n, lambda i, d: time.sleep(task_s), quorum,
+            stall_s=grace_s)
+        assert errs == [None] * n, errs
+        # a saturated host: EVERY write runs slow, the last one a bit
+        # slower — within K x its peers' median it is no laggard
+        _, errs = meta.for_each_disk_quorum(
+            [object()] * quorum,
+            lambda i, d: time.sleep(0.9 if i == 0 else 0.3),
+            quorum - 1, stall_s=0.2)
+        assert errs == [None] * quorum, errs
+        # a drive that outlives the grace where its peers are fast is
+        # still abandoned
+        _, errs = meta.for_each_disk_quorum(
+            [object()] * quorum,
+            lambda i, d: time.sleep(3 * grace_s if i == 0 else 0.01),
+            quorum - 1, stall_s=grace_s)
+        assert isinstance(errs[0], serr.StorageStalled)
+        assert errs[1:] == [None] * (quorum - 1)
+    finally:
+        pool.shutdown(wait=True)
+
+
 def test_stall_mid_multipart_commit(tmp_path):
     """CompleteMultipartUpload's rename fan-out acks at quorum under a
     stalled drive, and the commit converges through MRF."""

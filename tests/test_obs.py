@@ -705,7 +705,7 @@ def test_dispatch_stage_split_and_exposition_lint(monkeypatch):
     from minio_tpu.object import codec as codec_mod
     from minio_tpu.parallel.scheduler import BatchScheduler
 
-    monkeypatch.setattr(codec_mod, "_IS_TPU", True)
+    monkeypatch.setattr(codec_mod, "_device_is_tpu", lambda: True)
     monkeypatch.setattr(codec_mod, "DEVICE_MIN_BYTES", 0)
     hist = telemetry.REGISTRY.histogram(
         "minio_tpu_device_dispatch_seconds")

@@ -35,7 +35,7 @@ PKG = sse.PKG_SIZE
 def device_on(monkeypatch):
     """Run the device route on the CPU JAX backend: the fused programs
     jit and execute identically; only placement differs."""
-    monkeypatch.setattr(codec_mod, "_IS_TPU", True)
+    monkeypatch.setattr(codec_mod, "_device_is_tpu", lambda: True)
     monkeypatch.setattr(codec_mod, "DEVICE_MIN_BYTES", 0)
     monkeypatch.setenv("MINIO_TPU_SSE_DEVICE_MIN_BYTES", "0")
     monkeypatch.setenv("MINIO_TPU_SSE_CIPHER", "chacha20")
@@ -197,7 +197,7 @@ def test_knob_off_disables_device_path(monkeypatch, device_on):
 
 
 def test_deviceless_declines(monkeypatch):
-    monkeypatch.setattr(codec_mod, "_IS_TPU", False)
+    monkeypatch.setattr(codec_mod, "_device_is_tpu", lambda: False)
     monkeypatch.setenv("MINIO_TPU_SSE_DEVICE_MIN_BYTES", "0")
     assert not sse.device_sse_allowed(1 << 20)
 

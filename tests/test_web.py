@@ -313,7 +313,7 @@ def test_web_download_transformed_objects(web_server):
 
         def put(key, ssec_key=None, sse_s3=False, compress=False):
             md = {}
-            reader, size = sse.setup_put_transforms(
+            reader, size, _spec = sse.setup_put_transforms(
                 key_name=key,
                 raw_reader=HashReader(io.BytesIO(payload), len(payload)),
                 raw_size=len(payload), metadata=md, ssec_key=ssec_key,
