@@ -214,188 +214,6 @@ def bench_cpu_baseline() -> tuple[float, dict]:
                  "cpu_encode_only_gibs": round(K * S / dt_enc / 2**30, 3)}
 
 
-def bench_pipeline_ab(streams: int = 32, size: int = 16 << 20,
-                      drives: int = 16, parity: int = 4,
-                      spans_api: str = "", spans_trace_id: str = ""
-                      ) -> dict:
-    """Pipeline on/off A/B on BASELINE config #2 (`streams` concurrent
-    `size`-byte PutObject streams, EC 12+4, 1 MiB blocks) through the
-    engine data path on tmpfs drives. Per mode: aggregate PUT/GET GiB/s,
-    per-stage p50/p99 (stagetimer samples) and the overlap accounting
-    (wall vs sum-of-stages — >1.0x means the stages actually ran
-    concurrently)."""
-    import concurrent.futures as cf
-    import shutil
-    import tempfile
-
-    from minio_tpu.object import codec as codec_mod
-    from minio_tpu.object.sets import ErasureSets
-    from minio_tpu.parallel import pipeline as pl
-    from minio_tpu.utils import stagetimer, telemetry
-
-    # the A/B isolates HOST-path overlap, so the device route is pinned
-    # off. Restored on exit — a leaked 2^60 threshold would silently
-    # CPU-route later device work in this process.
-    was_min_bytes = codec_mod.DEVICE_MIN_BYTES
-    codec_mod.DEVICE_MIN_BYTES = 1 << 60
-    base = "/dev/shm" if os.path.isdir("/dev/shm") else \
-        tempfile.gettempdir()
-    payload = os.urandom(size)
-    was_enabled = pl.ENABLED
-    was_sampling = (telemetry.SPANS.slow_s, telemetry.SPANS.sample)
-    out: dict = {"config": {"streams": streams, "size": size,
-                            "k": drives - parity, "m": parity,
-                            "block": 1 << 20}}
-    try:
-        # keep every bench trace: the per-config snapshot reports the
-        # top-5 slowest span trees for stage-level attribution
-        telemetry.SPANS.configure(sample=1.0)
-        for mode in ("serial", "pipelined"):
-            pl.ENABLED = mode == "pipelined"
-            root = tempfile.mkdtemp(prefix=f"bench_ab_{mode}_", dir=base)
-            sets = ErasureSets.from_drives(
-                [f"{root}/d{i}" for i in range(drives)], 1, drives,
-                parity, block_size=1 << 20, enable_mrf=False)
-            try:
-                sets.make_bucket("bench")
-                sets.put_object("bench", "warm", payload)   # warm path
-                stagetimer.enable()
-                stagetimer.reset()
-                telemetry.SPANS.clear()
-
-                def put_one(i: int, prefix: str = "o",
-                            traced: bool = True) -> None:
-                    if traced:
-                        with telemetry.trace("bench.put", mode=mode,
-                                             stream=i):
-                            sets.put_object("bench", f"{prefix}{i}",
-                                            payload)
-                    else:
-                        sets.put_object("bench", f"{prefix}{i}", payload)
-
-                t0 = time.perf_counter()
-                with cf.ThreadPoolExecutor(max_workers=streams) as ex:
-                    list(ex.map(put_one, range(streams)))
-                put_wall = time.perf_counter() - t0
-                t0 = time.perf_counter()
-
-                def read_back(i: int) -> None:
-                    with telemetry.trace("bench.get", mode=mode,
-                                         stream=i):
-                        _, it = sets.get_object("bench", f"o{i}")
-                        n = sum(len(c) for c in it)
-                        assert n == size, (i, n)
-
-                with cf.ThreadPoolExecutor(max_workers=streams) as ex:
-                    list(ex.map(read_back, range(streams)))
-                get_wall = time.perf_counter() - t0
-
-                # multipart GET A/B (cross-part lookahead probe): one
-                # object of 4 uploaded parts; the pipelined mode should
-                # overlap part N's verify+decode with part N+1's first
-                # group read, which the serial mode cannot
-                mp_parts = 4
-                part_size = max(size // mp_parts, 5 << 20)  # S3 minimum
-                mp_payload = payload[:part_size] \
-                    if len(payload) >= part_size \
-                    else os.urandom(part_size)
-                uid = sets.new_multipart_upload("bench", "mp")
-                etags = []
-                for pn in range(1, mp_parts + 1):
-                    pi = sets.put_object_part(
-                        "bench", "mp", uid, pn, mp_payload, part_size)
-                    etags.append(pi.etag)
-                from minio_tpu.object.multipart import CompletePart
-                sets.complete_multipart_upload(
-                    "bench", "mp", uid,
-                    [CompletePart(i + 1, e)
-                     for i, e in enumerate(etags)])
-                mp_total = mp_parts * part_size
-
-                def read_mp() -> None:
-                    _, it = sets.get_object("bench", "mp")
-                    nread = sum(len(c) for c in it)
-                    assert nread == mp_total, nread
-
-                read_mp()                      # warm
-                t0 = time.perf_counter()
-                mp_rounds = 4
-                for _ in range(mp_rounds):
-                    read_mp()
-                mp_wall = time.perf_counter() - t0
-                stagetimer.disable()
-                total = streams * size
-                out[mode] = {
-                    "put_gib_s": round(total / put_wall / 2**30, 3),
-                    "put_wall_s": round(put_wall, 2),
-                    "get_gib_s": round(total / get_wall / 2**30, 3),
-                    "get_wall_s": round(get_wall, 2),
-                    "mp_get_gib_s": round(
-                        mp_rounds * mp_total / mp_wall / 2**30, 3),
-                    "mp_config": {"parts": mp_parts,
-                                  "part_size": part_size},
-                    "stage_percentiles_ms": stagetimer.percentiles(),
-                    "overlap": stagetimer.overlap_report(),
-                    # the perf trajectory carries stage-level
-                    # attribution: slowest span trees are per-config
-                    # (SPANS.clear() above); the registry counters are
-                    # PROCESS-CUMULATIVE at snapshot time — labelled
-                    # so, since earlier configs/phases contribute
-                    "telemetry": {
-                        "metrics_cumulative": telemetry.REGISTRY
-                        .snapshot("minio_tpu_"),
-                        # --spans-api/--spans-trace-id narrow the dump
-                        # with the /spans endpoint's own filters
-                        "top_spans": telemetry.SPANS.dump(
-                            5, slowest=True, name=spans_api,
-                            trace_id=spans_trace_id),
-                    },
-                }
-                if mode == "pipelined":
-                    # telemetry-on overhead: identical PUT batches with
-                    # and without a root span (span() is a no-op with
-                    # none active). Warm round first, then interleaved
-                    # timed pairs, best-of to shave scheduler noise —
-                    # comparing a cold traced round against a warm
-                    # untraced one would charge the page cache to
-                    # telemetry.
-                    ns = min(streams, 8)
-
-                    def put_round(traced: bool, prefix: str) -> float:
-                        t0 = time.perf_counter()
-                        with cf.ThreadPoolExecutor(
-                                max_workers=ns) as ex:
-                            list(ex.map(
-                                lambda i: put_one(i, prefix=prefix,
-                                                  traced=traced),
-                                range(ns)))
-                        return time.perf_counter() - t0
-
-                    put_round(False, "u")          # warm (untimed)
-                    plain, traced = [], []
-                    for _ in range(2):             # interleaved pairs
-                        plain.append(put_round(False, "u"))
-                        traced.append(put_round(True, "v"))
-                    out["telemetry_overhead_x"] = round(
-                        min(traced) / min(plain), 4)
-            finally:
-                stagetimer.disable()
-                sets.close()
-                shutil.rmtree(root, ignore_errors=True)
-        out["put_speedup_x"] = round(
-            out["pipelined"]["put_gib_s"] / out["serial"]["put_gib_s"], 3)
-        out["get_speedup_x"] = round(
-            out["pipelined"]["get_gib_s"] / out["serial"]["get_gib_s"], 3)
-        out["mp_get_speedup_x"] = round(
-            out["pipelined"]["mp_get_gib_s"]
-            / out["serial"]["mp_get_gib_s"], 3)
-    finally:
-        pl.ENABLED = was_enabled
-        codec_mod.DEVICE_MIN_BYTES = was_min_bytes
-        telemetry.SPANS.configure(*was_sampling)
-    return out
-
-
 def bench_saturation(streams: Sequence[int] = (1, 2, 4, 8, 16, 32),
                      size: int = 16 << 20, drives: int = 16,
                      parity: int = 4, block: int = 1 << 20,
@@ -2876,26 +2694,11 @@ def _http_put(port: int, path: str, body: bytes, signed, creds) -> int:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--ab-pipeline", action="store_true",
-                    help="force the pipeline on/off A/B on config #2 "
-                         "(default on; BENCH_PIPELINE_AB=0 skips it)")
-    ap.add_argument("--ab-only", action="store_true",
-                    help="run ONLY the pipeline A/B (no device access "
-                         "needed)")
     ap.add_argument("--ab-streams", type=int,
                     default=int(os.environ.get("BENCH_AB_STREAMS", "32")))
     ap.add_argument("--ab-size", type=int,
                     default=int(os.environ.get("BENCH_AB_SIZE",
                                                str(16 << 20))))
-    ap.add_argument("--spans", action="store_true",
-                    help="pretty-print the top-5 slowest span trees of "
-                         "each A/B config to stderr")
-    ap.add_argument("--spans-api", default="",
-                    help="with --spans: keep only this API's root "
-                         "spans (the /spans?api= filter)")
-    ap.add_argument("--spans-trace-id", default="",
-                    help="with --spans: keep only this trace id (the "
-                         "/spans?trace_id= filter)")
     ap.add_argument("--ab-rebalance", action="store_true",
                     help="run ONLY the rebalance-throttle A/B "
                          "(foreground PUT p50/p99 with vs without an "
@@ -3278,53 +3081,9 @@ def main() -> int:
         }))
         return 0
 
-    def emit_spans(ab: dict) -> None:
-        if not args.spans or not isinstance(ab, dict):
-            return
-
-        def walk(node, indent=0):
-            attrs = node.get("attrs", {})
-            label = " ".join(f"{k}={v}" for k, v in attrs.items())
-            print(f"{'  ' * indent}{node['name']} "
-                  f"{node['duration_ms']:.2f}ms {label}".rstrip(),
-                  file=sys.stderr)
-            for c in node.get("children", ()):
-                walk(c, indent + 1)
-
-        for mode in ("serial", "pipelined"):
-            trees = (ab.get(mode) or {}).get(
-                "telemetry", {}).get("top_spans") or []
-            print(f"-- {mode}: top {len(trees)} slowest traces --",
-                  file=sys.stderr)
-            for t in trees:
-                walk(t)
-
-    if args.ab_only:
-        ab = bench_pipeline_ab(args.ab_streams, args.ab_size,
-                               spans_api=args.spans_api,
-                               spans_trace_id=args.spans_trace_id)
-        emit_spans(ab)
-        print(json.dumps({
-            "metric": "e2e PutObject pipeline A/B "
-                      "(engine path, config #2)",
-            "value": ab["pipelined"]["put_gib_s"],
-            "unit": "GiB/s",
-            "pipeline_ab": ab,
-        }))
-        return 0
-
     dev_gib, dev_info = bench_device()
     cpu_gib, cpu_info = bench_cpu_baseline()
 
-    # pipeline on/off A/B on config #2, recorded alongside the kernel
-    # metric. A failed phase fails the run. BENCH_PIPELINE_AB=0 skips.
-    ab = None
-    if args.ab_pipeline or os.environ.get(
-            "BENCH_PIPELINE_AB", "1").lower() not in ("0", "false", "no"):
-        ab = bench_pipeline_ab(args.ab_streams, args.ab_size,
-                               spans_api=args.spans_api,
-                               spans_trace_id=args.spans_trace_id)
-        emit_spans(ab)
     out = {
         "metric": "Erasure encode+bitrot GiB/s per chip "
                   "(EC 12+4, 1 MiB block, PutObject)",
@@ -3343,8 +3102,6 @@ def main() -> int:
                 "(GFNI + AVX2 HighwayHash) full reference data path, "
                 "single core",
     }
-    if ab is not None:
-        out["pipeline_ab"] = ab
     print(json.dumps(out))
     return 0
 

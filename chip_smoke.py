@@ -367,7 +367,8 @@ class Pass:
             # damage a settled tree (see _get), and say what settled
             check(self.node.sets.drain_mrf(timeout=DEADLINE_S),
                   "MRF heal queue did not drain")
-            rec["mrf_before_damage"] = self.node.sets.mrf_stats()
+            rec["mrf_before_damage"] = self.mrf_before_damage = \
+                self.node.sets.mrf_stats()
             lose = self.lose(self.k)
             check(any(i < self.k for i in lose), "no data shard chosen")
             for key in list(self.bodies)[:self.n_degraded]:
@@ -409,6 +410,15 @@ class Pass:
             # shares the chip), then hold every removed shard to
             # check_parts + a full bitrot scan.
             before = self.ev.compute_observations()["recover"]
+            # a degraded GET hands its hint to the healer AFTER its last
+            # body byte, so the client can be here before the hint is:
+            # wait (briefly) until every damaged object has been queued,
+            # then drain
+            want = self.mrf_before_damage["queued"] + len(self.removed)
+            deadline = time.monotonic() + 30
+            while self.node.sets.mrf_stats()["queued"] < want \
+                    and time.monotonic() < deadline:
+                time.sleep(0.02)
             check(self.node.sets.drain_mrf(timeout=DEADLINE_S),
                   "MRF heal queue did not drain")
             rec["mrf"] = self.node.sets.mrf_stats()

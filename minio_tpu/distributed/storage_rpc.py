@@ -228,13 +228,13 @@ class StorageRPCServer:
         wrapper that reports when the transport closes it."""
         import time as _time
         parent = telemetry.current_span()
-        t0_wall, t0 = _time.time(), _time.perf_counter()
+        t0_ns = _time.perf_counter_ns()
         stream = self._disk(a).read_file_stream(
             a["volume"], a["path"], int(a["offset"]), int(a["length"]))
         if parent is None:
             return stream
         return _TimedReadStream(stream, parent, a.get("disk", ""),
-                                t0_wall, t0)
+                                t0_ns)
 
     def _renamefile(self, a, b):
         self._disk(a).rename_file(a["src-volume"], a["src-path"],
@@ -274,13 +274,11 @@ class _TimedReadStream:
     the stream after sending the last chunk — a plain `with span():`
     around the open would report ~0 ms and miss the actual I/O."""
 
-    def __init__(self, inner, parent, disk: str, t0_wall: float,
-                 t0: float):
+    def __init__(self, inner, parent, disk: str, t0_ns: int):
         self._inner = inner
         self._parent = parent
         self._disk = disk
-        self._t0_wall = t0_wall
-        self._t0 = t0
+        self._t0_ns = t0_ns
         self._done = False
 
     def read(self, n: int = -1) -> bytes:
@@ -297,7 +295,8 @@ class _TimedReadStream:
                 self._done = True
                 telemetry.attach_span(
                     self._parent, "storage.readfilestream",
-                    self._t0_wall, _time.perf_counter() - self._t0,
+                    self._t0_ns,
+                    (_time.perf_counter_ns() - self._t0_ns) / 1e9,
                     disk=self._disk)
 
 

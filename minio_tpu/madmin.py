@@ -406,6 +406,16 @@ class AdminClient:
             query["trace_id"] = trace_id
         return self._json("GET", "spans", query)
 
+    def spans_record(self, seconds: float) -> dict:
+        """Start the span window recorder: every request's tree is
+        kept whole for `seconds` (fetch with spans_recorded())."""
+        return self._json("GET", "spans", {"record": str(seconds)})
+
+    def spans_recorded(self) -> dict:
+        """The last recorded window ({"recording": true} while it
+        runs): flat `spans`, `roots`, `dropped`, `t_ns`, `cpu_s`."""
+        return self._json("GET", "spans", {"recorded": "1"})
+
     def profiling_start(self, profiler_type: str = "cpu") -> dict:
         """profiler_type: comma list of 'cpu' (cProfile) and 'mem'
         (tracemalloc) — the reference's profilerType=cpu,mem."""

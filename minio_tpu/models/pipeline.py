@@ -111,6 +111,21 @@ def heal_blocks(survivors, present_mask: int, cfg: ECConfig,
 # The flagship jittable step (what __graft_entry__.entry() exposes)
 # ---------------------------------------------------------------------------
 
+# Name scopes of the fused steps: metadata only (the programs' outputs
+# are byte-identical), so a profiler trace groups device time by what
+# the work IS — `rs_matmul` (the GF(2^8) matmul; its Pallas call is
+# named `gf_matmul`), `bitrot_hash`, `pack` (the concatenate / reshape
+# that feeds the hash and unpacks its digests), `cipher` — instead of
+# by XLA's serial numbers (`while.83`), which any edit renumbers.
+
+
+def _rs_matmul(matrix_bits, shards, r: int, k: int):
+    with jax.named_scope("rs_matmul"):
+        return rs_tpu._apply_matrix_impl(
+            jnp.asarray(matrix_bits), shards, r, k,
+            rs_tpu.default_use_pallas())
+
+
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
 def put_step(data: jax.Array, k: int, m: int, shard_len: int = 0,
              key: bytes = b"", algo: str = "highwayhash"
@@ -130,27 +145,22 @@ def put_step(data: jax.Array, k: int, m: int, shard_len: int = 0,
     (minio_tpu/bitrot.py). The caller already holds the data rows, so
     only parity + digests cross back to the host.
     """
-    from ..bitrot import MAGIC_HIGHWAYHASH_KEY
     b, k_, s = data.shape
     assert k_ == k
     shard_len = shard_len or s
     pm = np.asarray(rs_matrix.parity_matrix(k, m))
     m2 = rs_tpu._bit_expand_cached(pm.tobytes(), pm.shape)
-    parity = rs_tpu._apply_matrix_impl(
-        jnp.asarray(m2), data, m, k, rs_tpu.default_use_pallas())
+    parity = _rs_matmul(m2, data, m, k)
 
     # one hash scan over data+parity rows together: splitting into two
     # scans measures slower (the small parity-only scan underfills the
     # vector lanes and doubles loop overhead)
-    rows = jnp.concatenate([data, parity], axis=-2).reshape(b * (k + m), s)
-    if algo == "sha256":
-        from ..ops import sha256_jax
-        digests = sha256_jax._sha256_impl(rows, shard_len)
-    else:
-        from ..ops import highwayhash_jax
-        digests = highwayhash_jax._hh256_impl(
-            rows, shard_len, bytes(key or MAGIC_HIGHWAYHASH_KEY))
-    return parity, digests.reshape(b, k + m, 32)
+    with jax.named_scope("pack"):
+        rows = jnp.concatenate([data, parity],
+                               axis=-2).reshape(b * (k + m), s)
+    digests = _hash_rows(rows, shard_len, key, algo)
+    with jax.named_scope("pack"):
+        return parity, digests.reshape(b, k + m, 32)
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
@@ -183,20 +193,23 @@ def sse_put_step(data: jax.Array, keys: jax.Array, nonces: jax.Array,
     assert k_ == k
     p = nonces.shape[1]
     ct_bytes = p * pkg_bytes
-    ks = chacha20_jax.keystream_u8(keys, nonces, ct_bytes, pkg_bytes)
-    if ct_bytes < k * s:
-        ks = jnp.concatenate(
-            [ks, jnp.zeros((b, k * s - ct_bytes), jnp.uint8)], axis=-1)
-    ct = (jnp.asarray(data, jnp.uint8).reshape(b, k * s)
-          ^ ks).reshape(b, k, s)
+    with jax.named_scope("cipher"):
+        ks = chacha20_jax.keystream_u8(keys, nonces, ct_bytes, pkg_bytes)
+        if ct_bytes < k * s:
+            ks = jnp.concatenate(
+                [ks, jnp.zeros((b, k * s - ct_bytes), jnp.uint8)],
+                axis=-1)
+        ct = (jnp.asarray(data, jnp.uint8).reshape(b, k * s)
+              ^ ks).reshape(b, k, s)
     pm = np.asarray(rs_matrix.parity_matrix(k, m))
     m2 = rs_tpu._bit_expand_cached(pm.tobytes(), pm.shape)
-    parity = rs_tpu._apply_matrix_impl(
-        jnp.asarray(m2), ct, m, k, rs_tpu.default_use_pallas())
-    rows = jnp.concatenate([ct, parity], axis=-2)
-    digests = _hash_rows(rows.reshape(b * (k + m), s),
-                         shard_len or s, key, algo)
-    return rows, digests.reshape(b, k + m, 32)
+    parity = _rs_matmul(m2, ct, m, k)
+    with jax.named_scope("pack"):
+        rows = jnp.concatenate([ct, parity], axis=-2)
+        flat = rows.reshape(b * (k + m), s)
+    digests = _hash_rows(flat, shard_len or s, key, algo)
+    with jax.named_scope("pack"):
+        return rows, digests.reshape(b, k + m, 32)
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10))
@@ -228,15 +241,18 @@ def sse_get_step(survivors: jax.Array, matrix_bits: jax.Array,
     out, digests = _reconstruct_and_hash(
         survivors, matrix_bits, r, k, shard_len, key, algo)
     kd = len(data_src)
-    stacked = jnp.stack(
-        [survivors[:, i] if src == 0 else out[:, i]
-         for src, i in data_src], axis=1)
+    with jax.named_scope("pack"):
+        stacked = jnp.stack(
+            [survivors[:, i] if src == 0 else out[:, i]
+             for src, i in data_src], axis=1)
     ct_bytes = nonces.shape[1] * pkg_bytes
-    ks = chacha20_jax.keystream_u8(keys, nonces, ct_bytes, pkg_bytes)
-    if ct_bytes < kd * s:
-        ks = jnp.concatenate(
-            [ks, jnp.zeros((b, kd * s - ct_bytes), jnp.uint8)], axis=-1)
-    plain = (stacked.reshape(b, kd * s) ^ ks).reshape(b, kd, s)
+    with jax.named_scope("cipher"):
+        ks = chacha20_jax.keystream_u8(keys, nonces, ct_bytes, pkg_bytes)
+        if ct_bytes < kd * s:
+            ks = jnp.concatenate(
+                [ks, jnp.zeros((b, kd * s - ct_bytes), jnp.uint8)],
+                axis=-1)
+        plain = (stacked.reshape(b, kd * s) ^ ks).reshape(b, kd, s)
     return plain, out, digests[:, :k]
 
 
@@ -245,12 +261,13 @@ def _hash_rows(rows: jax.Array, shard_len: int, key: bytes,
     """(N, S) rows -> (N, 32) bitrot digests over the first shard_len
     bytes, on device (shared by put/get/heal steps)."""
     from ..bitrot import MAGIC_HIGHWAYHASH_KEY
-    if algo == "sha256":
-        from ..ops import sha256_jax
-        return sha256_jax._sha256_impl(rows, shard_len)
-    from ..ops import highwayhash_jax
-    return highwayhash_jax._hh256_impl(
-        rows, shard_len, bytes(key or MAGIC_HIGHWAYHASH_KEY))
+    with jax.named_scope("bitrot_hash"):
+        if algo == "sha256":
+            from ..ops import sha256_jax
+            return sha256_jax._sha256_impl(rows, shard_len)
+        from ..ops import highwayhash_jax
+        return highwayhash_jax._hh256_impl(
+            rows, shard_len, bytes(key or MAGIC_HIGHWAYHASH_KEY))
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
@@ -291,14 +308,13 @@ def _reconstruct_and_hash(survivors, matrix_bits, r, k, shard_len,
     b, k_, s = survivors.shape
     assert k_ == k
     shard_len = shard_len or s
-    from ..ops import rs_tpu
-    out = rs_tpu._apply_matrix_impl(
-        matrix_bits, survivors, r, k, rs_tpu.default_use_pallas())
-    rows = jnp.concatenate([survivors, out],
-                           axis=-2).reshape(b * (k + r), s)
-    digests = _hash_rows(rows, shard_len, key, algo).reshape(
-        b, k + r, 32)
-    return out, digests
+    out = _rs_matmul(matrix_bits, survivors, r, k)
+    with jax.named_scope("pack"):
+        rows = jnp.concatenate([survivors, out],
+                               axis=-2).reshape(b * (k + r), s)
+    digests = _hash_rows(rows, shard_len, key, algo)
+    with jax.named_scope("pack"):
+        return out, digests.reshape(b, k + r, 32)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))

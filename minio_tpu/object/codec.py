@@ -176,16 +176,43 @@ class Codec:
         return None
 
     @staticmethod
-    def _staged(stage_cb, outputs):
-        """Compute/fetch boundary for the single-device jit path: wait
-        for the device values (compute), stamp the stage, and let the
-        caller's numpy conversions (fetch = device→host readback) run
-        after. No-op without a callback — the hot path pays nothing."""
+    def _upload(stage_cb, *arrays):
+        """Stage "h2d" of the single-device jit path: put the fused
+        input on the device and wait for it, so the upload is timed
+        apart from the program (one extra host wake-up per launch; the
+        two were serial already). Without a callback the arrays go to
+        the step as they are — the hot path pays nothing."""
+        if stage_cb is None:
+            return arrays
         import time as _time
+        import jax
+        t0 = _time.perf_counter()
+        on_device = jax.block_until_ready(
+            tuple(jax.device_put(a) for a in arrays))
+        stage_cb("h2d", _time.perf_counter() - t0)
+        return on_device
+
+    @staticmethod
+    def _staged(stage_cb, t0: float, outputs) -> float:
+        """Compute/fetch boundary for the single-device jit path: wait
+        for the device values and report "compute" (launch + program +
+        sync, from `t0`); the caller's numpy conversions (fetch =
+        device→host readback) run after and end in `_fetched`. No-op
+        without a callback."""
+        import time as _time
+        if stage_cb is None:
+            return 0.0
+        import jax
+        jax.block_until_ready(outputs)
+        t1 = _time.perf_counter()
+        stage_cb("compute", t1 - t0)
+        return t1
+
+    @staticmethod
+    def _fetched(stage_cb, t1: float) -> None:
         if stage_cb is not None:
-            import jax
-            jax.block_until_ready(outputs)
-        return _time.perf_counter()
+            import time as _time
+            stage_cb("fetch", _time.perf_counter() - t1)
 
     def encode_and_hash_batch(self, data: np.ndarray, algo,
                               *, force: str = "", stage_cb=None):
@@ -198,9 +225,10 @@ class Codec:
         as numpy arrays, or None when the batch doesn't route to the
         device or the bitrot algorithm has no device kernel.
 
-        stage_cb(stage, seconds), when given, receives "compute" (device
-        program to completion) and "fetch" (device→host readback +
-        result assembly) timings — the batch scheduler's dispatch
+        stage_cb(stage, seconds), when given, is called as each stage
+        ENDS: "h2d" (the fused input's upload, waited for), "compute"
+        (launch + device program + sync) and "fetch" (device→host
+        readback + result assembly) — the batch scheduler's dispatch
         attribution. The mesh path reports a single "compute" stage (its
         sharded programs return host arrays in one step).
         """
@@ -222,17 +250,16 @@ class Codec:
         if path != "device":
             return None
         from ..models.pipeline import put_step
+        (dev,) = self._upload(stage_cb, data)
         t0 = _time.perf_counter()
-        parity, digests = put_step(data, self.k, self.m, algo=kernel)
-        t1 = self._staged(stage_cb, (parity, digests))
+        parity, digests = put_step(dev, self.k, self.m, algo=kernel)
+        t1 = self._staged(stage_cb, t0, (parity, digests))
         # only parity + digests cross back from the device; the k data
         # rows are the caller's own bytes
         out = (np.concatenate([np.asarray(data, np.uint8),
                                np.asarray(parity)], axis=1),
                np.asarray(digests))
-        if stage_cb is not None:
-            stage_cb("compute", t1 - t0)
-            stage_cb("fetch", _time.perf_counter() - t1)
+        self._fetched(stage_cb, t1)
         return out
 
     def encrypt_encode_and_hash_batch(self, data: np.ndarray, keys,
@@ -261,16 +288,15 @@ class Codec:
         if path != "device":
             return None
         from ..models.pipeline import sse_put_step
+        dev, dkeys, dnonces = self._upload(stage_cb, data, keys, nonces)
         t0 = _time.perf_counter()
-        full, digests = sse_put_step(data, keys, nonces, self.k,
+        full, digests = sse_put_step(dev, dkeys, dnonces, self.k,
                                      self.m, pkg_bytes, algo=kernel)
-        t1 = self._staged(stage_cb, (full, digests))
+        t1 = self._staged(stage_cb, t0, (full, digests))
         # the data rows DO cross back here: the caller staged plaintext
         # and must write (and Poly1305-tag) the ciphertext
         out = np.asarray(full), np.asarray(digests)
-        if stage_cb is not None:
-            stage_cb("compute", t1 - t0)
-            stage_cb("fetch", _time.perf_counter() - t1)
+        self._fetched(stage_cb, t1)
         return out
 
     def verify_decode_decrypt_batch(self, survivors: np.ndarray,
@@ -308,15 +334,15 @@ class Codec:
             for j in range(self.k))
         m2 = rs_tpu._bit_expand_cached(dm.tobytes(), dm.shape)
         from ..models.pipeline import sse_get_step
+        dev, dkeys, dnonces = self._upload(stage_cb, survivors, keys,
+                                           nonces)
         t0 = _time.perf_counter()
         plain, _ct_missing, digests = sse_get_step(
-            survivors, m2, keys, nonces, dm.shape[0], self.k,
+            dev, m2, dkeys, dnonces, dm.shape[0], self.k,
             data_src, pkg_bytes, shard_len, algo=kernel)
-        t1 = self._staged(stage_cb, (plain, digests))
+        t1 = self._staged(stage_cb, t0, (plain, digests))
         result = np.asarray(plain), missing, np.asarray(digests)
-        if stage_cb is not None:
-            stage_cb("compute", t1 - t0)
-            stage_cb("fetch", _time.perf_counter() - t1)
+        self._fetched(stage_cb, t1)
         return result
 
     # -- fused verify + decode / recover (device) --------------------------
@@ -359,14 +385,13 @@ class Codec:
             return None
         m2 = rs_tpu._bit_expand_cached(dm.tobytes(), dm.shape)
         from ..models.pipeline import get_step
+        (dev,) = self._upload(stage_cb, survivors)
         t0 = _time.perf_counter()
-        out, digests = get_step(survivors, m2, dm.shape[0], self.k,
+        out, digests = get_step(dev, m2, dm.shape[0], self.k,
                                 shard_len, algo=kernel)
-        t1 = self._staged(stage_cb, (out, digests))
+        t1 = self._staged(stage_cb, t0, (out, digests))
         result = np.asarray(out), missing, np.asarray(digests)
-        if stage_cb is not None:
-            stage_cb("compute", t1 - t0)
-            stage_cb("fetch", _time.perf_counter() - t1)
+        self._fetched(stage_cb, t1)
         return result
 
     def verify_and_recover_batch(self, survivors: np.ndarray,
@@ -403,15 +428,14 @@ class Codec:
             return None
         m2 = rs_tpu._bit_expand_cached(rec.tobytes(), rec.shape)
         from ..models.pipeline import heal_step
+        (dev,) = self._upload(stage_cb, survivors)
         t0 = _time.perf_counter()
-        out, sdig, odig = heal_step(survivors, m2, rec.shape[0], self.k,
+        out, sdig, odig = heal_step(dev, m2, rec.shape[0], self.k,
                                     shard_len, algo=kernel)
-        t1 = self._staged(stage_cb, (out, sdig, odig))
+        t1 = self._staged(stage_cb, t0, (out, sdig, odig))
         result = (np.asarray(out), idxs, np.asarray(sdig),
                   np.asarray(odig))
-        if stage_cb is not None:
-            stage_cb("compute", t1 - t0)
-            stage_cb("fetch", _time.perf_counter() - t1)
+        self._fetched(stage_cb, t1)
         return result
 
     def _recover_rows(self, present_mask: int, rows: "set[int]"

@@ -31,7 +31,7 @@ import numpy as np
 
 from .. import bitrot as bitrot_mod
 from ..storage import errors as serr
-from ..utils import crashpoint, healthtrack, knobs, stagetimer, telemetry
+from ..utils import crashpoint, healthtrack, knobs, telemetry
 from ..storage.api import StorageAPI
 from ..storage.datatypes import (BLOCK_SIZE_V1, RESTORE_EXPIRY_KEY,
                                  RESTORE_KEY, TRANSITION_COMPLETE,
@@ -324,7 +324,8 @@ class ErasureObjects:
                                             write_quorum, bucket,
                                             object_name,
                                             sse=opts.sse_spec)
-                with stagetimer.stage("put.hash_verify"):
+                # the async hasher drains here: a wait on MD5/SHA-256
+                with telemetry.span("put.hash_verify"):
                     reader.verify()
             finally:
                 reader.close()  # stop the async hasher even on failure
@@ -341,13 +342,12 @@ class ErasureObjects:
                 ChecksumInfo(1, self.bitrot_algo.value, b"")]
 
             # per-drive metadata then commit (2-phase: tmp -> final)
-            with stagetimer.stage("put.lock+commit"):
-                with self.ns.new_lock(
-                        f"{bucket}/{object_name}").write_locked():
-                    if opts.if_none_newer:
-                        self._check_none_newer(bucket, object_name, fi)
-                    lost = self._commit(shuffled, writers, tmp_id, fi,
-                                        bucket, object_name, write_quorum)
+            with telemetry.span("put.commit"), self.ns.new_lock(
+                    f"{bucket}/{object_name}").write_locked():
+                if opts.if_none_newer:
+                    self._check_none_newer(bucket, object_name, fi)
+                lost = self._commit(shuffled, writers, tmp_id, fi,
+                                    bucket, object_name, write_quorum)
         except Exception:
             self._cleanup_tmp(shuffled, tmp_id)
             raise
@@ -454,20 +454,18 @@ class ErasureObjects:
                 pool.put(buf)
 
         def encode_stage(item):
-            t0 = time.perf_counter()
             if item.get("sse_finish"):
                 # stream end under SSE: encrypt the short tail (if any)
                 # host-side, close the Poly1305 trailer, and re-chunk
                 # ct_tail‖trailer into block-size erasure batches. Runs
                 # on this FIFO stage so every prior batch has absorbed.
-                with stagetimer.stage("put.encode+digest"):
+                with telemetry.timed("put.sse_finish") as t:
                     item["rows_multi"] = self._sse_finish_rows(
                         codec, sse, item["tail"], item["sse_off"])
-                stage_s[1] += time.perf_counter() - t0
+                stage_s[1] += t.seconds
                 return item
-            with stagetimer.stage("put.encode+digest"), \
-                    telemetry.span("pipeline.encode",
-                                   blocks=item["data"].shape[0]):
+            with telemetry.timed("pipeline.encode",
+                                 blocks=item["data"].shape[0]) as t:
                 fut, data = item["fut"], item["data"]
                 if sse is not None:
                     item["rows"] = self._sse_encode(codec, data, item,
@@ -477,14 +475,13 @@ class ErasureObjects:
                     fused = fut.result() if fut is not None else \
                         codec.encode_and_hash_batch(data, self.bitrot_algo)
                     item["rows"] = self._unpack_fused(codec, data, fused)
-            stage_s[1] += time.perf_counter() - t0
+            stage_s[1] += t.seconds
             return item
 
         def write_stage(item):
-            t0 = time.perf_counter()
+            t = telemetry.timed("pipeline.shard_write")
             try:
-                with stagetimer.stage("put.shard_write"), \
-                        telemetry.span("pipeline.shard_write"):
+                with t:
                     for rows, parity, dd, dp in (
                             item["rows_multi"] if "rows_multi" in item
                             else [item["rows"]]):
@@ -492,7 +489,7 @@ class ErasureObjects:
                                                  writers, write_quorum)
             finally:
                 recycle(item)
-                stage_s[2] += time.perf_counter() - t0
+                stage_s[2] += t.seconds
 
         pipe = None
 
@@ -505,6 +502,7 @@ class ErasureObjects:
             again (a double pool.put would hand one bytearray to two
             later streams)."""
             nonlocal batches, buf, pipe, enc_off
+            reads.flush(blocks=int(data.shape[0]))
             if pipe is None:
                 pipe = pl.StagePipeline([encode_stage, write_stage],
                                         depth=pl.DEPTH, name="put-pipe",
@@ -531,9 +529,11 @@ class ErasureObjects:
             batches += 1
 
         def acquire():
-            t0 = time.perf_counter()
-            b = pool.get(timeout=pl.POOL_TIMEOUT_S)
-            stage_s[0] += time.perf_counter() - t0
+            # back-pressure: the ring is empty while the encode and
+            # write stages still hold every buffer
+            with telemetry.timed("put.buffer_wait") as t:
+                b = pool.get(timeout=pl.POOL_TIMEOUT_S)
+            stage_s[0] += t.seconds
             a = np.frombuffer(b, dtype=np.uint8).reshape(cap, k * s_len)
             if k * s_len > bs:
                 # pooled reuse: the pad tail must READ as zeros for
@@ -546,14 +546,19 @@ class ErasureObjects:
         buf = None
         enc_off = 0       # plaintext stream offset of the next sse batch
         tail_pt = b""     # short last block (plaintext) under sse
+        # pulling the body through the hash reader into the ring: one
+        # span per group of blocks (busy time + call count), not one
+        # per block
+        reads = telemetry.accum("put.read_stream")
         try:
             buf, arr = acquire()
             nb = 0
             while True:
-                t0 = time.perf_counter()
-                with stagetimer.stage("put.read_stream"):
-                    n = _read_full_into(reader, arr[nb][:bs])
-                stage_s[0] += time.perf_counter() - t0
+                t0 = time.perf_counter_ns()
+                n = _read_full_into(reader, arr[nb][:bs])
+                t1 = time.perf_counter_ns()
+                stage_s[0] += (t1 - t0) / 1e9
+                reads.add(t0, t1)
                 if n == 0:
                     break
                 total += n
@@ -581,11 +586,12 @@ class ErasureObjects:
                     # short block alone (split copies it out of the
                     # ring; whichever item takes the buffer recycles
                     # it)
-                    with stagetimer.stage("put.split"):
+                    with telemetry.span("put.split"):
                         data = codec.split(arr[nb][:n])[None, ...]
                     if pipe is None:
                         # unknown-length stream that fit one batch:
                         # encode+write inline — no stage threads
+                        reads.flush(blocks=nb + 1)
                         if nb:
                             self._encode_write(
                                 codec, arr[:nb].reshape(nb, k, s_len),
@@ -598,6 +604,7 @@ class ErasureObjects:
                         feed(data)
                     nb = 0
                     break
+            reads.flush(blocks=nb)
             if nb:
                 if pipe is None:
                     self._encode_write(codec,
@@ -628,7 +635,6 @@ class ErasureObjects:
         if pipe is not None:
             wall = time.perf_counter() - t_start
             pl.STATS.record_put(wall, sum(stage_s), batches)
-            stagetimer.add_overlap("put.pipeline", wall, sum(stage_s))
         if sse is not None:
             from ..features.crypto import encrypted_size
             return encrypted_size(total)   # ciphertext + tag trailer
@@ -655,9 +661,11 @@ class ErasureObjects:
         nb = 0
         enc_off = 0
         tail_pt = b""
+        reads = telemetry.accum("put.read_stream")
 
         def flush_full(n_rows: int) -> None:
             nonlocal enc_off
+            reads.flush(blocks=n_rows)
             if n_rows:
                 self._encode_write(codec,
                                    buf[:n_rows].reshape(n_rows, k, s_len),
@@ -667,8 +675,9 @@ class ErasureObjects:
 
         while True:
             row = buf[nb]
-            with stagetimer.stage("put.read_stream"):
-                n = _read_full_into(reader, row[:bs])
+            t0 = time.perf_counter_ns()
+            n = _read_full_into(reader, row[:bs])
+            reads.add(t0, time.perf_counter_ns())
             if n == 0:
                 break
             total += n
@@ -687,7 +696,7 @@ class ErasureObjects:
                 # the pending full rows first, then it alone
                 flush_full(nb)
                 nb = 0
-                with stagetimer.stage("put.split"):
+                with telemetry.span("put.split"):
                     data = codec.split(row[:n])[None, ...]
                 self._encode_write(codec, data, writers, write_quorum)
                 break
@@ -732,8 +741,7 @@ class ErasureObjects:
         With `sse`, the batch rows are PLAINTEXT full blocks starting
         at stream offset `sse_off` and the cipher fuses in (or falls
         back to the in-place CPU cipher)."""
-        with stagetimer.stage("put.encode+digest"), \
-                telemetry.span("pipeline.encode", blocks=data.shape[0]):
+        with telemetry.span("pipeline.encode", blocks=data.shape[0]):
             if sse is not None:
                 item = {"sse_kn": sse.batch_params(
                     sse_off, data.shape[0], self.block_size),
@@ -757,8 +765,7 @@ class ErasureObjects:
                                                         self.bitrot_algo)
                 data_rows, parity, dd, dp = self._unpack_fused(
                     codec, data, fused)
-        with stagetimer.stage("put.shard_write"), \
-                telemetry.span("pipeline.shard_write"):
+        with telemetry.span("pipeline.shard_write"):
             self._write_shards_batch(data_rows, parity, dd, dp, writers,
                                      write_quorum)
 
@@ -830,13 +837,12 @@ class ErasureObjects:
         def write(i: int, w) -> None:
             rows, digs, j = (data, dd, i) if i < k else \
                 (parity, dp, i - k)
-            t0 = time.perf_counter()
-            with telemetry.span("disk.shard_write", disk=i, blocks=B):
+            with telemetry.timed("disk.shard_write", disk=i,
+                                 blocks=B) as t:
                 for bi in range(B):
                     w.write_with_digest(rows[bi, j].data,
                                         digs[bi, j].data)
-            healthtrack.observe_disk(w.disk, "write",
-                                     time.perf_counter() - t0)
+            healthtrack.observe_disk(w.disk, "write", t.seconds)
 
         # quorum-ack lane: once write-quorum writers are durable, a
         # laggard past the stall grace is dropped from the fan-out
@@ -870,7 +876,7 @@ class ErasureObjects:
         # once quorum is durable — it is counted into `lost` below and
         # the object converges back through MRF
         stall = healthtrack.write_stall_s()
-        with stagetimer.stage("put.commit.close_writers"):
+        with telemetry.span("put.close_writers"):
             _, errs = meta.for_each_disk_quorum(shuffled, close_writer,
                                                 write_quorum,
                                                 stall_s=stall,
@@ -892,7 +898,7 @@ class ErasureObjects:
         # a crash here must leave the previous version untouched and
         # only tmp garbage for fsck to reclaim
         crashpoint.hit("put.shards.before_meta")
-        with stagetimer.stage("put.commit.write_meta"):
+        with telemetry.span("put.write_meta"):
             meta.write_unique_file_info(disks_for_meta,
                                         MINIO_META_TMP_BUCKET,
                                         tmp_id, metas, write_quorum,
@@ -915,7 +921,7 @@ class ErasureObjects:
             # so the drive is healed against current quorum state
             self._notify_degraded(bucket, object_name, fi.version_id)
 
-        with stagetimer.stage("put.commit.rename"):
+        with telemetry.span("put.rename"):
             _, errs = meta.for_each_disk_quorum(disks_for_meta, rename,
                                                 write_quorum,
                                                 stall_s=stall,
@@ -1169,11 +1175,18 @@ class ErasureObjects:
         bitrot) and reconstructed on the fly when shards are missing."""
         opts = opts or GetOptions()
         lock = self.ns.new_lock(f"{bucket}/{object_name}")
-        if not lock.get_rlock(30.0):
-            raise api_errors.ObjectApiError("read lock timeout")
+        # read lock + the quorum metadata read, before the stream (the
+        # body's spans hang under engine.get_object, a traced_iter)
+        with telemetry.span("get.open"):
+            if not lock.get_rlock(30.0):
+                raise api_errors.ObjectApiError("read lock timeout")
+            try:
+                fi, metas, online = self._object_file_info(
+                    bucket, object_name, opts.version_id)
+            except Exception:
+                lock.unlock()
+                raise
         try:
-            fi, metas, online = self._object_file_info(
-                bucket, object_name, opts.version_id)
             if fi.deleted:
                 # latest is a delete marker: plain GET -> NotFound;
                 # explicit version GET -> MethodNotAllowed (S3 semantics,
@@ -1498,8 +1511,8 @@ class ErasureObjects:
 
         def read_one(i: int, r) -> list:
             out = []
-            t0 = time.perf_counter()
-            with telemetry.span("disk.shard_read", disk=i, blocks=nb):
+            with telemetry.timed("disk.shard_read", disk=i,
+                                 blocks=nb) as t:
                 for b, sl in zip(blocks, shard_lens):
                     off = b * shard_size
                     if collect_digests and isinstance(
@@ -1509,8 +1522,7 @@ class ErasureObjects:
                                     frames[0][0] if frames else None))
                     else:
                         out.append((r.read_at(off, sl), None))
-            healthtrack.observe_disk(r.disk, "read",
-                                     time.perf_counter() - t0)
+            healthtrack.observe_disk(r.disk, "read", t.seconds)
             return out
 
         # candidate order: data rows first (their shards join without
@@ -2259,7 +2271,7 @@ class _PartReadPlan:
         offset, length = self.offset, self.length
         for si, (blocks, geoms) in enumerate(self.specs):
             group = []
-            with stagetimer.stage("get.read_shards"):
+            with telemetry.span("get.read_shards"):
                 lookahead = self._pending
                 self._pending = None
                 if lookahead is not None and lookahead.cancel():
@@ -2298,9 +2310,8 @@ class _PartReadPlan:
                 self.heal_required = self.heal_required or had_errors
                 group.append([b, block_off, block_len, shard_len,
                               shards, digests])
-            with stagetimer.stage("get.verify+decode"), \
-                    telemetry.span("pipeline.verify_decode",
-                                   blocks=len(blocks)):
+            with telemetry.span("pipeline.verify_decode",
+                                blocks=len(blocks)):
                 if self.eng._verify_and_reconstruct_group(
                         self.codec, group, k, n, readers,
                         self.shard_size,
@@ -2309,7 +2320,7 @@ class _PartReadPlan:
                         reader_gen=(self.reader_gen, gen_at_read),
                         benign_missing=benign):
                     self.heal_required = True
-            with stagetimer.stage("get.join"):
+            with telemetry.span("get.join"):
                 out = []
                 for b, block_off, block_len, shard_len, shards, _dg \
                         in group:

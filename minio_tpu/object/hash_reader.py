@@ -22,7 +22,7 @@ import time
 from typing import BinaryIO, Optional
 
 from . import api_errors
-from ..utils import stagetimer
+from ..utils import telemetry
 
 # Overlapping the digest with encode+write only pays when there is a
 # second core to run it on; on a single-core host the queue handoff is
@@ -45,6 +45,10 @@ class HashReader:
         self._md5 = hashlib.md5()
         self._sha256 = hashlib.sha256() if sha256_hex else None
         self.bytes_read = 0
+        # MD5 + SHA-256 busy time of this request, as ONE span under
+        # whatever span built the reader (the S3 handler's), fed from
+        # whichever thread hashes and attached when the hasher drains
+        self._hash_span = telemetry.accum("s3.body_hash")
 
         self._async = async_hash
         self._q: Optional[queue.Queue] = None
@@ -64,16 +68,11 @@ class HashReader:
             self._update(chunk)
 
     def _update(self, chunk) -> None:
-        if stagetimer.ENABLED:
-            t0 = time.perf_counter()
-            self._md5.update(chunk)
-            if self._sha256 is not None:
-                self._sha256.update(chunk)
-            stagetimer.add("put.md5+sha256", time.perf_counter() - t0)
-            return
+        t0 = time.perf_counter_ns()
         self._md5.update(chunk)
         if self._sha256 is not None:
             self._sha256.update(chunk)
+        self._hash_span.add(t0, time.perf_counter_ns())
 
     def read(self, n: int = -1) -> bytes:
         if self.size >= 0:
@@ -135,6 +134,7 @@ class HashReader:
             self._worker.join()
             self._q = None
             self._worker = None
+        self._hash_span.flush(bytes=self.bytes_read)
 
     def close(self) -> None:
         """Stop the background hasher — MUST be called on abandoned

@@ -77,6 +77,7 @@ def _run(m2p: jnp.ndarray, data: jnp.ndarray, r: int, k: int) -> jnp.ndarray:
         out_specs=pl.BlockSpec((1, r, _TS), lambda i, j: (i, 0, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((b, r, s), jnp.uint8),
+        name="gf_matmul",
     )(m2p, data)
 
 

@@ -75,18 +75,25 @@ _BATCHES_TOTAL = telemetry.REGISTRY.counter(
 _COALESCED_TOTAL = telemetry.REGISTRY.counter(
     "minio_tpu_sched_coalesced_total",
     "Groups that shared another request's dispatch")
-# dispatch-time attribution (ISSUE 13 pillar c): where a fused device
-# dispatch spends its time, per verb — "queue" (submit -> dispatch
-# start in the former), "transfer" (host batch assembly the dispatch
-# thread performs before launch), "compute" (device program to
-# completion), "fetch" (device->host readback + result assembly).
+# dispatch-time attribution: where a fused device dispatch spends its
+# time, per verb. Per group: "queue" (submit -> dispatch start in the
+# former) and its two parts, "collect" (submit -> the collector turns
+# to the group: the grace window, or the collector stalled acquiring a
+# slot for an earlier group) and "slot" (-> dispatch start: this
+# group's own wait on the INFLIGHT semaphore + the pool hand-off). Per
+# launch: "collector_blocked" (the collector's own time inside that
+# acquire — while it lasts EVERY bucket stands still), "transfer" (host
+# batch assembly before launch), "h2d" (upload of the fused input),
+# "compute" (launch + device program + sync), "fetch" (device->host
+# readback + result assembly).
 # Sub-ms buckets: a dispatch stage on a warm path is 10µs-100ms.
 _STAGE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
                   0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 10.0)
 _DISPATCH_STAGE_SECONDS = telemetry.REGISTRY.histogram(
     "minio_tpu_device_dispatch_seconds",
-    "Fused device dispatch stage timings (queue/transfer/compute/"
-    "fetch) per verb", buckets=_STAGE_BUCKETS)
+    "Fused device dispatch stage timings per verb (queue = collect + "
+    "slot; collector_blocked; transfer/h2d/compute/fetch)",
+    buckets=_STAGE_BUCKETS)
 
 
 def _collect_scheduler_metrics() -> None:
@@ -125,7 +132,7 @@ telemetry.REGISTRY.register_collector(_collect_scheduler_metrics)
 
 class _Pending:
     __slots__ = ("data", "payload", "blocks", "event", "out", "error",
-                 "span", "t_submit")
+                 "span", "t_submit_ns", "t_taken_ns")
 
     def __init__(self, data: Optional[np.ndarray] = None,
                  payload=None, blocks: Optional[int] = None):
@@ -141,8 +148,10 @@ class _Pending:
         # submitter's span: the collector thread is shared across
         # requests, so dispatch spans are attached explicitly
         self.span = None
-        # queue-wait attribution: submit time -> dispatch start
-        self.t_submit = time.perf_counter()
+        # queue-wait attribution (perf_counter_ns, the spans' clock):
+        # submit -> the collector turns to this group -> dispatch start
+        self.t_submit_ns = time.perf_counter_ns()
+        self.t_taken_ns = 0
 
 
 class DispatchFuture:
@@ -459,7 +468,14 @@ class BatchScheduler:
         sem = self._inflight_scan if key[0] == "scan" \
             else self._inflight
         for group in groups:
+            t_taken = time.perf_counter_ns()
+            for p in group:
+                p.t_taken_ns = t_taken
             sem.acquire()
+            if self.attrib:
+                _DISPATCH_STAGE_SECONDS.observe(
+                    (time.perf_counter_ns() - t_taken) / 1e9,
+                    verb=key[0], stage="collector_blocked")
             try:
                 self._pool.submit(self._dispatch_group, key, group, sem)
             except BaseException:  # noqa: BLE001 — pool gone (close race)
@@ -496,17 +512,21 @@ class BatchScheduler:
     def _dispatch_one(self, key: tuple, group: list) -> None:
         verb = key[0]
         attrib = self.attrib
-        # stage -> seconds for this dispatch ("transfer" is filled by
-        # the batch-assembly timer below; "compute"/"fetch" by the
-        # codec/kernel stage callback)
-        stages: dict[str, float] = {}
-        stage_cb = stages.__setitem__ if attrib else None
-        t0_wall, t0 = time.time(), time.perf_counter()
+        # stage -> (start ns, seconds) for this dispatch: the codec /
+        # kernel callback reports each stage as it ENDS, so its start
+        # is on the spans' clock without the callee knowing it
+        stages: dict[str, tuple[int, float]] = {}
+
+        def stage_cb(stage: str, seconds: float) -> None:
+            stages[stage] = (
+                time.perf_counter_ns() - int(seconds * 1e9), seconds)
+        t0_ns = time.perf_counter_ns()
         if verb == "scan":
-            out = self._run_scan(group, stage_cb)
+            out = self._run_scan(group, stage_cb if attrib else None)
         else:
-            out = self._run_erasure(key, group, stage_cb)
-        dt = time.perf_counter() - t0
+            out = self._run_erasure(key, group,
+                                    stage_cb if attrib else None)
+        t1_ns = time.perf_counter_ns()
         nb = sum(p.blocks for p in group)
         # a dispatch that DECLINED to the device (out is None: CPU
         # routing) launched nothing: it must feed neither the dispatch
@@ -531,33 +551,43 @@ class BatchScheduler:
                 _COALESCED_TOTAL.inc(len(group) - 1, verb=verb)
         if attrib and ran:
             for p in group:
-                _DISPATCH_STAGE_SECONDS.observe(
-                    max(t0 - p.t_submit, 0.0), verb=verb, stage="queue")
-            for stage, sdt in stages.items():
+                taken = p.t_taken_ns or t0_ns
+                for stage, a, b in (("queue", p.t_submit_ns, t0_ns),
+                                    ("collect", p.t_submit_ns, taken),
+                                    ("slot", taken, t0_ns)):
+                    _DISPATCH_STAGE_SECONDS.observe(
+                        max(b - a, 0) / 1e9, verb=verb, stage=stage)
+            for stage, (_at, sdt) in stages.items():
                 _DISPATCH_STAGE_SECONDS.observe(sdt, verb=verb,
                                                 stage=stage)
         for p in group:
             if p.span is not None:
                 # the collector/dispatch threads serve many requests:
                 # attach the dispatch to each submitter's tree as an
-                # externally-timed span, with the stage split as its
-                # children — /spans?sort=slowest answers WHERE a slow
-                # PUT/GET/heal/scan went (former queue? transfer?
-                # device compute? readback?)
+                # externally-timed span — submit to results, on the
+                # spans' own clock — with the stage split as its
+                # children: /spans?sort=slowest answers WHERE a slow
+                # PUT/GET/heal/scan went (the collector? a slot?
+                # transfer? the device? readback?)
                 d = telemetry.attach_span(
-                    p.span, "sched.dispatch", t0_wall, dt, verb=verb,
+                    p.span, "sched.dispatch", p.t_submit_ns,
+                    (t1_ns - p.t_submit_ns) / 1e9, verb=verb,
                     blocks=nb, coalesced=len(group) - 1)
                 if d is not None and attrib and ran:
-                    qw = max(t0 - p.t_submit, 0.0)
-                    telemetry.attach_span(d, "sched.queue",
-                                          t0_wall - qw, qw)
-                    off = t0_wall
-                    for stage in ("transfer", "compute", "fetch"):
-                        sdt = stages.get(stage)
-                        if sdt is not None:
-                            telemetry.attach_span(d, f"sched.{stage}",
-                                                  off, sdt)
-                            off += sdt
+                    taken = p.t_taken_ns or t0_ns
+                    q = telemetry.attach_span(
+                        d, "sched.queue", p.t_submit_ns,
+                        (t0_ns - p.t_submit_ns) / 1e9)
+                    if q is not None:
+                        telemetry.attach_span(
+                            q, "sched.collect", p.t_submit_ns,
+                            (taken - p.t_submit_ns) / 1e9)
+                        telemetry.attach_span(
+                            q, "sched.slot", taken,
+                            (t0_ns - taken) / 1e9)
+                    for stage, (at, sdt) in stages.items():
+                        telemetry.attach_span(d, f"sched.{stage}",
+                                              at, sdt)
         if not ran:
             # CPU routing: let each caller use its own path
             for p in group:

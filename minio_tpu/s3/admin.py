@@ -286,6 +286,25 @@ class AdminHandlers:
                 raise S3Error("AdminInvalidArgument",
                               "bad count") from None
             slowest = ctx.query1("sort", "recent") == "slowest"
+            # the window recorder: ?record=<seconds> keeps EVERY root
+            # whole for that long (the ring below shows only the slow
+            # tail); ?recorded=1 fetches the finished window — flat
+            # spans on the perf_counter_ns clock with thread CPU, the
+            # drop count, process CPU seconds at both ends
+            if ctx.query1("record", ""):
+                try:
+                    secs = float(ctx.query1("record"))
+                except ValueError:
+                    secs = -1.0
+                if not 0 < secs <= 300:
+                    raise S3Error("AdminInvalidArgument",
+                                  "record: seconds in (0, 300]")
+                telemetry.SPANS.record_for(secs)
+                return self._json({"recording": True, "seconds": secs})
+            if ctx.query1("recorded", ""):
+                done = telemetry.SPANS.recorded
+                return self._json(done if done is not None
+                                  else {"recording": True})
             return self._json({
                 "spans": telemetry.SPANS.dump(
                     n, slowest=slowest, name=ctx.query1("api", ""),

@@ -62,7 +62,11 @@ def run_request(api, extra_routers, ctx, command: str, raw_path: str,
             # an idle event stream runs for minutes by design — never
             # "slow"
             root_holder[0].slow_exempt = True
-        respond(resp)
+        # the response on the wire: a PUT's status line, a GET's whole
+        # body stream (the engine's read spans hang under it — the
+        # writer pulls them)
+        with telemetry.span("s3.respond"):
+            respond(resp)
 
     trace_id = ""
     try:

@@ -31,7 +31,7 @@ from ..object.hash_reader import HashReader
 from ..object.multipart import CompletePart
 from ..storage.datatypes import ObjectInfo
 from ..utils import knobs
-from ..utils import stagetimer, telemetry
+from ..utils import telemetry
 from ..utils.streams import IterStream as _IterStream
 from . import signature as sig
 from xml.sax.saxutils import escape as _sax_escape
@@ -315,7 +315,7 @@ class S3ApiHandlers:
                      object_name: str = "") -> None:
         """Verify the request signature and (if IAM is wired) that the
         caller may perform `action` (cmd/auth-handler.go checkRequestAuthType)."""
-        with stagetimer.stage("auth"):
+        with telemetry.span("s3.auth"):
             self._authenticate(ctx, action, bucket, object_name)
 
     def _authenticate(self, ctx: RequestContext,

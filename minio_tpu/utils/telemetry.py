@@ -36,6 +36,20 @@ knowing they mattered. Knobs (also README "Observability"):
   MINIO_TPU_TRACE_SLOW_MS=500    always keep traces at least this slow
   MINIO_TPU_TRACE_KEEP=128       kept-trace ring size
 
+Clocks: a span's ``start`` is wall time (for humans and for joining
+across nodes); ``t0_ns``/``t1_ns`` are ``time.perf_counter_ns()`` — one
+monotonic clock for every thread of the process, so spans of different
+threads order against each other, and a reader that took the same
+clock beside a profiler annotation can lay the tree over the device
+trace. ``tid`` is the thread the span was opened on.
+
+The window recorder (``SPANS.record_begin()`` / ``record_end()``, admin
+``/spans?record=<seconds>``) keeps EVERY finished root whole for a
+bounded window — the ring's tail sampling shows only the slow tail, a
+biased sample of where requests spend their time — and stamps each
+span's thread CPU at both ends (wall minus CPU = time the thread
+waited: socket, lock, GIL). Off, it costs one flag test per span.
+
 Cross-process joins: the internode transport injects
 ``x-minio-trace-id`` / ``x-minio-span-id`` headers; the serving side
 opens a `join()` span under that identity and records it as a
@@ -49,7 +63,9 @@ from __future__ import annotations
 
 import bisect
 import contextvars
+import itertools
 import math
+import os
 import random
 import re
 import threading
@@ -64,6 +80,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "Span", "SpanSink", "SPANS", "span", "trace", "join",
     "current_span", "attach_span", "propagating_context", "traced_iter",
+    "timed", "accum",
 ]
 
 # ---------------------------------------------------------------------------
@@ -366,13 +383,24 @@ _current: "contextvars.ContextVar[Optional[Span]]" = \
     contextvars.ContextVar("minio_tpu_span", default=None)
 
 
+# span ids: a per-process tag + a counter (a uuid4 per span was the
+# tracer's largest fixed cost); the tag keeps ids of different nodes
+# apart when RPC fragments graft into a caller's tree
+_ID_TAG = os.urandom(3).hex()
+_ids = itertools.count(1)
+# the window recorder's switch (SpanSink.record_begin/record_end): read
+# bare on every span's open and close, so off costs a flag test
+_recording = False
+
+
 class Span:
     """One timed operation in a request's tree. Children append under
     the parent's lock — stage threads and drive fan-outs attach
     concurrently."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start",
-                 "t0", "duration_s", "attrs", "error", "children",
+                 "t0_ns", "t1_ns", "tid", "cpu0_ns", "cpu_ns",
+                 "duration_s", "attrs", "error", "children",
                  "remote", "_mu", "_token", "root", "has_error",
                  "slow_exempt", "n_spans", "n_dropped")
 
@@ -381,10 +409,16 @@ class Span:
                  root: Optional["Span"] = None):
         self.name = name
         self.trace_id = trace_id
-        self.span_id = uuid.uuid4().hex[:12]
+        self.span_id = f"{_ID_TAG}{next(_ids):x}"
         self.parent_id = parent_id
         self.start = time.time()
-        self.t0 = time.perf_counter()
+        self.t0_ns = time.perf_counter_ns()
+        self.t1_ns = 0
+        self.tid = threading.get_ident()
+        # thread CPU burnt inside the span, taken only while the window
+        # recorder is on (-1 = not taken)
+        self.cpu0_ns = time.thread_time_ns() if _recording else -1
+        self.cpu_ns = -1
         self.duration_s = 0.0
         self.attrs = attrs or {}
         self.error = ""
@@ -425,7 +459,11 @@ class Span:
             self.children.append(child)
 
     def finish(self) -> None:
-        self.duration_s = time.perf_counter() - self.t0
+        self.t1_ns = time.perf_counter_ns()
+        self.duration_s = (self.t1_ns - self.t0_ns) / 1e9
+        if _recording and self.cpu0_ns >= 0 \
+                and threading.get_ident() == self.tid:
+            self.cpu_ns = time.thread_time_ns() - self.cpu0_ns
 
     def depth(self) -> int:
         with self._mu:
@@ -439,16 +477,25 @@ class Span:
         for c in kids:
             yield from c.walk()
 
-    def to_dict(self) -> dict:
-        with self._mu:
-            kids = list(self.children)
+    def to_dict(self, children: bool = True) -> dict:
+        """The span as a dict — with its subtree under `children`, or
+        alone (the window recorder's flat list links by `parent_id`)."""
+        kids = []
+        if children:
+            with self._mu:
+                kids = list(self.children)
         d = {
             "name": self.name,
             "trace_id": self.trace_id,
             "span_id": self.span_id,
             "start": round(self.start, 6),
             "duration_ms": round(self.duration_s * 1e3, 3),
+            "t0_ns": self.t0_ns,
+            "t1_ns": self.t1_ns,
+            "tid": self.tid,
         }
+        if self.cpu_ns >= 0:
+            d["cpu_ns"] = self.cpu_ns
         if self.parent_id:
             d["parent_id"] = self.parent_id
         if self.attrs:
@@ -563,7 +610,9 @@ def traced_iter(name: str, it, **attrs):
     reads, client hangups) would leak the span as that thread's
     current until GC — and then reset a foreign-context token. The
     span's duration covers first-to-last chunk; abandonment finishes
-    it from the generator's close."""
+    it from the generator's close. `busy_ns` in its attrs is the time
+    spent INSIDE the producer: the rest of the duration is the
+    consumer's (the response writer's), not this layer's."""
     parent = _current.get()
     if parent is None:
         yield from it
@@ -575,15 +624,18 @@ def traced_iter(name: str, it, **attrs):
     sp = Span(name, parent.trace_id, parent_id=parent.span_id,
               attrs=attrs or None, root=root)
     parent.add_child(sp)
+    busy = 0
     try:
         while True:
             token = _current.set(sp)
+            t = time.perf_counter_ns()
             try:
                 try:
                     chunk = next(it)
                 except StopIteration:
                     return
             finally:
+                busy += time.perf_counter_ns() - t
                 _current.reset(token)
             yield chunk
     except GeneratorExit:
@@ -596,6 +648,7 @@ def traced_iter(name: str, it, **attrs):
         sp.mark_error(f"{type(e).__name__}: {e}")
         raise
     finally:
+        sp.attrs["busy_ns"] = busy
         sp.finish()
         # abandonment (GeneratorExit) must close the inner generator
         # NOW, not at GC: its finally blocks release locks and join
@@ -605,22 +658,109 @@ def traced_iter(name: str, it, **attrs):
             close()
 
 
-def attach_span(parent: Span, name: str, start_wall: float,
+def attach_span(parent: Span, name: str, t0_ns: int,
                 duration_s: float, **attrs) -> Optional[Span]:
     """Attach an externally-timed, already-finished span (work done on
     a shared thread no contextvar reaches, e.g. the batch scheduler's
-    collector) under `parent`. Returns the new span (so the caller can
-    attach stage children under it), or None past the trace's span
-    budget."""
+    collector) under `parent`. `t0_ns` is the `time.perf_counter_ns()`
+    stamp the caller took when the work began — the same clock every
+    span carries, so the attached span orders against its siblings.
+    Returns the new span (so the caller can attach stage children
+    under it), or None past the trace's span budget."""
     root = parent.root or parent
     if not root._admit_child():
         return None
     sp = Span(name, parent.trace_id, parent_id=parent.span_id,
               attrs=attrs or None, root=root)
-    sp.start = start_wall
+    # wall start rebuilt from the monotonic stamp, for the dump
+    sp.start -= (sp.t0_ns - t0_ns) / 1e9
+    sp.t0_ns = t0_ns
+    sp.t1_ns = t0_ns + int(duration_s * 1e9)
+    sp.cpu0_ns = -1
     sp.duration_s = duration_s
     parent.add_child(sp)
     return sp
+
+
+class _Timed:
+    """`with timed(name) as t:` — a span when a trace is active, and in
+    any case the interval's seconds in `t.seconds` afterwards, from the
+    span's own stamps: call sites that feed a counter or a latency
+    tracker from the same interval take one timing, not two."""
+
+    __slots__ = ("_ctx", "_span", "_t0_ns", "seconds")
+
+    def __init__(self, ctx):
+        self._ctx = ctx
+        self.seconds = 0.0
+
+    def __enter__(self) -> "_Timed":
+        self._span = self._ctx.__enter__()
+        self._t0_ns = self._span.t0_ns if self._span is not None \
+            else time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._ctx.__exit__(exc_type, exc, tb)
+        self.seconds = self._span.duration_s if self._span is not None \
+            else (time.perf_counter_ns() - self._t0_ns) / 1e9
+        return False
+
+
+def timed(name: str, **attrs) -> _Timed:
+    return _Timed(span(name, **attrs))
+
+
+class _Accum:
+    """One span for a boundary crossed many times (per chunk, per
+    block): the caller adds each crossing's stamps, `flush()` attaches
+    ONE span from the first start to the last end with the summed busy
+    time and the call count in its attrs — a 64 MiB request stays far
+    under MAX_SPANS. Not thread-safe: one thread adds, and flush runs
+    after it is done."""
+
+    __slots__ = ("name", "parent", "t0_ns", "t1_ns", "busy_ns", "calls")
+
+    def __init__(self, name: str, parent: Span):
+        self.name = name
+        self.parent = parent
+        self.t0_ns = self.t1_ns = self.busy_ns = self.calls = 0
+
+    def add(self, t0_ns: int, t1_ns: int) -> None:
+        if not self.calls:
+            self.t0_ns = t0_ns
+        self.t1_ns = t1_ns
+        self.busy_ns += t1_ns - t0_ns
+        self.calls += 1
+
+    def flush(self, **attrs) -> None:
+        if self.calls:
+            attach_span(self.parent, self.name, self.t0_ns,
+                        (self.t1_ns - self.t0_ns) / 1e9,
+                        busy_ns=self.busy_ns, calls=self.calls, **attrs)
+            self.busy_ns = self.calls = 0
+
+
+class _NoAccum:
+    """Shared do-nothing accumulator: no trace is active."""
+
+    __slots__ = ()
+
+    def add(self, t0_ns: int, t1_ns: int) -> None:
+        pass
+
+    def flush(self, **attrs) -> None:
+        pass
+
+
+_NOACCUM = _NoAccum()
+
+
+def accum(name: str):
+    """An accumulator under the current span — the shared no-op when no
+    trace is active, so call sites add and flush unconditionally."""
+    p = _current.get()
+    return _Accum(name, p) if p is not None else _NOACCUM
 
 
 def propagating_context() -> Optional[contextvars.Context]:
@@ -650,6 +790,13 @@ class SpanSink:
         self._fragment_cap = 4 * capacity
         self.kept_total = 0
         self.dropped_total = 0
+        # the window recorder: every finished root (and RPC fragment)
+        # while on, whole, up to RECORD_CAP; the overflow is counted
+        self._rec: List[Span] = []
+        self._rec_dropped = 0
+        self._rec_mark: tuple = ()
+        self._rec_timer: Optional[threading.Timer] = None
+        self.recorded: Optional[dict] = None
 
     def configure(self, slow_s: Optional[float] = None,
                   sample: Optional[float] = None) -> None:
@@ -668,6 +815,8 @@ class SpanSink:
             or (root.duration_s >= self.slow_s
                 and not root.slow_exempt) \
             or (self.sample > 0 and random.random() < self.sample)
+        if _recording:
+            self._record(root)
         with self._mu:
             if keep:
                 self._kept.append(root)
@@ -677,6 +826,8 @@ class SpanSink:
         return keep
 
     def record_fragment(self, sp: Span) -> None:
+        if _recording:
+            self._record(sp)
         with self._mu:
             frags = self._fragments.get(sp.trace_id)
             if frags is None:
@@ -687,6 +838,62 @@ class SpanSink:
                     self._fragments.pop(evicted, None)
             if len(frags) < 64:           # bound one trace's fragments
                 frags.append(sp)
+
+    # -- the window recorder -----------------------------------------------
+
+    RECORD_CAP = 4096
+
+    def _record(self, root: Span) -> None:
+        with self._mu:
+            if len(self._rec) < self.RECORD_CAP:
+                self._rec.append(root)
+            else:
+                self._rec_dropped += 1
+
+    def record_begin(self) -> None:
+        """Start keeping every finished root whole (a running recording
+        starts over). Spans opened from now on stamp their thread CPU."""
+        global _recording
+        with self._mu:
+            self._rec = []
+            self._rec_dropped = 0
+            self._rec_mark = (time.perf_counter_ns(), time.process_time())
+        _recording = True
+
+    def record_end(self) -> dict:
+        """Stop, and return the window: `spans`, every span of every
+        root that finished in it, flat (`parent_id` links them; a root
+        has none); `roots` and `dropped` (roots past RECORD_CAP — never
+        silent); `t_ns` and `cpu_s`, perf_counter_ns and the process's
+        user+system CPU seconds at both ends. Spans still open at the
+        end (a write the quorum ack abandoned) are left out."""
+        global _recording
+        _recording = False
+        with self._mu:
+            roots, self._rec = self._rec, []
+            dropped, mark = self._rec_dropped, self._rec_mark
+        t0_ns, cpu0 = mark or (time.perf_counter_ns(), time.process_time())
+        return {
+            "spans": [sp.to_dict(children=False)
+                      for r in roots for sp in r.walk() if sp.t1_ns],
+            "roots": len(roots), "dropped": dropped,
+            "t_ns": [t0_ns, time.perf_counter_ns()],
+            "cpu_s": [cpu0, time.process_time()]}
+
+    def record_for(self, seconds: float) -> None:
+        """The operator's form (admin `/spans?record=<seconds>`): record
+        for `seconds`, then keep the window in `self.recorded` for the
+        next fetch."""
+        if self._rec_timer is not None:
+            self._rec_timer.cancel()
+        self.recorded = None
+        self.record_begin()
+
+        def done() -> None:
+            self.recorded = self.record_end()
+        self._rec_timer = threading.Timer(seconds, done)
+        self._rec_timer.daemon = True
+        self._rec_timer.start()
 
     # -- readback ----------------------------------------------------------
 

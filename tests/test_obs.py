@@ -709,8 +709,9 @@ def test_dispatch_stage_split_and_exposition_lint(monkeypatch):
     monkeypatch.setattr(codec_mod, "DEVICE_MIN_BYTES", 0)
     hist = telemetry.REGISTRY.histogram(
         "minio_tpu_device_dispatch_seconds")
-    before = {s: hist.count(verb="encode", stage=s)
-              for s in ("queue", "transfer", "compute", "fetch")}
+    stages = ("queue", "collect", "slot", "collector_blocked",
+              "transfer", "h2d", "compute", "fetch")
+    before = {s: hist.count(verb="encode", stage=s) for s in stages}
     sched = BatchScheduler(max_wait=0.05)
     codec = codec_mod.Codec(4, 2, 4 * 4096)
     data = np.random.randint(0, 255, (4, 4, 4096), dtype=np.uint8)
@@ -727,12 +728,26 @@ def test_dispatch_stage_split_and_exposition_lint(monkeypatch):
     # compute on the mesh path)
     for s in ("queue", "transfer", "compute"):
         assert hist.count(verb="encode", stage=s) > before[s], s
-    # the dispatch span carries the stage children
+    # queue's two parts are observed once per group beside it, the
+    # collector's own blocked time once per launch, the upload apart
+    # from the program
+    for s in ("collect", "slot", "collector_blocked", "h2d", "fetch"):
+        assert hist.count(verb="encode", stage=s) == before[s] + 1, s
+    # the dispatch span carries the stage children, and the queue span
+    # its two parts — which add up to it
     tree = root.to_dict()
     d = _find(tree, "sched.dispatch")
     assert d, tree
     child_names = {c["name"] for c in d[0].get("children", ())}
-    assert {"sched.queue", "sched.compute"} <= child_names, child_names
+    assert {"sched.queue", "sched.transfer", "sched.h2d",
+            "sched.compute", "sched.fetch"} <= child_names, child_names
+    q = _find(tree, "sched.queue")[0]
+    parts = {c["name"]: c for c in q["children"]}
+    assert set(parts) == {"sched.collect", "sched.slot"}
+    assert abs(sum(c["t1_ns"] - c["t0_ns"] for c in parts.values())
+               - (q["t1_ns"] - q["t0_ns"])) < 1e6
+    # a 50 ms grace window with one submitter: the wait is `collect`
+    assert parts["sched.collect"]["duration_ms"] >= 40
     # exposition lint: histogram triplet with consistent labels
     text = telemetry.REGISTRY.render()
     fam = "minio_tpu_device_dispatch_seconds"
