@@ -61,6 +61,35 @@ def data_path_line() -> str:
             f"{kernel}")
 
 
+class EncodedRows:
+    """First element of the plain encode route's result: the parity the
+    device made and a reference to the caller's own data rows — no
+    (B, k+m, S) array exists unless somebody asks for one. `np.array()`
+    / `np.asarray()` answer with the join [data ‖ parity] (the shape
+    the tuple had before PR 26), which is what lets a wrapper that
+    treats the result as a plain ndarray keep working; the batch former
+    and the engine read `.parity` and never pay for the join."""
+
+    __slots__ = ("data", "parity")
+
+    def __init__(self, data: np.ndarray, parity: np.ndarray):
+        self.data = data
+        self.parity = parity
+
+    def __array__(self, dtype=None, copy=None):
+        full = np.concatenate([self.data, self.parity], axis=1)
+        return full if dtype is None else full.astype(dtype, copy=False)
+
+
+def parity_rows(rows, k: int) -> np.ndarray:
+    """(B, m, S) parity of an encode result's first element: an
+    EncodedRows' own array, or the rows past k of a plain (B, k+m, S)
+    join (the mesh route's result, a wrapped codec's)."""
+    if isinstance(rows, EncodedRows):
+        return rows.parity
+    return rows[:, k:]
+
+
 class Codec:
     """RS(k, m) over GF(2^8), klauspost-compatible matrices."""
 
@@ -221,14 +250,18 @@ class Codec:
         Erasure.Encode + streaming-bitrot work, cmd/erasure-encode.go:75 +
         cmd/bitrot-streaming.go:46, as a single device step).
 
-        data: (B, k, S). Returns (full (B, k+m, S), digests (B, k+m, 32))
-        as numpy arrays, or None when the batch doesn't route to the
-        device or the bitrot algorithm has no device kernel.
+        data: (B, k, S). Returns (rows, digests (B, k+m, 32)), or None
+        when the batch doesn't route to the device or the bitrot
+        algorithm has no device kernel. `rows` is an EncodedRows —
+        parity (B, m, S) fetched from the device beside a reference to
+        `data`; the k data rows never cross back and are not copied —
+        or, on the mesh route, that route's own (B, k+m, S) join.
+        `parity_rows(rows, k)` reads either.
 
         stage_cb(stage, seconds), when given, is called as each stage
         ENDS: "h2d" (the fused input's upload, waited for), "compute"
-        (launch + device program + sync) and "fetch" (device→host
-        readback + result assembly) — the batch scheduler's dispatch
+        (launch + device program + sync) and "fetch" (the device→host
+        readback of parity + digests) — the batch scheduler's dispatch
         attribution. The mesh path reports a single "compute" stage (its
         sharded programs return host arrays in one step).
         """
@@ -255,10 +288,8 @@ class Codec:
         parity, digests = put_step(dev, self.k, self.m, algo=kernel)
         t1 = self._staged(stage_cb, t0, (parity, digests))
         # only parity + digests cross back from the device; the k data
-        # rows are the caller's own bytes
-        out = (np.concatenate([np.asarray(data, np.uint8),
-                               np.asarray(parity)], axis=1),
-               np.asarray(digests))
+        # rows stay the caller's own bytes, referenced and not copied
+        out = EncodedRows(data, np.asarray(parity)), np.asarray(digests)
         self._fetched(stage_cb, t1)
         return out
 

@@ -46,7 +46,7 @@ from ..storage.xl_storage import (MINIO_META_BUCKET,
                                   MINIO_META_MULTIPART_BUCKET,
                                   MINIO_META_TMP_BUCKET)
 from . import api_errors, bitrot_io, metadata as meta
-from .codec import Codec
+from .codec import Codec, parity_rows
 from .hash_reader import HashReader
 from .nslock import NSLockMap
 
@@ -471,10 +471,8 @@ class ErasureObjects:
                     item["rows"] = self._sse_encode(codec, data, item,
                                                     fut, sse)
                 else:
-                    # check: allow(deadline) device dispatch; scheduler close() flushes waiters
-                    fused = fut.result() if fut is not None else \
-                        codec.encode_and_hash_batch(data, self.bitrot_algo)
-                    item["rows"] = self._unpack_fused(codec, data, fused)
+                    item["rows"] = self._unpack_fused(
+                        codec, data, self._fused_encode(codec, data, fut))
             stage_s[1] += t.seconds
             return item
 
@@ -709,17 +707,40 @@ class ErasureObjects:
             return encrypted_size(total)
         return total
 
-    def _unpack_fused(self, codec: Codec, data: np.ndarray, fused
+    def _fused_encode(self, codec: Codec, data: np.ndarray, fut=None):
+        """(parity, digests) of one plain batch off the device — from
+        its future, the shared batch former, or the codec itself when
+        the engine runs without a former — or None (local CPU path)."""
+        if fut is not None:
+            # check: allow(deadline) device dispatch; scheduler close() flushes waiters
+            return fut.result()
+        if self.scheduler is not None:
+            # the cross-request scheduler coalesces concurrent PUT
+            # streams into shared dispatches
+            return self.scheduler.encode_and_hash(codec, data,
+                                                  self.bitrot_algo)
+        fused = codec.encode_and_hash_batch(data, self.bitrot_algo)
+        if fused is None:
+            return None
+        return parity_rows(fused[0], codec.k), fused[1]
+
+    def _unpack_fused(self, codec: Codec, data: np.ndarray, fused,
+                      ciphertext: bool = False
                       ) -> tuple[np.ndarray, np.ndarray, np.ndarray,
                                  np.ndarray]:
         """(data_rows, parity, data_digests, parity_digests) from one
         fused encode+digest result, or the local CPU fallback when the
-        batch didn't ride the device (`fused` is None). data rows stay
-        views of the caller's staging buffer on the CPU path."""
+        batch didn't ride the device (`fused` is None). A plain result
+        is (parity, digests): the data rows stay views of the caller's
+        staging buffer, on the device path as on the CPU path. Under
+        SSE (`ciphertext`) it is (full, digests) and the data rows are
+        the ciphertext the device made."""
         if fused is not None:
-            full, digests = fused
-            return (full[:, :codec.k], full[:, codec.k:],
-                    digests[:, :codec.k], digests[:, codec.k:])
+            rows, digests = fused
+            dd, dp = digests[:, :codec.k], digests[:, codec.k:]
+            if ciphertext:
+                return rows[:, :codec.k], rows[:, codec.k:], dd, dp
+            return data, rows, dd, dp
         b_ = data.shape[0]
         parity = codec.encode_parity_batch(data)
         dd = bitrot_mod.hash_shards_batch(
@@ -754,17 +775,9 @@ class ErasureObjects:
                     codec, data, item, fut, sse)
             else:
                 # fused device encode+digest when routed there (one
-                # program, one round-trip); the cross-request scheduler
-                # coalesces concurrent PUT streams into shared
-                # dispatches
-                if self.scheduler is not None:
-                    fused = self.scheduler.encode_and_hash(
-                        codec, data, self.bitrot_algo)
-                else:
-                    fused = codec.encode_and_hash_batch(data,
-                                                        self.bitrot_algo)
+                # program, one round-trip)
                 data_rows, parity, dd, dp = self._unpack_fused(
-                    codec, data, fused)
+                    codec, data, self._fused_encode(codec, data))
         with telemetry.span("pipeline.shard_write"):
             self._write_shards_batch(data_rows, parity, dd, dp, writers,
                                      write_quorum)
@@ -795,7 +808,7 @@ class ErasureObjects:
         if fused is None:
             flat = data.reshape(b_, -1)
             sse.cpu_encrypt_rows(flat[:, :bs], item["sse_off"])
-        rows = self._unpack_fused(codec, data, fused)
+        rows = self._unpack_fused(codec, data, fused, ciphertext=True)
         ct = rows[0]        # (B, k, S): device output or encrypted buf
         for i in range(b_):
             sse.absorb(ct[i].reshape(-1)[:bs])

@@ -54,6 +54,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..object.codec import Codec, parity_rows
 from ..utils import eventlog, knobs, lockcheck, telemetry
 
 MAX_BATCH_BLOCKS = knobs.get_int("MINIO_TPU_SCHED_MAX_BATCH")
@@ -82,10 +83,11 @@ _COALESCED_TOTAL = telemetry.REGISTRY.counter(
 # slot for an earlier group) and "slot" (-> dispatch start: this
 # group's own wait on the INFLIGHT semaphore + the pool hand-off). Per
 # launch: "collector_blocked" (the collector's own time inside that
-# acquire — while it lasts EVERY bucket stands still), "transfer" (host
-# batch assembly before launch), "h2d" (upload of the fused input),
+# acquire — while it lasts EVERY bucket stands still), "transfer" (the
+# gather of a multi-group launch into the slot's staging buffer; a
+# one-group launch copies nothing), "h2d" (upload of the fused input),
 # "compute" (launch + device program + sync), "fetch" (device->host
-# readback + result assembly).
+# readback of what the device made).
 # Sub-ms buckets: a dispatch stage on a warm path is 10µs-100ms.
 _STAGE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
                   0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 10.0)
@@ -160,10 +162,12 @@ class DispatchFuture:
     resolves the future when it actually needs the result (the fork's
     async QAT kernel launch pattern).
 
-    result() returns the verb's tuple — encode (full, digests); decode
-    (missing, missing_idx, survivor_digests); recover (out, idxs,
-    survivor_digests, out_digests) — or None when the work must take
-    the caller's local CPU path."""
+    result() returns the verb's tuple — encode (parity (B, m, S),
+    digests (B, k+m, 32)), or with sse= (full (B, k+m, S) with
+    ciphertext data rows, digests); decode (missing, missing_idx,
+    survivor_digests); recover (out, idxs, survivor_digests,
+    out_digests) — or None when the work must take the caller's local
+    CPU path. No array of a result aliases a buffer the former reuses."""
 
     __slots__ = ("_pending", "_value")
 
@@ -213,8 +217,11 @@ class BatchScheduler:
         self.batches = 0              # dispatch counter (tests/metrics)
         self.coalesced = 0            # groups that shared a dispatch
         self.dispatched_blocks = 0    # blocks through the device path
+        # staged_bytes: gathered into a staging buffer before upload (0
+        # for a one-group launch); fetched_bytes: what crossed back
         self.verb_stats = {v: {"batches": 0, "coalesced": 0, "blocks": 0,
-                               "cpu_routed": 0, "errors": 0}
+                               "cpu_routed": 0, "errors": 0,
+                               "staged_bytes": 0, "fetched_bytes": 0}
                            for v in VERBS}
         # stage attribution (queue/transfer/compute/fetch histograms +
         # per-dispatch child spans); `off` is the overhead-A/B escape
@@ -224,6 +231,14 @@ class BatchScheduler:
         # keeping `inflight` dispatches airborne overlaps batch N+1's
         # host->device transfer with batch N's compute
         self._inflight = threading.BoundedSemaphore(max(1, inflight))
+        # free list of the erasure slots' staging buffers: a launch of
+        # several groups gathers them into one flat uint8 buffer it
+        # takes here and gives back once its codec call has returned.
+        # At most `inflight` launches are airborne, so at most that
+        # many buffers ever exist, each grown to the largest launch it
+        # has carried — warm pages instead of a fresh allocation's
+        # first-touch faults on every launch.
+        self._staging: list[np.ndarray] = []
         # scan dispatches get their OWN slot: a Select with a fresh
         # plan signature pays a jax.jit trace+compile (seconds) inside
         # its dispatch — sharing slots would park latency-critical
@@ -313,10 +328,11 @@ class BatchScheduler:
                sse=None) -> DispatchFuture:
         """Non-blocking fused encode+digest dispatch: enqueue the
         (B, k, S) group on the batch former and return immediately. The
-        future resolves to (full, digests), or to None when the work
-        can't ride the device path (the caller falls back to its local
-        CPU path) — declined submissions return an already-done
-        future.
+        future resolves to (parity (B, m, S), digests (B, k+m, 32)) —
+        the data rows are the caller's own and do not come back — or to
+        None when the work can't ride the device path (the caller falls
+        back to its local CPU path) — declined submissions return an
+        already-done future.
 
         sse = (keys (B, 8), nonces (B, P, 3), pkg_bytes) turns the
         dispatch into the fused cipher+RS+digest program (codec.
@@ -324,7 +340,9 @@ class BatchScheduler:
         like survivor masks do, but the bucket key carries only their
         GEOMETRY (package count + size) — concurrent encrypted PUTs
         from different objects, under different keys, coalesce into one
-        launch. The resolved `full` then holds CIPHERTEXT data rows."""
+        launch. The device changed the data rows, so the future then
+        resolves to (full (B, k+m, S), digests): CIPHERTEXT data rows
+        with parity appended."""
         if self._declined(codec, algo):
             return DispatchFuture()
         if sse is None:
@@ -521,11 +539,25 @@ class BatchScheduler:
             stages[stage] = (
                 time.perf_counter_ns() - int(seconds * 1e9), seconds)
         t0_ns = time.perf_counter_ns()
+        staged = fetched = 0
+        # what moved, on the erasure stages' spans
+        stage_attrs: dict[str, dict] = {}
         if verb == "scan":
             out = self._run_scan(group, stage_cb if attrib else None)
         else:
-            out = self._run_erasure(key, group,
-                                    stage_cb if attrib else None)
+            out, staged = self._run_erasure(
+                key, group, stage_cb if attrib else None)
+            if out is not None:
+                if verb == "encode" and key[5] is None:
+                    # plain route: the device made parity and digests;
+                    # unwrap the codec's result at once, so no stream
+                    # ever sees (or joins) an EncodedRows
+                    out = (parity_rows(out[0], key[1]), out[1])
+                fetched = sum(a.nbytes for a in out
+                              if isinstance(a, np.ndarray))
+            stage_attrs = {
+                "transfer": {"groups": len(group), "bytes": staged},
+                "fetch": {"bytes": fetched}}
         t1_ns = time.perf_counter_ns()
         nb = sum(p.blocks for p in group)
         # a dispatch that DECLINED to the device (out is None: CPU
@@ -543,6 +575,8 @@ class BatchScheduler:
                 vs["batches"] += 1
                 vs["coalesced"] += len(group) - 1
                 vs["blocks"] += nb
+                vs["staged_bytes"] += staged
+                vs["fetched_bytes"] += fetched
             else:
                 vs["cpu_routed"] += 1
         if ran:
@@ -586,8 +620,9 @@ class BatchScheduler:
                             q, "sched.slot", taken,
                             (t0_ns - taken) / 1e9)
                     for stage, (at, sdt) in stages.items():
-                        telemetry.attach_span(d, f"sched.{stage}",
-                                              at, sdt)
+                        telemetry.attach_span(
+                            d, f"sched.{stage}", at, sdt,
+                            **stage_attrs.get(stage, {}))
         if not ran:
             # CPU routing: let each caller use its own path
             for p in group:
@@ -597,8 +632,10 @@ class BatchScheduler:
         for p in group:
             b = p.blocks
             if verb == "encode":
-                full, digests = out
-                p.out = (full[at:at + b], digests[at:at + b])
+                # (parity, digests) on the plain route, (full, digests)
+                # under sse
+                rows, digests = out
+                p.out = (rows[at:at + b], digests[at:at + b])
             elif verb == "decode":
                 missing, missing_idx, sdig = out
                 p.out = (missing[at:at + b], missing_idx,
@@ -612,20 +649,45 @@ class BatchScheduler:
             at += b
             p.event.set()
 
+    def _run_erasure(self, key: tuple, group: list, stage_cb=None):
+        """One fused codec call over the group -> (result or None,
+        bytes gathered into a staging buffer). A launch of one group
+        uploads its data as it is; several are copied, once, into a
+        buffer from the free list, which goes back when the codec call
+        has returned: its results are fetched by then, so the input is
+        consumed — also when the upload was not waited for, or
+        (XLA-CPU) aliased this memory."""
+        t0 = time.perf_counter()
+        buf = None
+        if len(group) == 1:
+            data, staged = group[0].data, 0
+        else:
+            staged = sum(p.data.nbytes for p in group)
+            with self._mu:
+                buf = self._staging.pop() if self._staging else None
+            if buf is None or buf.size < staged:
+                buf = np.empty(staged, dtype=np.uint8)
+            data = buf[:staged].reshape(-1, *group[0].data.shape[1:])
+            at = 0
+            for p in group:
+                data[at:at + p.blocks] = p.data
+                at += p.blocks
+        try:
+            if stage_cb is not None:
+                stage_cb("transfer", time.perf_counter() - t0)
+            return self._run_codec(key, group, data, stage_cb), staged
+        finally:
+            if buf is not None:
+                with self._mu:
+                    self._staging.append(buf)
+
     @staticmethod
-    def _run_erasure(key: tuple, group: list, stage_cb=None):
-        from ..object.codec import Codec
+    def _run_codec(key: tuple, group: list, data: np.ndarray,
+                   stage_cb=None):
         from .. import bitrot as bitrot_mod
         verb, k, m, s, algo_value, extra = key
         algo = bitrot_mod.BitrotAlgorithm.from_string(algo_value)
         codec = Codec(k, m, s * k)
-        t0 = time.perf_counter()
-        data = np.concatenate([p.data for p in group], axis=0) \
-            if len(group) > 1 else group[0].data
-        if stage_cb is not None:
-            # host-side batch staging: the fused input's assembly into
-            # one contiguous array the device upload reads from
-            stage_cb("transfer", time.perf_counter() - t0)
 
         def _sse_arrays():
             # per-row key/nonce word arrays concatenate across the

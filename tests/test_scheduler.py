@@ -61,9 +61,9 @@ def test_scheduler_coalesces_concurrent_streams(device_codec):
 
     for i, out in enumerate(outs):
         assert out is not None
-        full, digests = out
+        parity, digests = out
         want = codec.encode_batch(inputs[i], force="numpy")
-        assert (full == want).all()
+        assert (parity == want[:, 4:]).all()
         want_dg = bitrot_mod.hash_shards_batch(
             want.reshape(-1, 512), HH).reshape(2, 6, 32)
         assert (digests == want_dg).all()
@@ -89,9 +89,9 @@ def test_scheduler_respects_max_batch(device_codec):
     for t in threads:
         t.join(timeout=30)
     for i in range(4):
-        full, _ = outs[i]
-        assert (full == codec.encode_batch(inputs[i],
-                                           force="numpy")).all()
+        parity, _ = outs[i]
+        assert (parity == codec.encode_batch(
+            inputs[i], force="numpy")[:, 4:]).all()
     sched.close()
 
 
@@ -155,8 +155,8 @@ def test_scheduler_submit_resolves_on_device_route(device_codec):
     fut = sched.submit(codec, data, HH)
     out = fut.result(timeout=30)
     assert out is not None
-    full, _dg = out
-    assert (full == codec.encode_batch(data, force="numpy")).all()
+    parity, _dg = out
+    assert (parity == codec.encode_batch(data, force="numpy")[:, 4:]).all()
     assert fut.done()
     sched.close()
 
@@ -299,9 +299,9 @@ def test_mixed_verb_mixed_geometry_coalescing(device_codec):
 
     # encode oracle
     for i, nm in enumerate(("enc0", "enc1")):
-        full_got, _dg = results[nm]
-        assert (full_got == codec.encode_batch(enc_in[i],
-                                               force="numpy")).all()
+        parity_got, _dg = results[nm]
+        assert (parity_got == codec.encode_batch(
+            enc_in[i], force="numpy")[:, k:]).all()
     # decode oracle: missing data rows + survivor digests
     dm, used, missing = rs_matrix.missing_data_matrix(k, m, mask)
     want = np.stack([gf256.gf_matmul(np.asarray(dm, np.uint8), sv)
@@ -417,3 +417,130 @@ def test_sched_totals_exposed_as_prometheus_counters(device_codec):
     assert 'minio_tpu_sched_batches_total{verb="encode"}' in text
     # occupancy stays a gauge (instantaneous, per-verb labelled)
     assert "# TYPE minio_tpu_sched_batch_occupancy_groups gauge" in text
+
+
+# ---------------------------------------------------------------------------
+# PR 26: a PUT launch moves the data rows once — parity + digests come
+# back, groups gather into a slot-owned staging buffer
+# ---------------------------------------------------------------------------
+
+SHA = bitrot_mod.BitrotAlgorithm.SHA256
+# 12+4-like (shard length neither lane- nor unroll-aligned) and 8+8-like
+_GEOS = [pytest.param(12, 4, 346, id="12p4"), pytest.param(8, 8, 512, id="8p8")]
+_ALGOS = [pytest.param(HH, id="highwayhash"), pytest.param(SHA, id="sha256")]
+
+
+def _launch(sched, codec, groups, algo, **kw):
+    """Submit `groups` so that they share ONE launch: the scheduler's
+    max_batch equals their block count and the grace window is long,
+    so the bucket dispatches the moment the last group lands."""
+    assert sum(g.shape[0] for g in groups) == sched.max_batch
+    futs = [sched.submit(codec, g, algo, **kw) for g in groups]
+    return [f.result(timeout=120) for f in futs]
+
+
+def _assert_plain(codec, algo, data, out):
+    """(parity, digests) of one group against the host oracle."""
+    b, k, s = data.shape
+    parity, digests = out
+    want = codec.encode_batch(data, force="numpy")
+    assert parity.shape == (b, codec.m, s)        # no k+m-row array
+    assert (parity == want[:, k:]).all()
+    want_dg = bitrot_mod.hash_shards_batch(
+        want.reshape(b * (k + codec.m), s), algo)
+    assert (digests == want_dg.reshape(b, k + codec.m, -1)).all()
+
+
+@pytest.mark.parametrize("algo", _ALGOS)
+@pytest.mark.parametrize("k,m,s", _GEOS)
+def test_staging_buffer_reuse_aliases_nothing(device_codec, k, m, s, algo):
+    """Three launches in a row through ONE former, of 1, 3 and 2
+    groups: the later ones gather into (and overwrite) the slot's
+    staging buffer, and every group's result — the first launch's too,
+    read again afterwards — stays right and shares no memory with it."""
+    sched = BatchScheduler(max_batch=6, max_wait=30.0)
+    codec = Codec(k, m, k * s)
+    rng = np.random.default_rng(k * 1000 + s)
+    launches = [[rng.integers(0, 256, (b, k, s), dtype=np.uint8)
+                 for b in blocks] for blocks in ((6,), (2, 2, 2), (3, 3))]
+    try:
+        outs = [_launch(sched, codec, groups, algo) for groups in launches]
+        st = sched.stats()["verbs"]["encode"]
+        assert (st["batches"], st["coalesced"]) == (3, 3)
+        # the one-group launch copied nothing; the other two 6 blocks each
+        assert st["staged_bytes"] == 2 * 6 * k * s
+        assert len(sched._staging) == 1       # serial launches: one buffer
+        for groups, results in zip(launches, outs):
+            for data, out in zip(groups, results):
+                _assert_plain(codec, algo, data, out)
+                for a in out:
+                    assert not any(np.shares_memory(a, buf)
+                                   for buf in sched._staging)
+    finally:
+        sched.close()
+
+
+@pytest.mark.parametrize("algo", _ALGOS)
+@pytest.mark.parametrize("k,m,s", _GEOS)
+def test_plain_encode_fetches_parity_and_digests_only(device_codec, k, m, s,
+                                                      algo):
+    """On the plain route a group gets m rows, not k+m, and the former's
+    byte counters say what moved: a one-group launch stages nothing and
+    fetches B·m·S of parity + B·(k+m)·32 of digests."""
+    b = 4
+    sched = BatchScheduler(max_batch=b, max_wait=30.0)
+    codec = Codec(k, m, k * s)
+    data = np.random.default_rng(7).integers(0, 256, (b, k, s),
+                                             dtype=np.uint8)
+    try:
+        (out,) = _launch(sched, codec, [data], algo)
+        _assert_plain(codec, algo, data, out)
+        st = sched.stats()["verbs"]["encode"]
+        assert st["staged_bytes"] == 0 and not sched._staging
+        assert st["fetched_bytes"] == b * m * s + b * (k + m) * 32
+    finally:
+        sched.close()
+
+
+@pytest.mark.parametrize("k,m", [pytest.param(12, 4, id="12p4"),
+                                 pytest.param(8, 8, id="8p8")])
+def test_sse_encode_still_returns_ciphertext_rows(device_codec, monkeypatch,
+                                                  k, m):
+    """The device changed the data rows, so the SSE route keeps the
+    (B, k+m, S) shape: ciphertext rows + parity over them, for two
+    groups under different keys gathered into one launch."""
+    from minio_tpu.features import crypto as sse
+    monkeypatch.setenv("MINIO_TPU_SSE_DEVICE_MIN_BYTES", "0")
+    block = 1 << 16
+    codec = Codec(k, m, block)
+    s = codec.shard_size
+    rng = np.random.default_rng(26)
+    specs = [sse.DeviceSSE(rng.bytes(32), rng.bytes(12)) for _ in range(2)]
+    groups = []
+    for _ in specs:
+        g = np.zeros((2, k * s), dtype=np.uint8)
+        g[:, :block] = rng.integers(0, 256, (2, block), dtype=np.uint8)
+        groups.append(g.reshape(2, k, s))
+    sched = BatchScheduler(max_batch=4, max_wait=30.0)
+    try:
+        futs = []
+        for spec, g in zip(specs, groups):
+            keys, nonces = spec.batch_params(0, 2, block)
+            futs.append(sched.submit(codec, g, HH,
+                                     sse=(keys, nonces, sse.PKG_SIZE)))
+        outs = [f.result(timeout=120) for f in futs]
+        assert sched.stats()["verbs"]["encode"]["coalesced"] == 1
+        for spec, g, (full, digests) in zip(specs, groups, outs):
+            assert full.shape == (2, k + m, s)
+            want_ct = g.reshape(2, -1).copy()
+            spec.cpu_encrypt_rows(want_ct[:, :block], 0)
+            want = codec.encode_batch(want_ct.reshape(2, k, s),
+                                      force="numpy")
+            assert (full == want).all()
+            want_dg = bitrot_mod.hash_shards_batch(
+                want.reshape(2 * (k + m), s), HH)
+            assert (digests == want_dg.reshape(2, k + m, -1)).all()
+            assert not any(np.shares_memory(full, buf)
+                           for buf in sched._staging)
+    finally:
+        sched.close()
