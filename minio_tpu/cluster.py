@@ -35,7 +35,8 @@ from .s3.credentials import Credentials
 from .s3.server import S3Server
 from .storage import errors as serr
 from .storage.xl_storage import XLStorage
-from .utils import device, ellipses, knobs
+from .parallel import ladder
+from .utils import device, ellipses, knobs, telemetry
 
 
 @dataclasses.dataclass
@@ -129,10 +130,11 @@ class ClusterNode:
         self._peer_clients: list[PeerRPCClient] = []
         self._start_server(region, iam)
         try:
-            self._finish_boot(nodes, this, all_drives, endpoints, ak, sk,
-                              set_count, set_drive_count, parity,
-                              block_size, bootstrap_timeout,
-                              format_timeout)
+            with telemetry.trace("node.boot", node=self.spec.addr):
+                self._finish_boot(nodes, this, all_drives, endpoints,
+                                  ak, sk, set_count, set_drive_count,
+                                  parity, block_size, bootstrap_timeout,
+                                  format_timeout)
         except BaseException:
             # a failed boot must not leak the already-listening server /
             # RPC clients into the process (shutdown is idempotent and
@@ -208,6 +210,15 @@ class ClusterNode:
                     raise
                 time.sleep(0.5)
         self.sets = sets
+        # the geometry is declared: on a TPU, load every encode rung of
+        # the launch ladder now — the object layer is set (and the
+        # listener answers its first request) only when they are in.
+        # A CPU host loads nothing. Decode rungs wait for a drive to
+        # be found missing (ROADMAP A5).
+        eng = sets.sets[0]
+        ladder.load_encode(
+            eng.codec(eng.data_shards, eng.parity_shards),
+            eng.bitrot_algo, self.scheduler.max_batch)
         # distributed clusters are single-pool (expansion/decommission
         # are the single-node surface today): skip the boot-time
         # cluster-wide topology read — during a concurrent multi-node

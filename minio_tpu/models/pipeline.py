@@ -12,9 +12,12 @@ pipelines. Each is a jittable function over batched shard tensors:
     matrix (cmd/erasure-lowlevel-heal.go:28-48 collapsed to a single
     device op).
 
-All pipelines are shape-static per (k, m, S, B) and cached; the batch
-scheduler (parallel/scheduler.py) routes variable traffic into a small set
-of bucketed shapes so XLA compiles each program once.
+All pipelines are shape-static per (k, m, S, B) and cached. The B
+ladder lives in parallel/ladder.py: a launch of any block count is
+padded with zero blocks up to its rung (by the batch former in its
+staging buffer, by object/codec.py on the direct route) and cut back
+on the device (`head_blocks`), so a geometry launches a closed set of
+programs at full-block S — which boot loads for the encode verb.
 """
 
 from __future__ import annotations
@@ -254,6 +257,14 @@ def sse_get_step(survivors: jax.Array, matrix_bits: jax.Array,
                 axis=-1)
         plain = (stacked.reshape(b, kd * s) ^ ks).reshape(b, kd, s)
     return plain, out, digests[:, :k]
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def head_blocks(outputs: tuple, n: int) -> tuple:
+    """The first n blocks of every output of a step that ran at a
+    ladder rung above its launch's block count: the pad blocks' rows
+    and digests end here, on the device, and never cross to the host."""
+    return tuple(o[:n] for o in outputs)
 
 
 def _hash_rows(rows: jax.Array, shard_len: int, key: bytes,

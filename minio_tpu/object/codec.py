@@ -226,7 +226,7 @@ class Codec:
         """Compute/fetch boundary for the single-device jit path: wait
         for the device values and report "compute" (launch + program +
         sync, from `t0`); the caller's numpy conversions (fetch =
-        device→host readback) run after and end in `_fetched`. No-op
+        device→host readback) run after and end in `_fetch`. No-op
         without a callback."""
         import time as _time
         if stage_cb is None:
@@ -238,13 +238,57 @@ class Codec:
         return t1
 
     @staticmethod
-    def _fetched(stage_cb, t1: float) -> None:
+    def _on_rung(verb: str, blocks: Optional[int], *arrays):
+        """-> (n, arrays at the launch's rung). `blocks` None: the
+        arrays hold n real blocks each and are padded here, with zero
+        blocks, up to the ladder's rung for n (parallel/ladder.py). A
+        caller that gathered the launch itself (the batch former's
+        staging buffer) has padded already and says how many of the
+        rows are real; the small per-row arrays beside the data (SSE
+        key and nonce words) are still brought up to it."""
+        from ..parallel import ladder
+        if blocks is None:
+            n = arrays[0].shape[0]
+            to = ladder.rung(verb, n)
+        else:
+            n, to = blocks, arrays[0].shape[0]
+        return n, tuple(ladder.pad_blocks(a, to) for a in arrays)
+
+    @staticmethod
+    def _fetch(stage_cb, t1: float, n: int, *outputs):
+        """Stage "fetch" of the single-device jit path: the device→host
+        readback of the first n blocks of every output. A padded
+        launch's pad rows are cut off ON THE DEVICE, so no stream sees
+        one and none crosses back."""
+        if outputs[0].shape[0] != n:
+            from ..models.pipeline import head_blocks
+            outputs = head_blocks(outputs, n)
+        host = tuple(np.asarray(o) for o in outputs)
         if stage_cb is not None:
             import time as _time
             stage_cb("fetch", _time.perf_counter() - t1)
+        return host
+
+    def load_encode_program(self, blocks: int, cuts, algo) -> None:
+        """Lower and compile, without running them, the programs an
+        encode launch at rung `blocks` can need: the fused step through
+        the jitted entry point and call form `encode_and_hash_batch`
+        uses — so the loaded executable is the one a request hits — and
+        the cut of its outputs to each real count in `cuts`. Boot's
+        loader (parallel/ladder.load_encode) asks."""
+        import jax
+        from ..models.pipeline import head_blocks, put_step
+        kernel = self._device_hash_kernel(algo)
+        data = jax.ShapeDtypeStruct(
+            (blocks, self.k, self.shard_size), np.uint8)
+        step = put_step.lower(data, self.k, self.m, algo=kernel)
+        step.compile()
+        for n in cuts:
+            head_blocks.lower(tuple(step.out_info), n).compile()
 
     def encode_and_hash_batch(self, data: np.ndarray, algo,
-                              *, force: str = "", stage_cb=None):
+                              *, force: str = "", stage_cb=None,
+                              blocks: Optional[int] = None):
         """Fused device path for the PUT hot loop: one program computes
         parity AND every shard's HighwayHash256 digest (the reference's
         Erasure.Encode + streaming-bitrot work, cmd/erasure-encode.go:75 +
@@ -264,39 +308,46 @@ class Codec:
         readback of parity + digests) — the batch scheduler's dispatch
         attribution. The mesh path reports a single "compute" stage (its
         sharded programs return host arrays in one step).
+
+        The single-device launch runs at its ladder rung (`_on_rung`):
+        `blocks`, when given, says that `data` is padded to it already
+        and how many of its rows are real — true of every fused route
+        below, whose results hold the real blocks only.
         """
         import time as _time
         kernel = self._device_hash_kernel(algo)
         if kernel is None or self.m == 0:
             return None
-        mesh = self._mesh_route(data.nbytes, force)
+        real = data if blocks is None else data[:blocks]
+        mesh = self._mesh_route(real.nbytes, force)
         if mesh is not None:
             from ..parallel import mesh as pmesh
             t0 = _time.perf_counter()
-            out = pmesh.mesh_encode_and_hash(mesh, data, self.k, self.m,
+            out = pmesh.mesh_encode_and_hash(mesh, real, self.k, self.m,
                                              kernel)
             if out is not None:
                 if stage_cb is not None:
                     stage_cb("compute", _time.perf_counter() - t0)
                 return out
-        path = force or self._route(data.nbytes)
+        path = force or self._route(real.nbytes)
         if path != "device":
             return None
         from ..models.pipeline import put_step
+        n, (data,) = self._on_rung("encode", blocks, data)
         (dev,) = self._upload(stage_cb, data)
         t0 = _time.perf_counter()
         parity, digests = put_step(dev, self.k, self.m, algo=kernel)
         t1 = self._staged(stage_cb, t0, (parity, digests))
         # only parity + digests cross back from the device; the k data
         # rows stay the caller's own bytes, referenced and not copied
-        out = EncodedRows(data, np.asarray(parity)), np.asarray(digests)
-        self._fetched(stage_cb, t1)
-        return out
+        parity, digests = self._fetch(stage_cb, t1, n, parity, digests)
+        return EncodedRows(real, parity), digests
 
     def encrypt_encode_and_hash_batch(self, data: np.ndarray, keys,
                                       nonces, pkg_bytes: int, algo,
                                       *, force: str = "",
-                                      stage_cb=None):
+                                      stage_cb=None,
+                                      blocks: Optional[int] = None):
         """Fused device path for the ENCRYPTED PUT hot loop: ChaCha20
         cipher + parity + per-shard digests in one launch
         (models/pipeline.sse_put_step) — an encrypted batch costs the
@@ -315,25 +366,26 @@ class Codec:
         kernel = self._device_hash_kernel(algo)
         if kernel is None or self.m == 0:
             return None
-        path = force or self._route(data.nbytes)
+        real = data if blocks is None else data[:blocks]
+        path = force or self._route(real.nbytes)
         if path != "device":
             return None
         from ..models.pipeline import sse_put_step
-        dev, dkeys, dnonces = self._upload(stage_cb, data, keys, nonces)
+        n, fused = self._on_rung("encode", blocks, data, keys, nonces)
+        dev, dkeys, dnonces = self._upload(stage_cb, *fused)
         t0 = _time.perf_counter()
         full, digests = sse_put_step(dev, dkeys, dnonces, self.k,
                                      self.m, pkg_bytes, algo=kernel)
         t1 = self._staged(stage_cb, t0, (full, digests))
         # the data rows DO cross back here: the caller staged plaintext
         # and must write (and Poly1305-tag) the ciphertext
-        out = np.asarray(full), np.asarray(digests)
-        self._fetched(stage_cb, t1)
-        return out
+        return self._fetch(stage_cb, t1, n, full, digests)
 
     def verify_decode_decrypt_batch(self, survivors: np.ndarray,
                                     present_mask: int, shard_len: int,
                                     keys, nonces, pkg_bytes: int, algo,
-                                    *, force: str = "", stage_cb=None):
+                                    *, force: str = "", stage_cb=None,
+                                    blocks: Optional[int] = None):
         """Fused device path for the ENCRYPTED degraded GET: bitrot-
         verify survivors, reconstruct the missing data rows, and
         decipher the reassembled data shards in one launch
@@ -350,7 +402,8 @@ class Codec:
         kernel = self._device_hash_kernel(algo)
         if kernel is None:
             return None
-        path = force or self._route(survivors.nbytes)
+        real = survivors if blocks is None else survivors[:blocks]
+        path = force or self._route(real.nbytes)
         if path != "device":
             return None
         dm, used, missing = rs_matrix.missing_data_matrix(
@@ -365,22 +418,23 @@ class Codec:
             for j in range(self.k))
         m2 = rs_tpu._bit_expand_cached(dm.tobytes(), dm.shape)
         from ..models.pipeline import sse_get_step
-        dev, dkeys, dnonces = self._upload(stage_cb, survivors, keys,
-                                           nonces)
+        n, fused = self._on_rung("decode", blocks, survivors, keys,
+                                 nonces)
+        dev, dkeys, dnonces = self._upload(stage_cb, *fused)
         t0 = _time.perf_counter()
         plain, _ct_missing, digests = sse_get_step(
             dev, m2, dkeys, dnonces, dm.shape[0], self.k,
             data_src, pkg_bytes, shard_len, algo=kernel)
         t1 = self._staged(stage_cb, t0, (plain, digests))
-        result = np.asarray(plain), missing, np.asarray(digests)
-        self._fetched(stage_cb, t1)
-        return result
+        plain, digests = self._fetch(stage_cb, t1, n, plain, digests)
+        return plain, missing, digests
 
     # -- fused verify + decode / recover (device) --------------------------
 
     def verify_and_decode_batch(self, survivors: np.ndarray,
                                 present_mask: int, shard_len: int, algo,
-                                *, force: str = "", stage_cb=None):
+                                *, force: str = "", stage_cb=None,
+                                blocks: Optional[int] = None):
         """Fused device path for the degraded-GET hot loop: ONE program
         bitrot-hashes every survivor shard AND reconstructs only the
         missing data rows (models/pipeline.get_step — the device form of
@@ -396,18 +450,19 @@ class Codec:
         kernel = self._device_hash_kernel(algo)
         if kernel is None:
             return None
-        mesh = self._mesh_route(survivors.nbytes, force)
+        real = survivors if blocks is None else survivors[:blocks]
+        mesh = self._mesh_route(real.nbytes, force)
         if mesh is not None:
             from ..parallel import mesh as pmesh
             t0 = _time.perf_counter()
             out = pmesh.mesh_verify_and_decode(
-                mesh, survivors, self.k, self.m, present_mask,
+                mesh, real, self.k, self.m, present_mask,
                 shard_len, kernel)
             if out is not None:
                 if stage_cb is not None:
                     stage_cb("compute", _time.perf_counter() - t0)
                 return out
-        path = force or self._route(survivors.nbytes)
+        path = force or self._route(real.nbytes)
         if path != "device":
             return None
         dm, _used, missing = rs_matrix.missing_data_matrix(
@@ -416,19 +471,20 @@ class Codec:
             return None
         m2 = rs_tpu._bit_expand_cached(dm.tobytes(), dm.shape)
         from ..models.pipeline import get_step
+        n, (survivors,) = self._on_rung("decode", blocks, survivors)
         (dev,) = self._upload(stage_cb, survivors)
         t0 = _time.perf_counter()
         out, digests = get_step(dev, m2, dm.shape[0], self.k,
                                 shard_len, algo=kernel)
         t1 = self._staged(stage_cb, t0, (out, digests))
-        result = np.asarray(out), missing, np.asarray(digests)
-        self._fetched(stage_cb, t1)
-        return result
+        out, digests = self._fetch(stage_cb, t1, n, out, digests)
+        return out, missing, digests
 
     def verify_and_recover_batch(self, survivors: np.ndarray,
                                  present_mask: int, rows: "set[int]",
                                  shard_len: int, algo, *,
-                                 force: str = "", stage_cb=None):
+                                 force: str = "", stage_cb=None,
+                                 blocks: Optional[int] = None):
         """Fused device path for heal: verify survivors, rebuild exactly
         the requested lost rows, and digest the rebuilt shards for their
         new bitrot frames (models/pipeline.heal_step).
@@ -440,18 +496,19 @@ class Codec:
         kernel = self._device_hash_kernel(algo)
         if kernel is None:
             return None
-        mesh = self._mesh_route(survivors.nbytes, force)
+        real = survivors if blocks is None else survivors[:blocks]
+        mesh = self._mesh_route(real.nbytes, force)
         if mesh is not None:
             from ..parallel import mesh as pmesh
             t0 = _time.perf_counter()
             out = pmesh.mesh_verify_and_recover(
-                mesh, survivors, self.k, self.m, present_mask, rows,
+                mesh, real, self.k, self.m, present_mask, rows,
                 shard_len, kernel)
             if out is not None:
                 if stage_cb is not None:
                     stage_cb("compute", _time.perf_counter() - t0)
                 return out
-        path = force or self._route(survivors.nbytes)
+        path = force or self._route(real.nbytes)
         if path != "device":
             return None
         rec, idxs = self._recover_rows(present_mask, rows)
@@ -459,15 +516,14 @@ class Codec:
             return None
         m2 = rs_tpu._bit_expand_cached(rec.tobytes(), rec.shape)
         from ..models.pipeline import heal_step
+        n, (survivors,) = self._on_rung("recover", blocks, survivors)
         (dev,) = self._upload(stage_cb, survivors)
         t0 = _time.perf_counter()
         out, sdig, odig = heal_step(dev, m2, rec.shape[0], self.k,
                                     shard_len, algo=kernel)
         t1 = self._staged(stage_cb, t0, (out, sdig, odig))
-        result = (np.asarray(out), idxs, np.asarray(sdig),
-                  np.asarray(odig))
-        self._fetched(stage_cb, t1)
-        return result
+        out, sdig, odig = self._fetch(stage_cb, t1, n, out, sdig, odig)
+        return out, idxs, sdig, odig
 
     def _recover_rows(self, present_mask: int, rows: "set[int]"
                       ) -> tuple[np.ndarray, list[int]]:

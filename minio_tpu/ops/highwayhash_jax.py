@@ -198,8 +198,18 @@ def _init_state(key: bytes, g: int, cols: int):
     return st
 
 
+@jax.jit
 def _update(st, pe, po):
-    """One packet round. pe/po: u64 pairs of (2G, N/G) — even/odd lanes."""
+    """One packet round. pe/po: u64 pairs of (2G, N/G) — even/odd lanes.
+
+    Jitted on its own so that a fused step holds ONE copy of the
+    round's ~330 u32 ops and calls it from every unrolled packet
+    (16 a scan step, the packets left over, the remainder, the
+    finalize loop: 28 at S = 349526) instead of 28 inlined copies:
+    XLA inlines the calls before it optimises, so the device program
+    is the same, but tracing and lowering a step — Python under the
+    GIL, 2.2 s a program on a v5e host, which a node loading its
+    launch ladder at boot pays ten times — fall to a tenth."""
     v0e, v0o = st["v0e"], st["v0o"]
     v1e, v1o = st["v1e"], st["v1o"]
     mul0e, mul0o = st["mul0e"], st["mul0o"]

@@ -28,6 +28,12 @@ device call through object/codec.py — which routes to parallel/mesh.py
 back. Coalescing N streams' work into one call amortizes the
 per-dispatch launch + transfer cost and keeps MXU batches full.
 
+Launch sizes are a closed ladder (parallel/ladder.py): an erasure
+launch runs at the rung of its block count — padded with zero blocks in
+the slot's staging buffer, the pad cut off on the device before the
+readback — so a geometry launches a finite set of programs, which boot
+loads. No future and no block counter ever sees a pad block.
+
 Occupancy smarts (PR 6):
   * a bucket that already holds >= max_batch blocks dispatches
     IMMEDIATELY instead of sleeping the grace window;
@@ -56,6 +62,7 @@ import numpy as np
 
 from ..object.codec import Codec, parity_rows
 from ..utils import eventlog, knobs, lockcheck, telemetry
+from . import ladder
 
 MAX_BATCH_BLOCKS = knobs.get_int("MINIO_TPU_SCHED_MAX_BATCH")
 MAX_WAIT_S = knobs.get_float("MINIO_TPU_SCHED_MAX_WAIT_MS") / 1e3
@@ -217,9 +224,12 @@ class BatchScheduler:
         self.batches = 0              # dispatch counter (tests/metrics)
         self.coalesced = 0            # groups that shared a dispatch
         self.dispatched_blocks = 0    # blocks through the device path
-        # staged_bytes: gathered into a staging buffer before upload (0
-        # for a one-group launch); fetched_bytes: what crossed back
+        # blocks: real ones; pad_blocks: the zero blocks that brought
+        # launches up to their ladder rung; staged_bytes: gathered
+        # into a staging buffer before upload, pad included (0 for a
+        # one-group launch on a rung); fetched_bytes: what crossed back
         self.verb_stats = {v: {"batches": 0, "coalesced": 0, "blocks": 0,
+                               "pad_blocks": 0,
                                "cpu_routed": 0, "errors": 0,
                                "staged_bytes": 0, "fetched_bytes": 0}
                            for v in VERBS}
@@ -539,14 +549,15 @@ class BatchScheduler:
             stages[stage] = (
                 time.perf_counter_ns() - int(seconds * 1e9), seconds)
         t0_ns = time.perf_counter_ns()
-        staged = fetched = 0
+        staged = fetched = pad = 0
+        nb = sum(p.blocks for p in group)
         # what moved, on the erasure stages' spans
         stage_attrs: dict[str, dict] = {}
         if verb == "scan":
             out = self._run_scan(group, stage_cb if attrib else None)
         else:
-            out, staged = self._run_erasure(
-                key, group, stage_cb if attrib else None)
+            out, staged, pad = self._run_erasure(
+                key, group, nb, stage_cb if attrib else None)
             if out is not None:
                 if verb == "encode" and key[5] is None:
                     # plain route: the device made parity and digests;
@@ -556,10 +567,10 @@ class BatchScheduler:
                 fetched = sum(a.nbytes for a in out
                               if isinstance(a, np.ndarray))
             stage_attrs = {
-                "transfer": {"groups": len(group), "bytes": staged},
+                "transfer": {"groups": len(group), "bytes": staged,
+                             "rung": nb + pad, "pad_blocks": pad},
                 "fetch": {"bytes": fetched}}
         t1_ns = time.perf_counter_ns()
-        nb = sum(p.blocks for p in group)
         # a dispatch that DECLINED to the device (out is None: CPU
         # routing) launched nothing: it must feed neither the dispatch
         # counters nor the device-dispatch histogram — a box would
@@ -575,6 +586,7 @@ class BatchScheduler:
                 vs["batches"] += 1
                 vs["coalesced"] += len(group) - 1
                 vs["blocks"] += nb
+                vs["pad_blocks"] += pad
                 vs["staged_bytes"] += staged
                 vs["fetched_bytes"] += fetched
             else:
@@ -649,20 +661,27 @@ class BatchScheduler:
             at += b
             p.event.set()
 
-    def _run_erasure(self, key: tuple, group: list, stage_cb=None):
-        """One fused codec call over the group -> (result or None,
-        bytes gathered into a staging buffer). A launch of one group
-        uploads its data as it is; several are copied, once, into a
-        buffer from the free list, which goes back when the codec call
-        has returned: its results are fetched by then, so the input is
-        consumed — also when the upload was not waited for, or
-        (XLA-CPU) aliased this memory."""
+    def _run_erasure(self, key: tuple, group: list, nb: int,
+                     stage_cb=None):
+        """One fused codec call over the group's nb blocks, at their
+        ladder rung -> (result or None, bytes gathered into a staging
+        buffer, pad blocks). A launch of one group that sits on a rung
+        uploads its data as it is; any other is a gathered launch: its
+        groups are copied, once, into a buffer from the free list and
+        zero blocks fill it up to the rung. The buffer goes back when
+        the codec call has returned: its results are fetched by then,
+        so the input is consumed — also when the upload was not waited
+        for, or (XLA-CPU) aliased this memory. The mesh route shards
+        its batches itself and is handed them unpadded."""
         t0 = time.perf_counter()
         buf = None
-        if len(group) == 1:
+        rung = ladder.rung(key[0], nb, self.max_batch) \
+            if _mesh_dp() == 1 else nb
+        if len(group) == 1 and rung == nb:
             data, staged = group[0].data, 0
         else:
-            staged = sum(p.data.nbytes for p in group)
+            block = group[0].data[0].nbytes
+            staged = rung * block
             with self._mu:
                 buf = self._staging.pop() if self._staging else None
             if buf is None or buf.size < staged:
@@ -672,17 +691,19 @@ class BatchScheduler:
             for p in group:
                 data[at:at + p.blocks] = p.data
                 at += p.blocks
+            data[nb:] = 0
         try:
             if stage_cb is not None:
                 stage_cb("transfer", time.perf_counter() - t0)
-            return self._run_codec(key, group, data, stage_cb), staged
+            return (self._run_codec(key, group, data, nb, stage_cb),
+                    staged, rung - nb)
         finally:
             if buf is not None:
                 with self._mu:
                     self._staging.append(buf)
 
     @staticmethod
-    def _run_codec(key: tuple, group: list, data: np.ndarray,
+    def _run_codec(key: tuple, group: list, data: np.ndarray, nb: int,
                    stage_cb=None):
         from .. import bitrot as bitrot_mod
         verb, k, m, s, algo_value, extra = key
@@ -702,22 +723,25 @@ class BatchScheduler:
                 keys, nonces = _sse_arrays()
                 return codec.encrypt_encode_and_hash_batch(
                     data, keys, nonces, extra[2], algo,
-                    stage_cb=stage_cb)
+                    stage_cb=stage_cb, blocks=nb)
             return codec.encode_and_hash_batch(data, algo,
-                                               stage_cb=stage_cb)
+                                               stage_cb=stage_cb,
+                                               blocks=nb)
         if verb == "decode":
             if len(extra) > 2 and extra[2] == "sse":
                 keys, nonces = _sse_arrays()
                 return codec.verify_decode_decrypt_batch(
                     data, extra[0], extra[1], keys, nonces, extra[4],
-                    algo, stage_cb=stage_cb)
+                    algo, stage_cb=stage_cb, blocks=nb)
             mask, shard_len = extra
             return codec.verify_and_decode_batch(data, mask, shard_len,
-                                                 algo, stage_cb=stage_cb)
+                                                 algo, stage_cb=stage_cb,
+                                                 blocks=nb)
         mask, rows, shard_len = extra
         return codec.verify_and_recover_batch(data, mask, set(rows),
                                               shard_len, algo,
-                                              stage_cb=stage_cb)
+                                              stage_cb=stage_cb,
+                                              blocks=nb)
 
     @staticmethod
     def _run_scan(group: list, stage_cb=None):

@@ -1,8 +1,10 @@
 """The seam the benchmark's controls stand on. `benchmark/benchlib/
 faults.py` plants its encode faults by wrapping `Codec.
-encode_and_hash_batch`, treating the first result as a plain
-(B, k+m, S) ndarray and handing two ndarrays back. Whatever shape the
-program gives that result, a planted fault must still reach the drives
+encode_and_hash_batch` and touching the parity and digests its result
+carries (a `.parity` attribute, a bare (B, m, S) array, or the rows
+past k of a join). Whatever shape the program gives that result — and
+at whatever ladder rung the launch ran, its pad blocks cut off — a
+planted fault must still reach the drives
 through an engine with a batch former — else the PUT cells' controls go
 blind and `correct` stops meaning anything. The plain reference
 (`benchlib/reference.py`) says what a sound PUT leaves on the drives."""
