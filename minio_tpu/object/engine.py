@@ -842,9 +842,10 @@ class ErasureObjects:
         [digest‖block] frames in one call (cmd/erasure-encode.go:38-72's
         per-disk goroutine — but fanned out once per encode batch, not
         once per block: B× fewer pool tasks, and the frames are handed
-        over as memoryviews of the encode output, copy-free until the
-        writer's own buffer). Data and parity arrive as separate arrays
-        so the data rows stay views of the read buffer."""
+        over as rows of the encode output, which a local drive takes in
+        one vectored write, copy-free down to the kernel). Data and
+        parity arrive as separate arrays so the data rows stay views of
+        the read buffer."""
         B, k = data.shape[0], data.shape[1]
 
         def write(i: int, w) -> None:
@@ -852,9 +853,13 @@ class ErasureObjects:
                 (parity, dp, i - k)
             with telemetry.timed("disk.shard_write", disk=i,
                                  blocks=B) as t:
-                for bi in range(B):
-                    w.write_with_digest(rows[bi, j].data,
-                                        digs[bi, j].data)
+                # a row of the ring or of the fetched parity is
+                # C-contiguous as it is; one that is not is copied
+                # alone, never the group
+                writes, vectored = w.write_frames(
+                    [np.ascontiguousarray(rows[bi, j]) for bi in range(B)],
+                    [np.ascontiguousarray(digs[bi, j]) for bi in range(B)])
+                t.annotate(writes=writes, vectored=int(vectored))
             healthtrack.observe_disk(w.disk, "write", t.seconds)
 
         # quorum-ack lane: once write-quorum writers are durable, a

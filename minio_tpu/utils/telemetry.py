@@ -700,6 +700,11 @@ class _Timed:
             else time.perf_counter_ns()
         return self
 
+    def annotate(self, **attrs) -> None:
+        """Attributes known only once the interval's work has run."""
+        if self._span is not None:
+            self._span.attrs.update(attrs)
+
     def __exit__(self, exc_type, exc, tb) -> bool:
         self._ctx.__exit__(exc_type, exc, tb)
         self.seconds = self._span.duration_s if self._span is not None \

@@ -53,7 +53,8 @@ def payload(size: int, seed: int = 7) -> bytes:
 
 
 def make_chaos_sets(tmp_path, schedules: dict,
-                    n: int = NDISKS, parity: int = M
+                    n: int = NDISKS, parity: int = M,
+                    wrapper=NaughtyDisk
                     ) -> tuple[ErasureSets, list[NaughtyDisk]]:
     """1 set x n drives; drives named in `schedules` get a (disarmed)
     NaughtyDisk wrapper — arm after the fixture is built."""
@@ -63,7 +64,7 @@ def make_chaos_sets(tmp_path, schedules: dict,
         d = XLStorage(str(tmp_path / f"d{j}"))
         sched = schedules.get(j)
         if sched is not None:
-            nd = NaughtyDisk(d, schedule=sched, enabled=False)
+            nd = wrapper(d, schedule=sched, enabled=False)
             naughty.append(nd)
             drives.append(nd)
         else:
@@ -178,6 +179,73 @@ def test_chaos_pipelined_put_writer_death_mid_batch(tmp_path,
         assert nd.stats.calls.get("append_file", 0) >= 5
         stats = sets.mrf_stats()
         assert stats["queued"] >= 1        # degraded write fed MRF
+        assert_converged(sets, {"o": data})
+    finally:
+        sets.close()
+
+
+class LocalNaughtyDisk(NaughtyDisk):
+    """A NaughtyDisk that hands out the inner drive's append handle, as
+    a local drive does (a plain NaughtyDisk has none, so its writers
+    take the remote append_file path): every vectored write counts as
+    verb `writev`, and a programmed error lets the kernel take the
+    first buffer before it raises — a drive filling up mid-group."""
+
+    def has_appender(self) -> bool:
+        return True
+
+    def open_appender(self, volume, path):
+        self._begin("open_appender")
+        return _NaughtyAppender(self, self.inner.open_appender(volume,
+                                                               path))
+
+
+class _NaughtyAppender:
+    def __init__(self, disk, handle):
+        self._disk, self._handle = disk, handle
+        self.vectored = handle.vectored
+
+    def writev(self, buffers) -> int:
+        try:
+            self._disk._begin("writev")
+        except serr.StorageError as e:
+            self._handle.writev(buffers[:1])
+            raise OSError(28, f"No space left on device ({e})") from e
+        return self._handle.writev(buffers)
+
+    def close(self) -> None:
+        self._handle.close()
+
+
+def test_chaos_pipelined_put_vectored_write_dies_mid_stream(tmp_path,
+                                                            monkeypatch):
+    """The fan-out's semantics over the vectored write: a LOCAL drive
+    whose third group write fails part-way (a prefix taken, then
+    ENOSPC) costs the PUT nothing at quorum, is dropped from every
+    later group, is counted by the commit, feeds MRF and heals back to
+    byte-identical shards."""
+    from minio_tpu.object import engine as engine_mod
+    from minio_tpu.parallel import pipeline as pl
+    assert pl.ENABLED
+    monkeypatch.setattr(engine_mod, "ENCODE_BATCH_BLOCKS", 2)
+    seed = chaos_seed(2203)
+    announce(seed)
+    sets, naughty = make_chaos_sets(
+        tmp_path, {0: FaultSchedule(seed=seed), 1: FaultSchedule(seed=seed)},
+        wrapper=LocalNaughtyDisk)
+    try:
+        bad, good = naughty
+        bad.arm()
+        good.arm()
+        bad.verb_errors["writev"] = {3: serr.FaultyDisk("mid-stream")}
+        data = payload(10 * BLOCK + 1234, seed=seed)
+        sets.put_object("b", "o", data)
+        groups = good.stats.calls["writev"]
+        assert groups >= 5                  # 5 groups of 2 and the tail
+        assert bad.stats.calls["writev"] == 3   # none after its failure
+        assert sets.mrf_stats()["queued"] >= 1
+        _, it = sets.get_object("b", "o")
+        assert b"".join(it) == data
         assert_converged(sets, {"o": data})
     finally:
         sets.close()

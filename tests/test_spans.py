@@ -114,6 +114,35 @@ def test_timed_and_accum():
     assert len(root.children) == 2
 
 
+def test_shard_write_span_says_one_vectored_write_a_group(tmp_path):
+    """`disk.shard_write` names the mechanism: on a local drive a whole
+    group of B frames is writes=1, vectored=1 (it was 2 x B buffered
+    writes), and the two /metrics counters read 1 syscall a B frames."""
+    from minio_tpu.object.sets import ErasureSets
+    reg = telemetry.REGISTRY
+    syscalls = reg.counter("minio_tpu_shard_write_syscalls_total")
+    frames = reg.counter("minio_tpu_shard_write_frames_total")
+    sets = ErasureSets.from_drives(
+        [str(tmp_path / f"d{i}") for i in range(6)], 1, 6, 2,
+        block_size=1 << 16)
+    try:
+        sets.make_bucket("b")
+        before = syscalls.value(), frames.value()
+        with telemetry.trace("put") as root:
+            sets.put_object("b", "o", os.urandom(16 * (1 << 16)))
+        spans = [sp for sp in root.walk() if sp.name == "disk.shard_write"]
+        blocks = sum(sp.attrs["blocks"] for sp in spans)
+        assert blocks == 6 * 16 and len(spans) < blocks  # groups, not frames
+        assert {sp.attrs["disk"] for sp in spans} == set(range(6))
+        for sp in spans:
+            assert (sp.attrs["writes"], sp.attrs["vectored"]) == (1, 1), \
+                sp.attrs
+        assert syscalls.value() - before[0] == len(spans)
+        assert frames.value() - before[1] == blocks
+    finally:
+        sets.close()
+
+
 # ---------------------------------------------------------------------------
 # the window recorder
 # ---------------------------------------------------------------------------

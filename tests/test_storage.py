@@ -345,6 +345,16 @@ def test_shard_file_math():
 # cmd/fallocate_linux.go)
 # ---------------------------------------------------------------------------
 
+def _fs_has_o_direct(tmp_path) -> bool:
+    try:
+        fd = os.open(str(tmp_path / "o_direct_probe"),
+                     os.O_WRONLY | os.O_CREAT | os.O_DIRECT)
+    except OSError:
+        return False
+    os.close(fd)
+    return True
+
+
 def test_direct_io_aligned_writer_roundtrip(tmp_path):
     """The O_DIRECT appender produces byte-identical files across
     alignment edge cases (page-multiple, sub-page tail, tiny writes)."""
@@ -372,17 +382,9 @@ def test_direct_io_aligned_writer_roundtrip(tmp_path):
     w = drive.open_appender("v", "probe")
     engaged = isinstance(w, xs._DirectWriter)
     w.close()
-    import os as _os
     # ext4 supports O_DIRECT; only skip the engagement assert on
     # filesystems that don't
-    try:
-        fd = _os.open(str(tmp_path / "o_direct_probe"),
-                      _os.O_WRONLY | _os.O_CREAT | _os.O_DIRECT)
-        _os.close(fd)
-        supports = True
-    except OSError:
-        supports = False
-    assert engaged == supports
+    assert engaged == _fs_has_o_direct(tmp_path)
 
 
 def test_direct_io_appender_appends_like_buffered(tmp_path):
@@ -425,7 +427,7 @@ def test_direct_io_fallback_when_fs_refuses(tmp_path, monkeypatch):
     drive = xs.XLStorage(str(tmp_path / "d"), direct_io=True)
     drive.make_vol("v")
     w = drive.open_appender("v", "f")
-    assert isinstance(w, _io.IOBase)      # plain buffered file
+    assert isinstance(w, xs._Appender)    # the plain append handle
     w.write(b"payload")
     w.close()
     assert drive.read_all("v", "f") == b"payload"
@@ -455,13 +457,8 @@ def test_direct_io_full_engine_put_get(tmp_path):
     import os as _os
     import minio_tpu.storage.xl_storage as xs
     from minio_tpu.object.sets import ErasureSets
-    try:
-        fd = _os.open(str(tmp_path / "probe"),
-                      _os.O_WRONLY | _os.O_CREAT | _os.O_DIRECT)
-        _os.close(fd)
-    except OSError:
-        import pytest as _pytest
-        _pytest.skip("filesystem lacks O_DIRECT")
+    if not _fs_has_o_direct(tmp_path):
+        pytest.skip("filesystem lacks O_DIRECT")
     _os.environ["MINIO_TPU_DIRECT_IO"] = "on"
     try:
         sets = ErasureSets.from_drives(
@@ -475,3 +472,152 @@ def test_direct_io_full_engine_put_get(tmp_path):
         sets.close()
     finally:
         _os.environ.pop("MINIO_TPU_DIRECT_IO", None)
+
+
+# ---------------------------------------------------------------------------
+# one vectored write a drive a group: StreamingBitrotWriter.write_frames
+# over every kind of append handle
+# ---------------------------------------------------------------------------
+
+HANDLE_KINDS = ("plain", "fsync", "direct", "remote")
+SHARD_SIZES = (349526, 524288)          # 12+4 and 8+8 at 4 MiB blocks
+TAIL = 43691                            # a short last block
+
+
+def _drive_of_kind(kind, tmp_path, monkeypatch):
+    """(disk the writer is given, the XLStorage under it, handle class
+    expected — None where the writer has no handle)."""
+    import minio_tpu.storage.xl_storage as xs
+    from minio_tpu.storage.naughty import NaughtyDisk
+    if kind == "fsync":
+        monkeypatch.setenv("MINIO_TPU_FSYNC", "on")
+    drive = xs.XLStorage(str(tmp_path / "d"), direct_io=kind == "direct")
+    drive.make_vol("v")
+    if kind == "remote":
+        # no has_appender: the buffered append_file path remote drives take
+        return NaughtyDisk(drive), drive, None
+    if kind == "direct" and _fs_has_o_direct(tmp_path):
+        return drive, drive, xs._DirectWriter
+    return drive, drive, xs._Appender   # also the fallback without O_DIRECT
+
+
+def _frames(n, size, salt=0):
+    import numpy as np
+    rng = np.random.default_rng(size + salt)
+    base = rng.integers(0, 256, size + n, dtype=np.uint8)
+    blocks = [base[i:i + size] for i in range(n)]       # views, all differ
+    digests = [rng.integers(0, 256, 32, dtype=np.uint8) for _ in range(n)]
+    want = b"".join(d.tobytes() + b.tobytes()
+                    for d, b in zip(digests, blocks))
+    return blocks, digests, want
+
+
+def _writer(disk, name, size):
+    from minio_tpu.object.bitrot_io import StreamingBitrotWriter
+    return StreamingBitrotWriter(disk, "v", name, size,
+                                 bitrot.DEFAULT_BITROT_ALGORITHM)
+
+
+@pytest.mark.parametrize("nblocks", (1, 2, 8, 32))
+@pytest.mark.parametrize("kind", HANDLE_KINDS)
+def test_write_frames_is_byte_identical_to_frame_by_frame(
+        kind, nblocks, tmp_path, monkeypatch):
+    """A group written by one write_frames call, then a short tail, is
+    the file the per-frame call writes — on every handle kind; a local
+    handle takes the group in one syscall."""
+    disk, drive, handle = _drive_of_kind(kind, tmp_path, monkeypatch)
+    for size in SHARD_SIZES:
+        blocks, digests, want = _frames(nblocks, size)
+        tb, td, tail = _frames(1, TAIL, salt=1)
+        w = _writer(disk, f"group-{size}", size)
+        writes, vectored = w.write_frames(blocks, digests)
+        if handle is not None:
+            assert isinstance(w._file, handle)
+        w.write_frames(tb, td)
+        w.close()
+        one = _writer(disk, f"each-{size}", size)
+        for b, d in zip(blocks + tb, digests + td):
+            one.write_with_digest(b, d)
+        one.close()
+        assert drive.read_all("v", f"group-{size}") == want + tail
+        assert drive.read_all("v", f"each-{size}") == want + tail
+        if handle is not None and handle.vectored:
+            assert (writes, vectored) == (1, True)
+        else:
+            assert vectored is False
+
+
+def _short_writev(monkeypatch, limit_of_call):
+    """os.writev that takes at most limit_of_call(n) bytes on its n-th
+    call (None = all): what ENOSPC or a signal leaves behind."""
+    import minio_tpu.storage.xl_storage as xs
+    real = os.writev
+    calls = []
+
+    def fake(fd, bufs):
+        calls.append(sum(memoryview(b).nbytes for b in bufs))
+        limit = limit_of_call(len(calls))
+        if limit is None:
+            return real(fd, bufs)
+        if limit == 0:
+            return 0
+        flat = b"".join(memoryview(b).cast("B") for b in bufs)
+        return os.write(fd, flat[:limit])
+    monkeypatch.setattr(xs.os, "writev", fake)
+    return calls
+
+
+@pytest.mark.parametrize("cut", (
+    pytest.param(32 + 349526 + 7, id="inside-a-digest"),
+    pytest.param(3 * (32 + 349526) + 32 + 1000, id="inside-a-block"),
+    pytest.param(2 * (32 + 349526), id="on-a-frame-boundary"),
+    pytest.param(32, id="between-digest-and-block"),
+    pytest.param(-100003, id="every-call-short"),
+))
+def test_short_writev_count_loses_and_repeats_no_byte(cut, tmp_path,
+                                                      monkeypatch):
+    disk, drive, _ = _drive_of_kind("plain", tmp_path, monkeypatch)
+    blocks, digests, want = _frames(8, 349526)
+    calls = _short_writev(
+        monkeypatch, (lambda n: -cut) if cut < 0
+        else (lambda n: cut if n == 1 else None))
+    w = _writer(disk, "f", 349526)
+    writes, vectored = w.write_frames(blocks, digests)
+    w.close()
+    assert drive.read_all("v", "f") == want
+    assert writes == len(calls) and vectored
+    if cut > 0:
+        assert calls == [len(want), len(want) - cut]
+    else:
+        assert len(calls) == -(-len(want) // -cut)
+
+
+def test_writev_that_takes_nothing_is_a_faulty_disk(tmp_path, monkeypatch):
+    from minio_tpu.storage import errors as serr
+    disk, drive, _ = _drive_of_kind("plain", tmp_path, monkeypatch)
+    blocks, digests, want = _frames(2, 4096)
+    _short_writev(monkeypatch, lambda n: 100 if n == 1 else 0)
+    w = _writer(disk, "f", 4096)
+    with pytest.raises(serr.FaultyDisk):
+        w.write_frames(blocks, digests)
+    assert drive.read_all("v", "f") == want[:100]   # nothing re-sent
+
+
+@pytest.mark.parametrize("kind", HANDLE_KINDS[:3])
+def test_reopened_appender_still_appends(kind, tmp_path, monkeypatch):
+    """O_APPEND, never a truncate: a second handle on the same file adds
+    to it, by write and by writev, whatever the first left."""
+    disk, drive, _ = _drive_of_kind(kind, tmp_path, monkeypatch)
+    first = drive.open_appender("v", "f")
+    first.writev([b"a" * 4096, b"b" * 4096])
+    first.close()
+    again = drive.open_appender("v", "f")
+    again.writev([b"c" * 32, b"", b"d" * 1000])
+    assert again.write(b"e" * 10) == 10
+    again.close()
+    last = drive.open_appender("v", "f")            # unaligned size now
+    last.writev([b"f"])
+    last.close()
+    assert drive.read_all("v", "f") == (b"a" * 4096 + b"b" * 4096
+                                        + b"c" * 32 + b"d" * 1000
+                                        + b"e" * 10 + b"f")
