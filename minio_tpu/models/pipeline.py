@@ -1,28 +1,34 @@
 """Flagship device pipelines: the "models" of this framework.
 
 Where an ML framework has model families, an object store has data-path
-pipelines. Each is a jittable function over batched shard tensors:
+pipelines. Each is one jitted function over batched shard tensors, one
+launch a batch:
 
-  * EncodePipeline  — PUT hot loop: batch of blocks -> parity shards (+
-    per-shard bitrot digests). The device analog of the reference's
-    Erasure.Encode loop (cmd/erasure-encode.go:75-146).
-  * DecodePipeline  — GET-with-failures: survivor shards -> data shards
-    (cmd/erasure-decode.go Reconstruct semantics).
-  * HealPipeline    — decode->reencode in one matmul via the recover
-    matrix (cmd/erasure-lowlevel-heal.go:28-48 collapsed to a single
-    device op).
+  * put_step      — PUT hot loop: a batch of blocks -> parity shards +
+    every shard's bitrot digest (the reference's Erasure.Encode loop,
+    cmd/erasure-encode.go:75-146, with cmd/bitrot-streaming.go's hash).
+  * sse_put_step  — the same with the ChaCha20 cipher in front of it.
+  * get_step      — GET with failures: verify the survivor shards and
+    rebuild the missing data shards (cmd/erasure-decode.go).
+  * sse_get_step  — the same with the decipher behind it.
+  * heal_step     — verify, rebuild exactly the lost shards through the
+    recover matrix, and digest them for their new frames
+    (cmd/erasure-lowlevel-heal.go:28-48 collapsed to one device op).
+  * head_blocks   — the first n blocks of a step's outputs: where a
+    padded launch's pad rows end.
 
-All pipelines are shape-static per (k, m, S, B) and cached. The B
-ladder lives in parallel/ladder.py: a launch of any block count is
-padded with zero blocks up to its rung (by the batch former in its
-staging buffer, by object/codec.py on the direct route) and cut back
-on the device (`head_blocks`), so a geometry launches a closed set of
-programs at full-block S — which boot loads for the encode verb.
+object/codec.py's table of fused programs (`FUSED`) says how each is
+called; `Codec._launch` is their one caller. All are shape-static per
+(k, m, S, B) and cached. The B ladder lives in parallel/ladder.py: a
+launch of any block count is padded with zero blocks up to its rung (by
+the batch former in its staging buffer, by object/codec.py on the
+direct route) and cut back on the device (`head_blocks`), so a geometry
+launches a closed set of programs at full-block S — which boot loads
+for the encode verb.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
@@ -30,84 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import chacha20_jax, rs_matrix, rs_tpu
-
-
-@dataclasses.dataclass(frozen=True)
-class ECConfig:
-    """Erasure-set geometry: k data + m parity shards over blockSize-byte
-    blocks (reference defaults: block 4 MiB; this framework benches 1 MiB
-    per BASELINE config). Placement math delegates to
-    storage.datatypes.ErasureInfo so there is exactly one copy of the
-    cmd/erasure-coding.go:120-143 formulas."""
-    data_shards: int
-    parity_shards: int
-    block_size: int = 1 << 20
-
-    @property
-    def total_shards(self) -> int:
-        return self.data_shards + self.parity_shards
-
-    def _erasure_info(self):
-        from ..storage.datatypes import ErasureInfo
-        return ErasureInfo(data_blocks=self.data_shards,
-                           parity_blocks=self.parity_shards,
-                           block_size=self.block_size)
-
-    @property
-    def shard_size(self) -> int:
-        """Per-shard bytes of one full block (ceil division, zero-padded:
-        same split semantics as the reference codec)."""
-        return self._erasure_info().shard_size()
-
-    def shard_file_size(self, total_length: int) -> int:
-        return self._erasure_info().shard_file_size(total_length)
-
-    def shard_file_offset(self, start: int, length: int, total: int) -> int:
-        return self._erasure_info().shard_file_offset(start, length, total)
-
-
-# ---------------------------------------------------------------------------
-# Encode
-# ---------------------------------------------------------------------------
-
-def encode_blocks(data: jax.Array | np.ndarray, cfg: ECConfig,
-                  *, use_pallas: bool | None = None) -> jax.Array:
-    """(B, k, S) data shards -> (B, m, S) parity shards on device."""
-    return rs_tpu.apply_matrix(
-        np.asarray(rs_matrix.parity_matrix(cfg.data_shards,
-                                           cfg.parity_shards)),
-        data, use_pallas=use_pallas)
-
-
-def encode_blocks_full(data, cfg: ECConfig, *,
-                       use_pallas: bool | None = None) -> jax.Array:
-    """(B, k, S) -> (B, n, S): data with parity appended (GET-comparable
-    to the host oracle byte-for-byte)."""
-    data = jnp.asarray(data, jnp.uint8)
-    parity = encode_blocks(data, cfg, use_pallas=use_pallas)
-    return jnp.concatenate([data, parity], axis=-2)
-
-
-# ---------------------------------------------------------------------------
-# Decode / heal
-# ---------------------------------------------------------------------------
-
-def decode_blocks(survivors, present_mask: int, cfg: ECConfig,
-                  *, use_pallas: bool | None = None) -> jax.Array:
-    """(B, k, S) stacked survivor shards (in decode_matrix `used` order)
-    -> (B, k, S) data shards."""
-    return rs_tpu.reconstruct_data(
-        survivors, present_mask, cfg.data_shards, cfg.parity_shards,
-        use_pallas=use_pallas)
-
-
-def heal_blocks(survivors, present_mask: int, cfg: ECConfig,
-                *, use_pallas: bool | None = None) -> jax.Array:
-    """(B, k, S) survivors -> (B, |missing|, S): exactly the lost shards,
-    one fused matmul (decode+reencode collapsed)."""
-    return rs_tpu.recover_missing(
-        survivors, present_mask, cfg.data_shards, cfg.parity_shards,
-        use_pallas=use_pallas)
 
 
 # ---------------------------------------------------------------------------

@@ -15,10 +15,13 @@ Both produce byte-identical shards (tests/test_rs_tpu.py oracle checks).
 
 from __future__ import annotations
 
-from typing import Optional
+import time
+from typing import Callable, NamedTuple, Optional
 
+import jax
 import numpy as np
 
+from ..models import pipeline
 from ..ops import gf256, rs_matrix, rs_ref, rs_tpu
 from ..utils import device, knobs, native
 
@@ -61,33 +64,85 @@ def data_path_line() -> str:
             f"{kernel}")
 
 
-class EncodedRows:
-    """First element of the plain encode route's result: the parity the
-    device made and a reference to the caller's own data rows — no
-    (B, k+m, S) array exists unless somebody asks for one. `np.array()`
-    / `np.asarray()` answer with the join [data ‖ parity] (the shape
-    the tuple had before PR 26), which is what lets a wrapper that
-    treats the result as a plain ndarray keep working; the batch former
-    and the engine read `.parity` and never pay for the join."""
+class _Fused(NamedTuple):
+    """One fused device program of the data path: what differs between
+    the five, and nothing else — `Codec._launch` holds what they share.
+    `FUSED` names a row by the Codec method that enters it; `static`
+    below is that method's arguments between the data and the bitrot
+    algorithm, the per-row arrays left out.
 
-    __slots__ = ("data", "parity")
+    step       jitted step in models/pipeline.py, looked up there at
+               call time
+    verb       the launch ladder's, and the batch former's, verb
+    operands   (codec, *static) -> (shared, lead, tail), or None when
+               the launch has nothing to rebuild: the step's operands
+               before and after the per-row arrays (`_step_call`), and
+               the value every block of the result shares
+    mesh       sharded program in parallel/mesh.py, called with
+               (mesh, data, k, m, *static, kernel); "": it has none
+    keep       the step's outputs that cross back; (): all of them
+    shared_at  where `shared` joins the result; every other element
+               of it is per block
+    rows_at    where the per-row word arrays (keys, nonces) sit among
+               `static` in the method's signature
+    """
+    step: str
+    verb: str
+    operands: Callable
+    mesh: str = ""
+    keep: tuple = ()
+    shared_at: Optional[int] = None
+    rows_at: int = 0
 
-    def __init__(self, data: np.ndarray, parity: np.ndarray):
-        self.data = data
-        self.parity = parity
 
-    def __array__(self, dtype=None, copy=None):
-        full = np.concatenate([self.data, self.parity], axis=1)
-        return full if dtype is None else full.astype(dtype, copy=False)
+def _encode_operands(codec, *pkg_bytes):
+    # the steps build the parity matrix themselves, from k and m
+    return None, (), (codec.k, codec.m, *pkg_bytes)
 
 
-def parity_rows(rows, k: int) -> np.ndarray:
-    """(B, m, S) parity of an encode result's first element: an
-    EncodedRows' own array, or the rows past k of a plain (B, k+m, S)
-    join (the mesh route's result, a wrapped codec's)."""
-    if isinstance(rows, EncodedRows):
-        return rows.parity
-    return rows[:, k:]
+def _decode_operands(codec, present_mask: int, shard_len: int,
+                     *pkg_bytes):
+    dm, used, missing = rs_matrix.missing_data_matrix(
+        codec.k, codec.m, present_mask)
+    if not missing:
+        return None     # plain verify has no matmul to fuse with
+    src = ()
+    if pkg_bytes:
+        # static reassembly map: data shard j comes from the survivors
+        # stack (decode `used` order) or the reconstructed rows
+        # (`missing` order)
+        src = (tuple((0, used.index(j)) if j in used
+                     else (1, missing.index(j))
+                     for j in range(codec.k)),)
+    return (missing, (rs_tpu._bit_expand_cached(dm.tobytes(), dm.shape),),
+            (dm.shape[0], codec.k, *src, *pkg_bytes, shard_len))
+
+
+def _recover_operands(codec, present_mask: int, rows, shard_len: int):
+    rec, idxs = codec._recover_rows(present_mask, rows)
+    if not idxs:
+        return None
+    return (idxs, (rs_tpu._bit_expand_cached(rec.tobytes(), rec.shape),),
+            (rec.shape[0], codec.k, shard_len))
+
+
+FUSED = {
+    "encode_and_hash_batch": _Fused(
+        "put_step", "encode", _encode_operands,
+        mesh="mesh_encode_and_hash"),
+    "encrypt_encode_and_hash_batch": _Fused(
+        "sse_put_step", "encode", _encode_operands),
+    "verify_and_decode_batch": _Fused(
+        "get_step", "decode", _decode_operands,
+        mesh="mesh_verify_and_decode", shared_at=1),
+    # the step also returns the rebuilt CIPHERTEXT rows, which stay
+    "verify_decode_decrypt_batch": _Fused(
+        "sse_get_step", "decode", _decode_operands,
+        keep=(0, 2), shared_at=1, rows_at=2),
+    "verify_and_recover_batch": _Fused(
+        "heal_step", "recover", _recover_operands,
+        mesh="mesh_verify_and_recover", shared_at=1),
+}
 
 
 class Codec:
@@ -205,149 +260,128 @@ class Codec:
         return None
 
     @staticmethod
-    def _upload(stage_cb, *arrays):
-        """Stage "h2d" of the single-device jit path: put the fused
-        input on the device and wait for it, so the upload is timed
-        apart from the program (one extra host wake-up per launch; the
-        two were serial already). Without a callback the arrays go to
-        the step as they are — the hot path pays nothing."""
-        if stage_cb is None:
-            return arrays
-        import time as _time
-        import jax
-        t0 = _time.perf_counter()
-        on_device = jax.block_until_ready(
-            tuple(jax.device_put(a) for a in arrays))
-        stage_cb("h2d", _time.perf_counter() - t0)
-        return on_device
-
-    @staticmethod
-    def _staged(stage_cb, t0: float, outputs) -> float:
-        """Compute/fetch boundary for the single-device jit path: wait
-        for the device values and report "compute" (launch + program +
-        sync, from `t0`); the caller's numpy conversions (fetch =
-        device→host readback) run after and end in `_fetch`. No-op
-        without a callback."""
-        import time as _time
-        if stage_cb is None:
-            return 0.0
-        import jax
-        jax.block_until_ready(outputs)
-        t1 = _time.perf_counter()
-        stage_cb("compute", t1 - t0)
-        return t1
-
-    @staticmethod
-    def _on_rung(verb: str, blocks: Optional[int], *arrays):
-        """-> (n, arrays at the launch's rung). `blocks` None: the
-        arrays hold n real blocks each and are padded here, with zero
-        blocks, up to the ladder's rung for n (parallel/ladder.py). A
-        caller that gathered the launch itself (the batch former's
-        staging buffer) has padded already and says how many of the
-        rows are real; the small per-row arrays beside the data (SSE
-        key and nonce words) are still brought up to it."""
-        from ..parallel import ladder
-        if blocks is None:
-            n = arrays[0].shape[0]
-            to = ladder.rung(verb, n)
-        else:
-            n, to = blocks, arrays[0].shape[0]
-        return n, tuple(ladder.pad_blocks(a, to) for a in arrays)
-
-    @staticmethod
-    def _fetch(stage_cb, t1: float, n: int, *outputs):
-        """Stage "fetch" of the single-device jit path: the device→host
-        readback of the first n blocks of every output. A padded
-        launch's pad rows are cut off ON THE DEVICE, so no stream sees
-        one and none crosses back."""
-        if outputs[0].shape[0] != n:
-            from ..models.pipeline import head_blocks
-            outputs = head_blocks(outputs, n)
-        host = tuple(np.asarray(o) for o in outputs)
-        if stage_cb is not None:
-            import time as _time
-            stage_cb("fetch", _time.perf_counter() - t1)
-        return host
+    def _step_call(step, arrays, lead, tail, kernel):
+        """THE call form of the five fused steps: the data, the matrix
+        operand where the step takes one, the per-row arrays, the
+        static operands. `step` is the jitted function (a launch) or
+        its `.lower` (boot's load), so both make the same program."""
+        return step(arrays[0], *lead, *arrays[1:], *tail, algo=kernel)
 
     def load_encode_program(self, blocks: int, cuts, algo) -> None:
         """Lower and compile, without running them, the programs an
         encode launch at rung `blocks` can need: the fused step through
-        the jitted entry point and call form `encode_and_hash_batch`
-        uses — so the loaded executable is the one a request hits — and
-        the cut of its outputs to each real count in `cuts`. Boot's
-        loader (parallel/ladder.load_encode) asks."""
-        import jax
-        from ..models.pipeline import head_blocks, put_step
-        kernel = self._device_hash_kernel(algo)
+        the table row and call form `_launch` uses — so the loaded
+        executable is the one a request hits — and the cut of its
+        outputs to each real count in `cuts`. Boot's loader
+        (parallel/ladder.load_encode) asks."""
+        row = FUSED["encode_and_hash_batch"]
+        _shared, lead, tail = row.operands(self)
         data = jax.ShapeDtypeStruct(
             (blocks, self.k, self.shard_size), np.uint8)
-        step = put_step.lower(data, self.k, self.m, algo=kernel)
+        step = self._step_call(getattr(pipeline, row.step).lower, (data,),
+                               lead, tail, self._device_hash_kernel(algo))
         step.compile()
         for n in cuts:
-            head_blocks.lower(tuple(step.out_info), n).compile()
+            pipeline.head_blocks.lower(tuple(step.out_info), n).compile()
 
-    def encode_and_hash_batch(self, data: np.ndarray, algo,
-                              *, force: str = "", stage_cb=None,
-                              blocks: Optional[int] = None):
+    def _launch(self, row: _Fused, data: np.ndarray, row_arrays, static,
+                algo, *, force: str = "", stage_cb=None,
+                blocks: Optional[int] = None):
+        """One launch of the fused program `row` names over data
+        (B, k, S), the per-row arrays beside it and the entry's static
+        arguments -> the entry's result tuple, or None when the batch
+        doesn't route to the device, the bitrot algorithm has no device
+        kernel, or the row has nothing to do.
+
+        force: "" auto-route, "device" (tests).
+
+        stage_cb(stage, seconds), when given, is called as each stage
+        ENDS: "h2d" (the fused input's upload, waited for — one extra
+        host wake-up per launch, so without a callback the arrays go
+        to the step as they are), "compute" (launch + device program +
+        sync) and "fetch" (the device→host readback of what crosses
+        back) — the batch scheduler's dispatch attribution. The mesh
+        route reports a single "compute" stage (its sharded programs
+        return host arrays in one step).
+
+        The single-device launch runs at its ladder rung
+        (parallel/ladder.py): the arrays are padded here, with zero
+        blocks, up to the rung of their block count. `blocks`, when
+        given, says that `data` is padded to it already (the batch
+        former's staging buffer) and how many of its rows are real; the
+        small per-row arrays are still brought up to it. A padded
+        launch's pad rows are cut off ON THE DEVICE, so no result
+        holds one and none crosses back."""
+        kernel = self._device_hash_kernel(algo)
+        if kernel is None:
+            return None
+        real = data if blocks is None else data[:blocks]
+        mesh = self._mesh_route(real.nbytes, force) if row.mesh else None
+        if mesh is not None:
+            from ..parallel import mesh as pmesh
+            t0 = time.perf_counter()
+            out = getattr(pmesh, row.mesh)(mesh, real, self.k, self.m,
+                                           *static, kernel)
+            if out is not None:
+                if stage_cb is not None:
+                    stage_cb("compute", time.perf_counter() - t0)
+                return out
+        if (force or self._route(real.nbytes)) != "device":
+            return None
+        operands = row.operands(self, *static)
+        if operands is None:
+            return None
+        shared, lead, tail = operands
+        from ..parallel import ladder
+        n, to = (data.shape[0], ladder.rung(row.verb, data.shape[0])) \
+            if blocks is None else (blocks, data.shape[0])
+        arrays = tuple(ladder.pad_blocks(a, to)
+                       for a in (data, *row_arrays))
+        if stage_cb is not None:
+            t0 = time.perf_counter()
+            arrays = jax.block_until_ready(
+                tuple(jax.device_put(a) for a in arrays))
+            stage_cb("h2d", time.perf_counter() - t0)
+        t0 = time.perf_counter()
+        outs = self._step_call(getattr(pipeline, row.step), arrays, lead,
+                               tail, kernel)
+        if row.keep:
+            outs = tuple(outs[i] for i in row.keep)
+        if stage_cb is not None:
+            jax.block_until_ready(outs)
+            t1 = time.perf_counter()
+            stage_cb("compute", t1 - t0)
+        if to != n:
+            outs = pipeline.head_blocks(outs, n)
+        host = tuple(np.asarray(o) for o in outs)
+        if stage_cb is not None:
+            stage_cb("fetch", time.perf_counter() - t1)
+        if row.shared_at is None:
+            return host
+        return (*host[:row.shared_at], shared, *host[row.shared_at:])
+
+    # The five entries: arguments -> table row + operands -> `_launch`,
+    # whose keyword arguments (force, stage_cb, blocks) they pass on.
+
+    def encode_and_hash_batch(self, data: np.ndarray, algo, **launch):
         """Fused device path for the PUT hot loop: one program computes
         parity AND every shard's HighwayHash256 digest (the reference's
         Erasure.Encode + streaming-bitrot work, cmd/erasure-encode.go:75 +
         cmd/bitrot-streaming.go:46, as a single device step).
 
-        data: (B, k, S). Returns (rows, digests (B, k+m, 32)), or None
-        when the batch doesn't route to the device or the bitrot
-        algorithm has no device kernel. `rows` is an EncodedRows —
-        parity (B, m, S) fetched from the device beside a reference to
-        `data`; the k data rows never cross back and are not copied —
-        or, on the mesh route, that route's own (B, k+m, S) join.
-        `parity_rows(rows, k)` reads either.
-
-        stage_cb(stage, seconds), when given, is called as each stage
-        ENDS: "h2d" (the fused input's upload, waited for), "compute"
-        (launch + device program + sync) and "fetch" (the device→host
-        readback of parity + digests) — the batch scheduler's dispatch
-        attribution. The mesh path reports a single "compute" stage (its
-        sharded programs return host arrays in one step).
-
-        The single-device launch runs at its ladder rung (`_on_rung`):
-        `blocks`, when given, says that `data` is padded to it already
-        and how many of its rows are real — true of every fused route
-        below, whose results hold the real blocks only.
+        data: (B, k, S). Returns (parity (B, m, S), digests
+        (B, k+m, 32)) on every route, or None. Only parity + digests
+        cross back from the device: the k data rows stay the caller's
+        own bytes.
         """
-        import time as _time
-        kernel = self._device_hash_kernel(algo)
-        if kernel is None or self.m == 0:
+        if self.m == 0:
             return None
-        real = data if blocks is None else data[:blocks]
-        mesh = self._mesh_route(real.nbytes, force)
-        if mesh is not None:
-            from ..parallel import mesh as pmesh
-            t0 = _time.perf_counter()
-            out = pmesh.mesh_encode_and_hash(mesh, real, self.k, self.m,
-                                             kernel)
-            if out is not None:
-                if stage_cb is not None:
-                    stage_cb("compute", _time.perf_counter() - t0)
-                return out
-        path = force or self._route(real.nbytes)
-        if path != "device":
-            return None
-        from ..models.pipeline import put_step
-        n, (data,) = self._on_rung("encode", blocks, data)
-        (dev,) = self._upload(stage_cb, data)
-        t0 = _time.perf_counter()
-        parity, digests = put_step(dev, self.k, self.m, algo=kernel)
-        t1 = self._staged(stage_cb, t0, (parity, digests))
-        # only parity + digests cross back from the device; the k data
-        # rows stay the caller's own bytes, referenced and not copied
-        parity, digests = self._fetch(stage_cb, t1, n, parity, digests)
-        return EncodedRows(real, parity), digests
+        return self._launch(FUSED["encode_and_hash_batch"], data, (), (),
+                            algo, **launch)
 
     def encrypt_encode_and_hash_batch(self, data: np.ndarray, keys,
                                       nonces, pkg_bytes: int, algo,
-                                      *, force: str = "",
-                                      stage_cb=None,
-                                      blocks: Optional[int] = None):
+                                      **launch):
         """Fused device path for the ENCRYPTED PUT hot loop: ChaCha20
         cipher + parity + per-shard digests in one launch
         (models/pipeline.sse_put_step) — an encrypted batch costs the
@@ -357,35 +391,21 @@ class Codec:
         (B, P, 3) u32 word arrays (features/crypto.DeviceSSE.
         batch_params — P·pkg_bytes plaintext bytes per row). Returns
         (full (B, k+m, S) — CIPHERTEXT data rows with parity appended,
-        digests (B, k+m, 32)), or None when the batch doesn't route to
-        the device (the caller's CPU cipher path is the oracle). The
-        mesh has no sse program yet, so mesh-routed hosts fall back to
-        the CPU path too.
+        digests (B, k+m, 32)), or None (the caller's CPU cipher path is
+        the oracle). The data rows DO cross back here: the caller staged
+        plaintext and must write (and Poly1305-tag) the ciphertext. The
+        mesh has no sse program: on a mesh host this routes as on any
+        other, to one device or to None.
         """
-        import time as _time
-        kernel = self._device_hash_kernel(algo)
-        if kernel is None or self.m == 0:
+        if self.m == 0:
             return None
-        real = data if blocks is None else data[:blocks]
-        path = force or self._route(real.nbytes)
-        if path != "device":
-            return None
-        from ..models.pipeline import sse_put_step
-        n, fused = self._on_rung("encode", blocks, data, keys, nonces)
-        dev, dkeys, dnonces = self._upload(stage_cb, *fused)
-        t0 = _time.perf_counter()
-        full, digests = sse_put_step(dev, dkeys, dnonces, self.k,
-                                     self.m, pkg_bytes, algo=kernel)
-        t1 = self._staged(stage_cb, t0, (full, digests))
-        # the data rows DO cross back here: the caller staged plaintext
-        # and must write (and Poly1305-tag) the ciphertext
-        return self._fetch(stage_cb, t1, n, full, digests)
+        return self._launch(FUSED["encrypt_encode_and_hash_batch"], data,
+                            (keys, nonces), (pkg_bytes,), algo, **launch)
 
     def verify_decode_decrypt_batch(self, survivors: np.ndarray,
                                     present_mask: int, shard_len: int,
                                     keys, nonces, pkg_bytes: int, algo,
-                                    *, force: str = "", stage_cb=None,
-                                    blocks: Optional[int] = None):
+                                    **launch):
         """Fused device path for the ENCRYPTED degraded GET: bitrot-
         verify survivors, reconstruct the missing data rows, and
         decipher the reassembled data shards in one launch
@@ -393,48 +413,19 @@ class Codec:
 
         survivors: (B, k, S) in missing_data_matrix `used` order.
         Returns (plain (B, k, S) deciphered data shards in shard-index
-        order, missing_idx, survivor_digests (B, k, 32)), or None when
-        not device-routed / no device hash kernel / nothing missing.
-        Package tags still verify host-side before any of this output
-        is served (features/crypto.chacha_decrypt_ranged discipline).
+        order, missing_idx, survivor_digests (B, k, 32)), or None
+        (also when nothing is missing). Package tags still verify
+        host-side before any of this output is served
+        (features/crypto.chacha_decrypt_ranged discipline).
         """
-        import time as _time
-        kernel = self._device_hash_kernel(algo)
-        if kernel is None:
-            return None
-        real = survivors if blocks is None else survivors[:blocks]
-        path = force or self._route(real.nbytes)
-        if path != "device":
-            return None
-        dm, used, missing = rs_matrix.missing_data_matrix(
-            self.k, self.m, present_mask)
-        if not missing:
-            return None
-        # static reassembly map: data shard j comes from the survivors
-        # stack (decode `used` order) or the reconstructed rows
-        # (`missing` order)
-        data_src = tuple(
-            (0, used.index(j)) if j in used else (1, missing.index(j))
-            for j in range(self.k))
-        m2 = rs_tpu._bit_expand_cached(dm.tobytes(), dm.shape)
-        from ..models.pipeline import sse_get_step
-        n, fused = self._on_rung("decode", blocks, survivors, keys,
-                                 nonces)
-        dev, dkeys, dnonces = self._upload(stage_cb, *fused)
-        t0 = _time.perf_counter()
-        plain, _ct_missing, digests = sse_get_step(
-            dev, m2, dkeys, dnonces, dm.shape[0], self.k,
-            data_src, pkg_bytes, shard_len, algo=kernel)
-        t1 = self._staged(stage_cb, t0, (plain, digests))
-        plain, digests = self._fetch(stage_cb, t1, n, plain, digests)
-        return plain, missing, digests
-
-    # -- fused verify + decode / recover (device) --------------------------
+        return self._launch(FUSED["verify_decode_decrypt_batch"], survivors,
+                            (keys, nonces),
+                            (present_mask, shard_len, pkg_bytes), algo,
+                            **launch)
 
     def verify_and_decode_batch(self, survivors: np.ndarray,
                                 present_mask: int, shard_len: int, algo,
-                                *, force: str = "", stage_cb=None,
-                                blocks: Optional[int] = None):
+                                **launch):
         """Fused device path for the degraded-GET hot loop: ONE program
         bitrot-hashes every survivor shard AND reconstructs only the
         missing data rows (models/pipeline.get_step — the device form of
@@ -442,88 +433,25 @@ class Codec:
 
         survivors: (B, k, S) stacked in missing_data_matrix `used` order.
         Returns (missing (B, r, S), missing_idx, survivor_digests
-        (B, k, 32)) as numpy arrays, or None when the batch doesn't route
-        to the device / the algorithm has no device kernel / nothing is
-        missing (plain verify has no matmul to fuse with).
+        (B, k, 32)) as numpy arrays, or None (also when nothing is
+        missing: plain verify has no matmul to fuse with).
         """
-        import time as _time
-        kernel = self._device_hash_kernel(algo)
-        if kernel is None:
-            return None
-        real = survivors if blocks is None else survivors[:blocks]
-        mesh = self._mesh_route(real.nbytes, force)
-        if mesh is not None:
-            from ..parallel import mesh as pmesh
-            t0 = _time.perf_counter()
-            out = pmesh.mesh_verify_and_decode(
-                mesh, real, self.k, self.m, present_mask,
-                shard_len, kernel)
-            if out is not None:
-                if stage_cb is not None:
-                    stage_cb("compute", _time.perf_counter() - t0)
-                return out
-        path = force or self._route(real.nbytes)
-        if path != "device":
-            return None
-        dm, _used, missing = rs_matrix.missing_data_matrix(
-            self.k, self.m, present_mask)
-        if not missing:
-            return None
-        m2 = rs_tpu._bit_expand_cached(dm.tobytes(), dm.shape)
-        from ..models.pipeline import get_step
-        n, (survivors,) = self._on_rung("decode", blocks, survivors)
-        (dev,) = self._upload(stage_cb, survivors)
-        t0 = _time.perf_counter()
-        out, digests = get_step(dev, m2, dm.shape[0], self.k,
-                                shard_len, algo=kernel)
-        t1 = self._staged(stage_cb, t0, (out, digests))
-        out, digests = self._fetch(stage_cb, t1, n, out, digests)
-        return out, missing, digests
+        return self._launch(FUSED["verify_and_decode_batch"], survivors, (),
+                            (present_mask, shard_len), algo, **launch)
 
     def verify_and_recover_batch(self, survivors: np.ndarray,
                                  present_mask: int, rows: "set[int]",
-                                 shard_len: int, algo, *,
-                                 force: str = "", stage_cb=None,
-                                 blocks: Optional[int] = None):
+                                 shard_len: int, algo, **launch):
         """Fused device path for heal: verify survivors, rebuild exactly
         the requested lost rows, and digest the rebuilt shards for their
         new bitrot frames (models/pipeline.heal_step).
 
         Returns (out (B, R, S), idxs, survivor_digests (B, k, 32),
-        out_digests (B, R, 32)) or None when not device-routed.
+        out_digests (B, R, 32)) or None.
         """
-        import time as _time
-        kernel = self._device_hash_kernel(algo)
-        if kernel is None:
-            return None
-        real = survivors if blocks is None else survivors[:blocks]
-        mesh = self._mesh_route(real.nbytes, force)
-        if mesh is not None:
-            from ..parallel import mesh as pmesh
-            t0 = _time.perf_counter()
-            out = pmesh.mesh_verify_and_recover(
-                mesh, real, self.k, self.m, present_mask, rows,
-                shard_len, kernel)
-            if out is not None:
-                if stage_cb is not None:
-                    stage_cb("compute", _time.perf_counter() - t0)
-                return out
-        path = force or self._route(real.nbytes)
-        if path != "device":
-            return None
-        rec, idxs = self._recover_rows(present_mask, rows)
-        if not idxs:
-            return None
-        m2 = rs_tpu._bit_expand_cached(rec.tobytes(), rec.shape)
-        from ..models.pipeline import heal_step
-        n, (survivors,) = self._on_rung("recover", blocks, survivors)
-        (dev,) = self._upload(stage_cb, survivors)
-        t0 = _time.perf_counter()
-        out, sdig, odig = heal_step(dev, m2, rec.shape[0], self.k,
-                                    shard_len, algo=kernel)
-        t1 = self._staged(stage_cb, t0, (out, sdig, odig))
-        out, sdig, odig = self._fetch(stage_cb, t1, n, out, sdig, odig)
-        return out, idxs, sdig, odig
+        return self._launch(FUSED["verify_and_recover_batch"], survivors,
+                            (), (present_mask, rows, shard_len), algo,
+                            **launch)
 
     def _recover_rows(self, present_mask: int, rows: "set[int]"
                       ) -> tuple[np.ndarray, list[int]]:

@@ -46,7 +46,7 @@ from ..storage.xl_storage import (MINIO_META_BUCKET,
                                   MINIO_META_MULTIPART_BUCKET,
                                   MINIO_META_TMP_BUCKET)
 from . import api_errors, bitrot_io, metadata as meta
-from .codec import Codec, parity_rows
+from .codec import Codec
 from .hash_reader import HashReader
 from .nslock import NSLockMap
 
@@ -719,10 +719,7 @@ class ErasureObjects:
             # streams into shared dispatches
             return self.scheduler.encode_and_hash(codec, data,
                                                   self.bitrot_algo)
-        fused = codec.encode_and_hash_batch(data, self.bitrot_algo)
-        if fused is None:
-            return None
-        return parity_rows(fused[0], codec.k), fused[1]
+        return codec.encode_and_hash_batch(data, self.bitrot_algo)
 
     def _unpack_fused(self, codec: Codec, data: np.ndarray, fused,
                       ciphertext: bool = False

@@ -317,7 +317,7 @@ def _pad_batch(data: np.ndarray, dp: int) -> tuple[np.ndarray, int]:
 def mesh_encode_and_hash(mesh: Mesh, data: np.ndarray, k: int, m: int,
                          algo: str = "highwayhash"):
     """Sharded form of Codec.encode_and_hash_batch: (B, k, S) ->
-    (full (B, k+m, S), digests (B, k+m, 32)) numpy, or None when the
+    (parity (B, m, S), digests (B, k+m, 32)) numpy, or None when the
     shapes can't shard over this mesh (caller falls through to the
     single-device path)."""
     b_, k_, s = data.shape
@@ -331,9 +331,7 @@ def mesh_encode_and_hash(mesh: Mesh, data: np.ndarray, k: int, m: int,
         arr = shard_array(mesh, data, P("dp", None, "sp"))
         parity, digests, _total = step(arr)
         DISPATCHES.bump()
-        full = np.concatenate([data[:b], np.asarray(parity)[:b]],
-                              axis=1)
-        return full, np.asarray(digests)[:b]
+        return np.asarray(parity)[:b], np.asarray(digests)[:b]
 
 
 def mesh_verify_and_decode(mesh: Mesh, survivors: np.ndarray, k: int,

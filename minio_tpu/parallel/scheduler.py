@@ -6,10 +6,14 @@ batches across CONCURRENT requests (BASELINE config #2: 32 concurrent
 + RAM-gated admission generalized into a device-batch former
 (cmd/erasure-sets.go:374, cmd/handler-api.go:46-57).
 
-PR 2 coalesced the PUT side only; the former is now a MULTI-VERB
-device dispatcher covering every fused program of the data path:
+The former is a multi-verb device dispatcher over every fused program
+of the data path. A program is defined in ONE place, the table
+`FUSED` in object/codec.py (its step, its verb, its operands, what
+rides beside the data, what comes back per block); the former knows a
+program by the name of the Codec method that enters it, and a verb as
+a label — the histogram's, `stats()["verbs"]`'s, the ladder's:
 
-  * ``encode``  — fused RS-encode + per-shard bitrot digest (PUT);
+  * ``encode``  — fused RS-encode + per-shard bitrot digest (PUT); and,
     with per-row cipher word arrays (sse=), fused ChaCha20 cipher +
     RS + digest — an encrypted batch is still ONE launch
   * ``decode``  — fused verify + reconstruct-missing-data (degraded
@@ -22,11 +26,12 @@ device dispatcher covering every fused program of the data path:
 
 Concurrent callers hand (B_i, k, S) block groups to the submit_*
 methods; a collector thread coalesces groups with identical
-(verb, geometry, algorithm, survivor-mask) into one fused (ΣB_i, k, S)
-device call through object/codec.py — which routes to parallel/mesh.py
-``mesh_*`` sharded programs when MINIO_TPU_MESH=1 — and scatters results
-back. Coalescing N streams' work into one call amortizes the
-per-dispatch launch + transfer cost and keeps MXU batches full.
+(program, geometry, algorithm, static arguments) into one fused
+(ΣB_i, k, S) device call through the program's Codec method — which
+routes to parallel/mesh.py ``mesh_*`` sharded programs when
+MINIO_TPU_MESH=1 — and scatters results back. Coalescing N streams'
+work into one call amortizes the per-dispatch launch + transfer cost
+and keeps MXU batches full.
 
 Launch sizes are a closed ladder (parallel/ladder.py): an erasure
 launch runs at the rung of its block count — padded with zero blocks in
@@ -34,7 +39,7 @@ the slot's staging buffer, the pad cut off on the device before the
 readback — so a geometry launches a finite set of programs, which boot
 loads. No future and no block counter ever sees a pad block.
 
-Occupancy smarts (PR 6):
+Occupancy:
   * a bucket that already holds >= max_batch blocks dispatches
     IMMEDIATELY instead of sleeping the grace window;
   * batch split points round down to multiples of the mesh ``dp`` axis
@@ -60,7 +65,7 @@ from typing import Optional
 
 import numpy as np
 
-from ..object.codec import Codec, parity_rows
+from ..object.codec import FUSED, Codec
 from ..utils import eventlog, knobs, lockcheck, telemetry
 from . import ladder
 
@@ -145,9 +150,11 @@ class _Pending:
 
     def __init__(self, data: Optional[np.ndarray] = None,
                  payload=None, blocks: Optional[int] = None):
-        # erasure verbs carry one (B, k, S) array; the scan verb
-        # carries its typed page arrays as an opaque payload — `blocks`
-        # is the occupancy unit either way (erasure blocks / pages)
+        # erasure verbs carry one (B, k, S) array and, as payload, the
+        # per-row arrays that ride beside it (none, or cipher words);
+        # the scan verb carries its typed page arrays as an opaque
+        # payload — `blocks` is the occupancy unit either way (erasure
+        # blocks / pages)
         self.data = data
         self.payload = payload
         self.blocks = int(data.shape[0]) if blocks is None else blocks
@@ -196,10 +203,6 @@ class DispatchFuture:
         return p.out
 
 
-# back-compat alias (PR 2 name; the PUT pipeline docstrings use it)
-EncodeFuture = DispatchFuture
-
-
 def _mesh_dp() -> int:
     """Batch-axis width of the active device mesh (1 = single device)."""
     from ..object.codec import _mesh_active
@@ -216,7 +219,8 @@ class BatchScheduler:
         self.max_batch = max_batch
         self.max_wait = max_wait
         self._mu = lockcheck.mutex("sched.buckets")
-        # (verb, k, m, S, algo_value, extra) -> list[_Pending]
+        # (verb, program, k, m, S, algo_value, static arguments,
+        # per-row array shapes) -> list[_Pending]
         self._buckets: dict[tuple, list[_Pending]] = {}
         self._bucket_blocks: dict[tuple, int] = {}
         self._kick = threading.Condition(self._mu)
@@ -319,9 +323,23 @@ class BatchScheduler:
                                reason="no-device")
         return declined
 
-    def _enqueue(self, key: tuple, data: np.ndarray) -> DispatchFuture:
-        return self._enqueue_pending(
-            key, _Pending(np.ascontiguousarray(data, np.uint8)))
+    def _enqueue(self, entry: str, codec, data: np.ndarray, algo,
+                 static: tuple = (), row_arrays: tuple = ()
+                 ) -> DispatchFuture:
+        """One (B, k, S) group for the fused program the Codec method
+        `entry` enters (codec.FUSED), with that method's static
+        arguments and the per-row arrays that ride beside the data.
+        The arrays ride the batch like the data does; the bucket key
+        carries only their GEOMETRY, so groups of different objects,
+        under different keys, coalesce into one launch."""
+        if self._declined(codec, algo):
+            return DispatchFuture()
+        key = (FUSED[entry].verb, entry, codec.k, codec.m, data.shape[-1],
+               algo.value, static, tuple(a.shape[1:] for a in row_arrays))
+        return self._enqueue_pending(key, _Pending(
+            np.ascontiguousarray(data, np.uint8),
+            payload=tuple(np.ascontiguousarray(a, np.uint32)
+                          for a in row_arrays)))
 
     def _enqueue_pending(self, key: tuple, p: _Pending) -> DispatchFuture:
         p.span = telemetry.current_span()
@@ -346,26 +364,14 @@ class BatchScheduler:
 
         sse = (keys (B, 8), nonces (B, P, 3), pkg_bytes) turns the
         dispatch into the fused cipher+RS+digest program (codec.
-        encrypt_encode_and_hash_batch): the word arrays ride the batch
-        like survivor masks do, but the bucket key carries only their
-        GEOMETRY (package count + size) — concurrent encrypted PUTs
-        from different objects, under different keys, coalesce into one
-        launch. The device changed the data rows, so the future then
-        resolves to (full (B, k+m, S), digests): CIPHERTEXT data rows
-        with parity appended."""
-        if self._declined(codec, algo):
-            return DispatchFuture()
+        encrypt_encode_and_hash_batch). The device changed the data
+        rows, so the future then resolves to (full (B, k+m, S),
+        digests): CIPHERTEXT data rows with parity appended."""
         if sse is None:
-            key = ("encode", codec.k, codec.m, data.shape[-1],
-                   algo.value, None)
-            return self._enqueue(key, data)
+            return self._enqueue("encode_and_hash_batch", codec, data, algo)
         keys, nonces, pkg_bytes = sse
-        key = ("encode", codec.k, codec.m, data.shape[-1], algo.value,
-               ("sse", nonces.shape[1], pkg_bytes))
-        p = _Pending(np.ascontiguousarray(data, np.uint8),
-                     payload=(np.ascontiguousarray(keys, np.uint32),
-                              np.ascontiguousarray(nonces, np.uint32)))
-        return self._enqueue_pending(key, p)
+        return self._enqueue("encrypt_encode_and_hash_batch", codec, data,
+                             algo, (pkg_bytes,), (keys, nonces))
 
     def submit_decode(self, codec, survivors: np.ndarray,
                       present_mask: int, shard_len: int, algo,
@@ -380,20 +386,14 @@ class BatchScheduler:
         the resolved first element is then the deciphered (B, k, S)
         data-shard stack in shard-index order instead of the missing
         ciphertext rows."""
-        if self._declined(codec, algo):
-            return DispatchFuture()
         if sse is None:
-            key = ("decode", codec.k, codec.m, survivors.shape[-1],
-                   algo.value, (present_mask, shard_len))
-            return self._enqueue(key, survivors)
+            return self._enqueue("verify_and_decode_batch", codec,
+                                 survivors, algo, (present_mask, shard_len))
         keys, nonces, pkg_bytes = sse
-        key = ("decode", codec.k, codec.m, survivors.shape[-1],
-               algo.value, (present_mask, shard_len, "sse",
-                            nonces.shape[1], pkg_bytes))
-        p = _Pending(np.ascontiguousarray(survivors, np.uint8),
-                     payload=(np.ascontiguousarray(keys, np.uint32),
-                              np.ascontiguousarray(nonces, np.uint32)))
-        return self._enqueue_pending(key, p)
+        return self._enqueue("verify_decode_decrypt_batch", codec,
+                             survivors, algo,
+                             (present_mask, shard_len, pkg_bytes),
+                             (keys, nonces))
 
     def submit_recover(self, codec, survivors: np.ndarray,
                        present_mask: int, rows, shard_len: int, algo
@@ -402,11 +402,9 @@ class BatchScheduler:
         bucket: survivors (B, k, S) in recover_matrix `used` order.
         Resolves to (out, idxs, survivor_digests, out_digests) or
         None (caller host-rebuilds)."""
-        if self._declined(codec, algo):
-            return DispatchFuture()
-        key = ("recover", codec.k, codec.m, survivors.shape[-1],
-               algo.value, (present_mask, frozenset(rows), shard_len))
-        return self._enqueue(key, survivors)
+        return self._enqueue("verify_and_recover_batch", codec, survivors,
+                             algo,
+                             (present_mask, frozenset(rows), shard_len))
 
     def submit_scan(self, pages) -> DispatchFuture:
         """Non-blocking device-scan dispatch for one Select request's
@@ -555,15 +553,20 @@ class BatchScheduler:
         stage_attrs: dict[str, dict] = {}
         if verb == "scan":
             out = self._run_scan(group, stage_cb if attrib else None)
+
+            def cut(lo: int, hi: int):           # row masks
+                return out[lo:hi]
         else:
             out, staged, pad = self._run_erasure(
                 key, group, nb, stage_cb if attrib else None)
+            shared_at = FUSED[key[1]].shared_at
+
+            def cut(lo: int, hi: int):
+                # a group's own blocks of every per-block element; the
+                # value the launch shares (missing / idxs), whole
+                return tuple(a if i == shared_at else a[lo:hi]
+                             for i, a in enumerate(out))
             if out is not None:
-                if verb == "encode" and key[5] is None:
-                    # plain route: the device made parity and digests;
-                    # unwrap the codec's result at once, so no stream
-                    # ever sees (or joins) an EncodedRows
-                    out = (parity_rows(out[0], key[1]), out[1])
                 fetched = sum(a.nbytes for a in out
                               if isinstance(a, np.ndarray))
             stage_attrs = {
@@ -642,23 +645,8 @@ class BatchScheduler:
             return
         at = 0
         for p in group:
-            b = p.blocks
-            if verb == "encode":
-                # (parity, digests) on the plain route, (full, digests)
-                # under sse
-                rows, digests = out
-                p.out = (rows[at:at + b], digests[at:at + b])
-            elif verb == "decode":
-                missing, missing_idx, sdig = out
-                p.out = (missing[at:at + b], missing_idx,
-                         sdig[at:at + b])
-            elif verb == "recover":
-                rec, idxs, sdig, odig = out
-                p.out = (rec[at:at + b], idxs, sdig[at:at + b],
-                         odig[at:at + b])
-            else:                                # scan: row masks
-                p.out = out[at:at + b]
-            at += b
+            p.out = cut(at, at + p.blocks)
+            at += p.blocks
             p.event.set()
 
     def _run_erasure(self, key: tuple, group: list, nb: int,
@@ -706,42 +694,18 @@ class BatchScheduler:
     def _run_codec(key: tuple, group: list, data: np.ndarray, nb: int,
                    stage_cb=None):
         from .. import bitrot as bitrot_mod
-        verb, k, m, s, algo_value, extra = key
-        algo = bitrot_mod.BitrotAlgorithm.from_string(algo_value)
-        codec = Codec(k, m, s * k)
-
-        def _sse_arrays():
-            # per-row key/nonce word arrays concatenate across the
-            # group exactly like the shard data does
-            if len(group) == 1:
-                return group[0].payload
-            return (np.concatenate([p.payload[0] for p in group]),
-                    np.concatenate([p.payload[1] for p in group]))
-
-        if verb == "encode":
-            if extra is not None and extra[0] == "sse":
-                keys, nonces = _sse_arrays()
-                return codec.encrypt_encode_and_hash_batch(
-                    data, keys, nonces, extra[2], algo,
-                    stage_cb=stage_cb, blocks=nb)
-            return codec.encode_and_hash_batch(data, algo,
-                                               stage_cb=stage_cb,
-                                               blocks=nb)
-        if verb == "decode":
-            if len(extra) > 2 and extra[2] == "sse":
-                keys, nonces = _sse_arrays()
-                return codec.verify_decode_decrypt_batch(
-                    data, extra[0], extra[1], keys, nonces, extra[4],
-                    algo, stage_cb=stage_cb, blocks=nb)
-            mask, shard_len = extra
-            return codec.verify_and_decode_batch(data, mask, shard_len,
-                                                 algo, stage_cb=stage_cb,
-                                                 blocks=nb)
-        mask, rows, shard_len = extra
-        return codec.verify_and_recover_batch(data, mask, set(rows),
-                                              shard_len, algo,
-                                              stage_cb=stage_cb,
-                                              blocks=nb)
+        _verb, entry, k, m, s, algo_value, static, _shapes = key
+        # per-row arrays concatenate across the group exactly like the
+        # shard data does, and go where the program's method takes them
+        arrays = tuple(cols[0] if len(cols) == 1 else np.concatenate(cols)
+                       for cols in zip(*(p.payload for p in group)))
+        at = FUSED[entry].rows_at
+        # the method is looked up on the codec NOW: a wrapper planted
+        # on the class (a fault, a test) is the one that runs
+        return getattr(Codec(k, m, s * k), entry)(
+            data, *static[:at], *arrays, *static[at:],
+            bitrot_mod.BitrotAlgorithm.from_string(algo_value),
+            stage_cb=stage_cb, blocks=nb)
 
     @staticmethod
     def _run_scan(group: list, stage_cb=None):

@@ -45,6 +45,9 @@ class M:
     def dev(self):
         with self._mu:
             self.codec.encode_and_hash_batch(None, None)
+    def launch(self):
+        with self._mu:
+            self.codec._launch(None, None, (), (), None)
     def fut(self):
         with self._mu:
             self.f.result()
@@ -89,10 +92,11 @@ def test_lock_rule_fires_on_every_banned_class():
     assert "shutil.rmtree" in msgs
     assert ".put_object()" in msgs
     assert ".encode_and_hash_batch()" in msgs
+    assert "._launch()" in msgs
     assert ".result()" in msgs
     assert ".wait()" in msgs
     assert "_write_meta() which performs" in msgs      # helper indirection
-    assert len(vs) >= 9
+    assert len(vs) >= 10
 
 
 def test_lock_rule_quiet_on_good_and_non_hot_modules():

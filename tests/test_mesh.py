@@ -177,10 +177,10 @@ def test_mesh_helper_pads_uneven_batch():
     data = _rand(3, k, s, seed=5)
     out = pmesh.mesh_encode_and_hash(mesh, data, k, m)
     assert out is not None
-    full_got, digests = out
+    parity, digests = out
     full = _full(data, k, m)
-    assert full_got.shape == (3, k + m, s)
-    assert (full_got == full).all()
+    assert parity.shape == (3, m, s)
+    assert (parity == full[:, k:]).all()
     assert digests.shape == (3, k + m, 32)
     assert digests[2, k].tobytes() == bitrot_mod.hash_shard(
         full[2, k], HH)
@@ -214,9 +214,9 @@ def test_codec_fused_paths_dispatch_on_mesh(mesh_serving):
 
     out = codec.encode_and_hash_batch(data, HH)
     assert out is not None and pmesh.DISPATCHES == before + 1
-    full_got, digests = out
+    parity, digests = out
     full = _full(data, k, m)
-    assert (full_got == full).all()
+    assert (parity == full[:, k:]).all()
     assert digests[0, 0].tobytes() == bitrot_mod.hash_shard(
         full[0, 0], HH)
 

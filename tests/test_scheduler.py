@@ -7,13 +7,15 @@ from __future__ import annotations
 
 import threading
 import time
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 from minio_tpu import bitrot as bitrot_mod
-from minio_tpu.object.codec import Codec
+from minio_tpu.object.codec import FUSED, Codec
 from minio_tpu.ops import gf256, rs_matrix, rs_ref
+from minio_tpu.parallel import ladder
 from minio_tpu.parallel.bpool import BytePool
 from minio_tpu.parallel.scheduler import BatchScheduler, requests_budget
 
@@ -361,45 +363,6 @@ def test_decode_dispatch_error_fans_out_to_all_waiters(device_codec,
     assert errs == ["decode device on fire"] * 3
 
 
-def test_coalesced_decode_byte_identical_to_serial_cpu(device_codec):
-    """Acceptance pin: shards reconstructed through a COALESCED fused
-    decode are byte-identical to the serial CPU oracle path
-    (gf256 matmul per block, no batching, no device)."""
-    sched = BatchScheduler(max_batch=64, max_wait=0.2)
-    k, m, s = 4, 2, 192
-    codec = Codec(k, m, k * s)
-    outs: list = [None] * 4
-    inputs = []
-    for i in range(4):
-        surv, mask, full = _degraded(50 + i, 2, k, m, s, lost=(2, 5))
-        inputs.append((surv, mask, full))
-
-    def run(i):
-        surv, mask, _ = inputs[i]
-        outs[i] = sched.submit_decode(codec, surv, mask, s, HH
-                                      ).result(30)
-
-    threads = [threading.Thread(target=run, args=(i,))
-               for i in range(4)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=60)
-    sched.close()
-    for i, (surv, mask, full) in enumerate(inputs):
-        assert outs[i] is not None
-        out, missing_idx, _sdig = outs[i]
-        dm, _used, missing = rs_matrix.missing_data_matrix(k, m, mask)
-        assert tuple(missing_idx) == missing
-        # serial CPU oracle: one host matmul per block
-        for bi in range(surv.shape[0]):
-            want = gf256.gf_matmul(np.asarray(dm, np.uint8), surv[bi])
-            assert out[bi].tobytes() == want.tobytes()
-            for r, mi in enumerate(missing):
-                assert (out[bi, r] == full[bi, mi]).all()
-    assert sched.coalesced >= 1       # they actually shared dispatches
-
-
 def test_sched_totals_exposed_as_prometheus_counters(device_codec):
     """minio_tpu_sched_batches_total / _coalesced_total are monotonic
     totals — they must expose as TYPE counter (rate()-able), labelled
@@ -422,6 +385,139 @@ def test_sched_totals_exposed_as_prometheus_counters(device_codec):
 # ---------------------------------------------------------------------------
 # PR 26: a PUT launch moves the data rows once — parity + digests come
 # back, groups gather into a slot-owned staging buffer
+# ---------------------------------------------------------------------------
+# the five fused programs (codec.FUSED) through the one launch path
+# ---------------------------------------------------------------------------
+
+_K, _M, _BLOCK = 4, 2, 1 << 16          # a block is one cipher package
+_S = _BLOCK // _K
+_LOST = (2, 5)                          # a data row and a parity row
+_MASK = sum(1 << i for i in range(_K + _M) if i not in _LOST)
+
+
+def _host_digests(rows: np.ndarray) -> np.ndarray:
+    """(..., S) shard rows -> (..., 32) through the host's bitrot path."""
+    return bitrot_mod.hash_shards_batch(
+        rows.reshape(-1, rows.shape[-1]), HH).reshape(*rows.shape[:-1], 32)
+
+
+def _obj(seed: int, b: int) -> SimpleNamespace:
+    """b blocks of one object under its own cipher key, with what the
+    HOST makes of them: the CPU cipher, rs_ref's parity, one matmul a
+    block for the lost rows (bitrot.hash_shard's digests come in
+    `_fused_cases`)."""
+    from minio_tpu.features import crypto as sse
+    rng = np.random.default_rng(seed)
+    spec = sse.DeviceSSE(rng.bytes(32), rng.bytes(12))
+    o = SimpleNamespace(kn=spec.batch_params(0, b, _BLOCK))
+    o.plain = rng.integers(0, 256, (b, _K, _S), dtype=np.uint8)
+    flat = o.plain.reshape(b, -1).copy()
+    spec.cpu_encrypt_rows(flat, 0)
+    o.full = np.stack([rs_ref.encode(blk, _M) for blk in o.plain])
+    o.full_ct = np.stack([rs_ref.encode(blk, _M)
+                          for blk in flat.reshape(b, _K, _S)])
+    dm, used, o.missing = rs_matrix.missing_data_matrix(_K, _M, _MASK)
+    o.surv = np.ascontiguousarray(o.full[:, list(used)])
+    o.surv_ct = np.ascontiguousarray(o.full_ct[:, list(used)])
+    # serial CPU oracle: one host matmul per block
+    o.rebuilt = np.stack([gf256.gf_matmul(np.asarray(dm, np.uint8), sv)
+                          for sv in o.surv])
+    assert (o.rebuilt == o.full[:, list(o.missing)]).all()
+    return o
+
+
+def _joined(objs) -> SimpleNamespace:
+    """The objects' blocks as one batch, each row under its own key."""
+    both = SimpleNamespace(
+        missing=objs[0].missing,
+        kn=tuple(np.concatenate(cols)
+                 for cols in zip(*(o.kn for o in objs))))
+    for name in ("plain", "surv", "surv_ct", "full", "full_ct", "rebuilt"):
+        setattr(both, name, np.concatenate(
+            [getattr(o, name) for o in objs]))
+    return both
+
+
+def _fused_cases(pkg: int):
+    """entry -> (the codec's direct call, the former's submit, the
+    host's answer), each over one `_obj`."""
+    lost = list(_LOST)
+    return {
+        "encode_and_hash_batch": (
+            lambda c, o, **kw: c.encode_and_hash_batch(o.plain, HH, **kw),
+            lambda s, c, o: s.submit(c, o.plain, HH),
+            lambda o: (o.full[:, _K:], _host_digests(o.full))),
+        "encrypt_encode_and_hash_batch": (
+            lambda c, o, **kw: c.encrypt_encode_and_hash_batch(
+                o.plain, *o.kn, pkg, HH, **kw),
+            lambda s, c, o: s.submit(c, o.plain, HH, sse=(*o.kn, pkg)),
+            lambda o: (o.full_ct, _host_digests(o.full_ct))),
+        "verify_and_decode_batch": (
+            lambda c, o, **kw: c.verify_and_decode_batch(
+                o.surv, _MASK, _S, HH, **kw),
+            lambda s, c, o: s.submit_decode(c, o.surv, _MASK, _S, HH),
+            lambda o: (o.rebuilt, o.missing, _host_digests(o.surv))),
+        "verify_decode_decrypt_batch": (
+            lambda c, o, **kw: c.verify_decode_decrypt_batch(
+                o.surv_ct, _MASK, _S, *o.kn, pkg, HH, **kw),
+            lambda s, c, o: s.submit_decode(c, o.surv_ct, _MASK, _S, HH,
+                                            sse=(*o.kn, pkg)),
+            lambda o: (o.plain, o.missing, _host_digests(o.surv_ct))),
+        "verify_and_recover_batch": (
+            lambda c, o, **kw: c.verify_and_recover_batch(
+                o.surv, _MASK, set(_LOST), _S, HH, **kw),
+            lambda s, c, o: s.submit_recover(c, o.surv, _MASK, set(_LOST),
+                                             _S, HH),
+            lambda o: (o.full[:, lost], lost, _host_digests(o.surv),
+                       _host_digests(o.full[:, lost]))),
+    }
+
+
+def _assert_same(got, want):
+    """Element for element: arrays byte for byte (and row for row: a
+    pad row would change the shape), shared index lists as lists."""
+    assert got is not None and len(got) == len(want)
+    for g, w in zip(got, want):
+        if isinstance(w, np.ndarray):
+            assert isinstance(g, np.ndarray) and g.shape == w.shape
+            assert g.tobytes() == w.tobytes()
+        else:
+            assert list(g) == list(w)
+
+
+@pytest.mark.parametrize("entry", sorted(FUSED))
+def test_fused_program_direct_coalesced_and_host_agree(device_codec, entry):
+    """Every row of codec.FUSED, at a block count that is not a rung:
+    the codec's own call over five blocks, the former's ONE launch
+    fused from an object's two and another's three, and the host
+    agree byte for byte; the launch reports h2d, compute, fetch; the
+    pad block reaches nobody. (Holds what the coalesced-decode-vs-
+    serial-CPU pin and the two-encrypted-PUTs-one-launch pin held.)"""
+    from minio_tpu.features import crypto as sse
+    verb = FUSED[entry].verb
+    assert 5 not in ladder.rungs_of(verb) and ladder.rung(verb, 5) == 6
+    direct, submit, want = _fused_cases(sse.PKG_SIZE)[entry]
+    codec = Codec(_K, _M, _BLOCK)
+    objs = [_obj(50, 2), _obj(51, 3)]
+    both = _joined(objs)
+    stages = []
+    got = direct(codec, both,
+                 stage_cb=lambda stage, _secs: stages.append(stage))
+    assert stages == ["h2d", "compute", "fetch"]
+    _assert_same(got, want(both))
+    sched = BatchScheduler(max_wait=0.5)
+    try:
+        futs = [submit(sched, codec, o) for o in objs]
+        outs = [f.result(120) for f in futs]
+        st = sched.stats()["verbs"][verb]
+        assert (st["batches"], st["coalesced"], st["blocks"],
+                st["pad_blocks"]) == (1, 1, 5, 1)
+    finally:
+        sched.close()
+    for o, out in zip(objs, outs):
+        _assert_same(out, want(o))
+
+
 # ---------------------------------------------------------------------------
 
 SHA = bitrot_mod.BitrotAlgorithm.SHA256

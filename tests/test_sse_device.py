@@ -1,8 +1,9 @@
 """Device-fused SSE data path: engine PUT byte-identity vs the CPU
-cipher oracle, cross-request coalescing of encrypted PUTs, fallback
-discipline (knob off / deviceless / dispatch error), host-side tag
-authentication of device output, and cross-path e2e (device-written
-read by CPU and vice versa) over the live S3 server."""
+cipher oracle, fallback discipline (knob off / deviceless / dispatch
+error), host-side tag authentication of device output, and cross-path
+e2e (device-written read by CPU and vice versa) over the live S3
+server. (Cross-request coalescing of encrypted PUTs under different
+keys: tests/test_scheduler.py, the fused programs' parametrised test.)"""
 
 from __future__ import annotations
 
@@ -11,7 +12,6 @@ import hashlib
 import http.client
 import io
 import os
-import threading
 import urllib.parse
 
 import numpy as np
@@ -133,58 +133,6 @@ def test_device_tags_verify_with_scalar_reference(tmp_path, device_on):
         got += c20.open_detached(OEK, sse._pkg_nonce(BASE, seq),
                                  sse._pkg_aad(seq), pkg_ct, tag)
     assert got == pt
-
-
-# ---------------------------------------------------------------------------
-# coalescing: concurrent encrypted PUTs under DIFFERENT keys share a launch
-# ---------------------------------------------------------------------------
-
-def test_two_encrypted_puts_coalesce_into_one_launch(device_on):
-    sched = BatchScheduler(max_wait=0.2)
-    codec = codec_mod.Codec(K, M, BLOCK)
-    rng = np.random.default_rng(21)
-    specs = [sse.DeviceSSE(rng.bytes(32), rng.bytes(12))
-             for _ in range(2)]
-    datas = [rng.integers(0, 256, (2, K, codec.shard_size),
-                          dtype=np.uint8) for _ in range(2)]
-    try:
-        # warm the jit cache so the counter window isn't skewed by
-        # compile time
-        w = specs[0].batch_params(0, 2, BLOCK)
-        sched.submit(codec, datas[0], engine_mod.bitrot_mod
-                     .BitrotAlgorithm.HIGHWAYHASH256,
-                     sse=(w[0], w[1], PKG)).result()
-        b0, c0 = sched.batches, sched.coalesced
-        barrier = threading.Barrier(2)
-        outs = [None, None]
-
-        def put(i):
-            kn = specs[i].batch_params(0, 2, BLOCK)
-            barrier.wait()
-            fut = sched.submit(
-                codec, datas[i],
-                engine_mod.bitrot_mod.BitrotAlgorithm.HIGHWAYHASH256,
-                sse=(kn[0], kn[1], PKG))
-            outs[i] = fut.result()
-
-        ts = [threading.Thread(target=put, args=(i,)) for i in range(2)]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join()
-        assert sched.batches - b0 == 1, "expected ONE shared dispatch"
-        assert sched.coalesced - c0 == 1
-        # each object's rows deciphered under its OWN key round-trip
-        for i in range(2):
-            full, _dig = outs[i]
-            flat = np.ascontiguousarray(
-                full[:, :K]).reshape(2, -1)[:, :BLOCK].copy()
-            specs_pt = flat.copy()
-            specs[i].cpu_encrypt_rows(specs_pt, 0)   # XOR twice = undo
-            assert specs_pt.tobytes() == \
-                datas[i].reshape(2, -1)[:, :BLOCK].tobytes()
-    finally:
-        sched.close()
 
 
 # ---------------------------------------------------------------------------

@@ -66,9 +66,11 @@ _OBJECT_LAYER = {
 # device dispatch — the PR 6 deadlock class: a mesh/jit launch under a
 # lock serializes the backend behind the lock's waiters
 _DEVICE = {
-    "encode_and_hash_batch", "verify_and_decode_batch",
-    "verify_and_recover_batch", "mesh_put_batch", "mesh_get_batch",
-    "mesh_heal_batch", "run_batch", "block_until_ready",
+    "encode_and_hash_batch", "encrypt_encode_and_hash_batch",
+    "verify_and_decode_batch", "verify_decode_decrypt_batch",
+    "verify_and_recover_batch", "_launch", "mesh_encode_and_hash",
+    "mesh_verify_and_decode", "mesh_verify_and_recover", "run_batch",
+    "block_until_ready",
 }
 
 
