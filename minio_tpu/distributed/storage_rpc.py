@@ -161,7 +161,8 @@ class StorageRPCServer:
 
     def _writemetadata(self, a, b):
         self._disk(a).write_metadata(a["volume"], a["path"],
-                                     fi_from_dict(json.loads(b.decode())))
+                                     fi_from_dict(json.loads(b.decode())),
+                                     fresh=a.get("fresh") == "true")
 
     def _readversion(self, a, b):
         fi = self._disk(a).read_version(a["volume"], a["path"],
@@ -190,7 +191,9 @@ class StorageRPCServer:
         self._disk(a).rename_data(a["src-volume"], a["src-path"],
                                   a["data-dir"], a["dst-volume"],
                                   a["dst-path"],
-                                  a.get("version-id", ""))
+                                  a.get("version-id", ""),
+                                  fi_from_dict(json.loads(b.decode()))
+                                  if b else None)
 
     def _listdir(self, a, b):
         return self._disk(a).list_dir(a["volume"], a.get("dir-path", ""),
@@ -401,8 +404,11 @@ class RemoteStorage(StorageAPI):
 
     # -- metadata ----------------------------------------------------------
 
-    def write_metadata(self, volume: str, path: str, fi: FileInfo) -> None:
-        self._call("writemetadata", {"volume": volume, "path": path},
+    def write_metadata(self, volume: str, path: str, fi: FileInfo,
+                       fresh: bool = False) -> None:
+        self._call("writemetadata",
+                   {"volume": volume, "path": path,
+                    "fresh": "true" if fresh else "false"},
                    json.dumps(fi_to_dict(fi)).encode())
 
     def read_version(self, volume: str, path: str,
@@ -440,11 +446,14 @@ class RemoteStorage(StorageAPI):
 
     def rename_data(self, src_volume: str, src_path: str, data_dir: str,
                     dst_volume: str, dst_path: str,
-                    version_id: str = "") -> None:
+                    version_id: str = "",
+                    fi: Optional[FileInfo] = None) -> None:
         self._call("renamedata", {
             "src-volume": src_volume, "src-path": src_path,
             "data-dir": data_dir, "dst-volume": dst_volume,
-            "dst-path": dst_path, "version-id": version_id})
+            "dst-path": dst_path, "version-id": version_id},
+            json.dumps(fi_to_dict(fi)).encode() if fi is not None
+            else b"")
 
     # -- files -------------------------------------------------------------
 

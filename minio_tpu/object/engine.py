@@ -98,6 +98,16 @@ class GetOptions:
         self.version_id = version_id
 
 
+# fan-outs / commits says how many quorum fan-outs over the drive-io
+# pool one PUT's commit costs (2: stage, rename)
+_PUT_COMMITS = telemetry.REGISTRY.counter(
+    "minio_tpu_put_commits_total",
+    "Single-part PUT commits begun (shards written, under the lock)")
+_PUT_COMMIT_FANOUTS = telemetry.REGISTRY.counter(
+    "minio_tpu_put_commit_fanouts_total",
+    "Quorum fan-outs over the drive set issued by single-part PUT "
+    "commits (stage, rename)")
+
 _GET_STREAMS = None
 
 
@@ -877,47 +887,62 @@ class ErasureObjects:
 
     def _commit(self, shuffled, writers, tmp_id: str, fi: FileInfo,
                 bucket: str, object_name: str, write_quorum: int) -> int:
-        """2-phase commit; returns how many drives MISSED the commit
-        (offline slot, dropped writer, or failed rename) — the MRF
-        degraded-write signal."""
-        def close_writer(i, d):
+        """2-phase commit in two quorum fan-outs — stage (close the
+        shard writer, write the staged journal), then rename; returns
+        how many drives MISSED the commit (offline slot, dropped
+        writer, or failed rename) — the MRF degraded-write signal. The
+        barrier between the two IS the two phases: below write quorum
+        at stage, no drive has been told to rename."""
+        metas = [fi.light_copy() for _ in range(len(shuffled))]
+
+        def stage(i, d):
             w = writers[i]
             if w is None:
                 raise serr.DiskNotFound(f"writer {i}")
             w.close()  # flushes remaining frames (empty file for 0-byte)
+            m = metas[i]
+            m.erasure.index = i + 1
+            if not self.bitrot_algo.streaming:
+                # whole-file digests are per-drive (each shard differs)
+                for c in m.erasure.checksums:
+                    c.hash = w.digest()
+            # the drive is told what this process knows: the staging
+            # directory is this request's own, and (at rename) the
+            # version in it — it probes for and re-reads neither
+            d.write_metadata(MINIO_META_TMP_BUCKET, tmp_id, m, fresh=True)
 
         # the whole commit window rides the quorum-ack lane: a drive
         # stalling at close/meta/rename must not hold the client ack
         # once quorum is durable — it is counted into `lost` below and
         # the object converges back through MRF
         stall = healthtrack.write_stall_s()
-        with telemetry.span("put.close_writers"):
-            _, errs = meta.for_each_disk_quorum(shuffled, close_writer,
-                                                write_quorum,
-                                                stall_s=stall,
-                                                stage="close")
-        for i, e in enumerate(errs):
-            if e is not None:
-                writers[i] = None
+        commit_span = telemetry.current_span()
+        _PUT_COMMITS.inc()
 
-        metas = [fi.light_copy() for _ in range(len(shuffled))]
-        if not self.bitrot_algo.streaming:
-            # whole-file digests are per-drive (each shard differs)
-            for i, w in enumerate(writers):
-                if w is not None:
-                    for c in metas[i].erasure.checksums:
-                        c.hash = w.digest()
-        disks_for_meta = [d if writers[i] is not None else None
-                          for i, d in enumerate(shuffled)]
-        # shard fan-out is durable (in tmp), no metadata exists yet —
+        def fan_out(phase: str, disks, fn, **kw):
+            with telemetry.span("put." + phase):
+                _, errs = meta.for_each_disk_quorum(
+                    disks, fn, write_quorum, stall_s=stall, stage=phase,
+                    **kw)
+            _PUT_COMMIT_FANOUTS.inc()
+            if commit_span is not None:
+                commit_span.attrs["fanouts"] = \
+                    commit_span.attrs.get("fanouts", 0) + 1
+            return errs
+
+        # shard fan-out is done (in tmp), no metadata exists yet —
         # a crash here must leave the previous version untouched and
         # only tmp garbage for fsck to reclaim
         crashpoint.hit("put.shards.before_meta")
-        with telemetry.span("put.write_meta"):
-            meta.write_unique_file_info(disks_for_meta,
-                                        MINIO_META_TMP_BUCKET,
-                                        tmp_id, metas, write_quorum,
-                                        stall_s=stall)
+        errs = fan_out("stage", shuffled, stage)
+        for i, e in enumerate(errs):
+            if e is not None:
+                writers[i] = None
+        err = meta.reduce_write_quorum_errs(
+            errs, meta.OBJECT_OP_IGNORED_ERRS, write_quorum)
+        if err is not None:
+            raise err
+        staged = meta.eval_disks(shuffled, errs)
         # fully staged, uncommitted: the rename fan-out is the point
         # of no return
         crashpoint.hit("put.meta.before_rename")
@@ -927,7 +952,7 @@ class ErasureObjects:
             # committed (torn below/at write quorum)
             crashpoint.hit("put.rename.partial", disk=i)
             d.rename_data(MINIO_META_TMP_BUCKET, tmp_id, fi.data_dir,
-                          bucket, object_name)
+                          bucket, object_name, fi=metas[i])
 
         def renamed_late(_i: int) -> None:
             # an abandoned rename that eventually LANDS may have laid
@@ -936,18 +961,13 @@ class ErasureObjects:
             # so the drive is healed against current quorum state
             self._notify_degraded(bucket, object_name, fi.version_id)
 
-        with telemetry.span("put.rename"):
-            _, errs = meta.for_each_disk_quorum(disks_for_meta, rename,
-                                                write_quorum,
-                                                stall_s=stall,
-                                                stage="rename",
-                                                on_settle=renamed_late)
+        errs = fan_out("rename", staged, rename, on_settle=renamed_late)
         err = meta.reduce_write_quorum_errs(
             errs, meta.OBJECT_OP_IGNORED_ERRS, write_quorum)
         if err is not None:
             raise api_errors.to_object_err(err, bucket, object_name)
         return sum(1 for i in range(len(shuffled))
-                   if disks_for_meta[i] is None or errs[i] is not None)
+                   if staged[i] is None or errs[i] is not None)
 
     def _cleanup_tmp(self, disks, tmp_id: str) -> None:
         def rm(i, d):

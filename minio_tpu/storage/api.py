@@ -87,7 +87,12 @@ class StorageAPI(abc.ABC):
     # -- metadata ----------------------------------------------------------
 
     @abc.abstractmethod
-    def write_metadata(self, volume: str, path: str, fi: FileInfo) -> None: ...
+    def write_metadata(self, volume: str, path: str, fi: FileInfo,
+                       fresh: bool = False) -> None:
+        """Append fi into path's journal. `fresh`: path is a staging
+        directory the caller made for this write alone — nothing to
+        read or merge, the journal holds fi and no more."""
+        ...
 
     @abc.abstractmethod
     def read_version(self, volume: str, path: str,
@@ -118,11 +123,14 @@ class StorageAPI(abc.ABC):
     @abc.abstractmethod
     def rename_data(self, src_volume: str, src_path: str, data_dir: str,
                     dst_volume: str, dst_path: str,
-                    version_id: str = "") -> None:
+                    version_id: str = "",
+                    fi: Optional[FileInfo] = None) -> None:
         """Commit a staged write. `version_id` names the version being
         committed (empty = legacy latest-pick) — version-faithful
         replays stage versions whose mod time sorts behind the
-        session placeholder, so "latest" is not "the one"."""
+        session placeholder, so "latest" is not "the one". `fi`: the
+        version itself, as staged with write_metadata(fresh=True) — the
+        drive then commits it without reading the staged journal back."""
         ...
 
     # -- files -------------------------------------------------------------

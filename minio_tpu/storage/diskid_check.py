@@ -120,8 +120,9 @@ class DiskIDCheck(StorageAPI):
     def delete_vol(self, volume, force=False):
         return self._call(self.inner.delete_vol, volume, force)
 
-    def write_metadata(self, volume, path, fi):
-        return self._call(self.inner.write_metadata, volume, path, fi)
+    def write_metadata(self, volume, path, fi, fresh=False):
+        return self._call(self.inner.write_metadata, volume, path, fi,
+                          fresh)
 
     def read_version(self, volume, path, version_id=""):
         return self._call(self.inner.read_version, volume, path,
@@ -137,9 +138,9 @@ class DiskIDCheck(StorageAPI):
         return self._call(self.inner.delete_versions, volume, versions)
 
     def rename_data(self, src_volume, src_path, data_dir, dst_volume,
-                    dst_path, version_id=""):
+                    dst_path, version_id="", fi=None):
         return self._call(self.inner.rename_data, src_volume, src_path,
-                          data_dir, dst_volume, dst_path, version_id)
+                          data_dir, dst_volume, dst_path, version_id, fi)
 
     def list_dir(self, volume, dir_path, count=-1):
         return self._call(self.inner.list_dir, volume, dir_path, count)

@@ -383,9 +383,10 @@ class NaughtyDisk(StorageAPI):
 
     # -- metadata ----------------------------------------------------------
 
-    def write_metadata(self, volume: str, path: str, fi: FileInfo) -> None:
+    def write_metadata(self, volume: str, path: str, fi: FileInfo,
+                       fresh: bool = False) -> None:
         self._begin("write_metadata")
-        self.inner.write_metadata(volume, path, fi)
+        self.inner.write_metadata(volume, path, fi, fresh)
 
     def read_version(self, volume: str, path: str,
                      version_id: str = "") -> FileInfo:
@@ -402,10 +403,11 @@ class NaughtyDisk(StorageAPI):
 
     def rename_data(self, src_volume: str, src_path: str, data_dir: str,
                     dst_volume: str, dst_path: str,
-                    version_id: str = "") -> None:
+                    version_id: str = "",
+                    fi: Optional[FileInfo] = None) -> None:
         self._begin("rename_data")
         self.inner.rename_data(src_volume, src_path, data_dir,
-                               dst_volume, dst_path, version_id)
+                               dst_volume, dst_path, version_id, fi)
 
     # -- files -------------------------------------------------------------
 

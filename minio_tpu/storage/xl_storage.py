@@ -33,6 +33,13 @@ from .datatypes import DiskInfo, FileInfo, VolInfo
 from .format import FORMAT_CONFIG_FILE, MINIO_META_BUCKET, FormatErasureV3
 from .xl_meta import XLMetaV2
 
+# reads / rename_data calls says how many commits were handed their
+# version: 0 for single-part PUTs, 1 for multipart complete and heal
+_SRC_READS = telemetry.REGISTRY.counter(
+    "minio_tpu_rename_data_src_reads_total",
+    "rename_data calls that read the staged xl.meta back from the "
+    "drive (the caller handed no FileInfo)")
+
 XL_STORAGE_FORMAT_FILE = "xl.meta"
 XL_LEGACY_FORMAT_FILE = "xl.json"   # format v1 (migrated on access)
 MINIO_META_TMP_BUCKET = MINIO_META_BUCKET + "/tmp"
@@ -413,9 +420,18 @@ class XLStorage(StorageAPI):
             raise errors.FaultyDisk(str(e)) from e
 
     def write_all(self, volume: str, path: str, data: bytes) -> None:
-        fp = self._file_path(volume, path)
+        self._commit_file(self._file_path(volume, path), data)
+
+    def _commit_file(self, fp: str, data: bytes, made: bool = False,
+                     private: bool = False) -> None:
+        """write_all's body. `made`: the caller has seen fp's directory
+        (it made it, or read from it), so none is made unless the write
+        misses it. `private`: nothing reads fp before its directory is
+        committed or swept, so it is written in place — no temp
+        sibling, no rename; the fsync discipline is the same."""
         try:
-            os.makedirs(os.path.dirname(fp), exist_ok=True)
+            if not made:
+                os.makedirs(os.path.dirname(fp), exist_ok=True)
             # torn-write injection context for in-process crash tests:
             # an armed action receives path=/data= and can commit a
             # truncated copy to the final name before aborting (what
@@ -424,7 +440,15 @@ class XLStorage(StorageAPI):
                            data=data)
             # write-temp → (fsync) → rename → (dirsync): MINIO_TPU_FSYNC
             # turns the barriers on (pkg/safe analog + ALICE safe-rename)
-            atomicfile.write_atomic(fp, data)
+            write = atomicfile.write_in_place if private \
+                else atomicfile.write_atomic
+            try:
+                write(fp, data)
+            except FileNotFoundError:
+                if not made:
+                    raise
+                os.makedirs(os.path.dirname(fp), exist_ok=True)
+                write(fp, data)
         except NotADirectoryError:
             raise errors.FileParentIsFile(fp) from None
         except OSError as e:
@@ -692,16 +716,27 @@ class XLStorage(StorageAPI):
             return meta
         return XLMetaV2.loads(buf)
 
-    def write_metadata(self, volume: str, path: str, fi: FileInfo) -> None:
+    def write_metadata(self, volume: str, path: str, fi: FileInfo,
+                       fresh: bool = False) -> None:
         """Append fi as a version into xl.meta (creating it if absent) —
-        reference WriteMetadata (cmd/xl-storage.go:1219)."""
-        try:
-            meta = self._read_xl_meta(volume, path)
-        except errors.FileNotFound:
+        reference WriteMetadata (cmd/xl-storage.go:1219). `fresh`: path
+        is a staging directory the caller made for this write alone, so
+        there is no journal to merge and no legacy file to probe for;
+        the journal is written in place beside the shards the caller
+        has just put there (a staging directory no writer has made yet
+        is made when the write misses it)."""
+        if fresh:
             meta = XLMetaV2()
+        else:
+            try:
+                meta = self._read_xl_meta(volume, path)
+            except errors.FileNotFound:
+                meta = XLMetaV2()
         meta.add_version(fi)
-        self.write_all(volume, os.path.join(path, XL_STORAGE_FORMAT_FILE),
-                       meta.dumps())
+        self._commit_file(
+            self._file_path(volume,
+                            os.path.join(path, XL_STORAGE_FORMAT_FILE)),
+            meta.dumps(), made=fresh, private=fresh)
 
     def read_version(self, volume: str, path: str,
                      version_id: str = "") -> FileInfo:
@@ -737,30 +772,81 @@ class XLStorage(StorageAPI):
 
     def rename_data(self, src_volume: str, src_path: str, data_dir: str,
                     dst_volume: str, dst_path: str,
-                    version_id: str = "") -> None:
+                    version_id: str = "",
+                    fi: Optional[FileInfo] = None) -> None:
         """Commit a staged write: merge the committed version of src's
         xl.meta into dst's journal, move the data dir, drop src
         (reference RenameData, cmd/xl-storage.go:2041 — the
         2-phase-commit finish). `version_id` names the version being
         committed; without it the latest entry is assumed (correct
-        only when the staged meta holds one version)."""
-        with telemetry.span("disk.rename_data"):
-            self._rename_data(src_volume, src_path, data_dir,
-                              dst_volume, dst_path, version_id)
+        only when the staged meta holds one version). `fi` is the
+        version the caller staged with write_metadata(fresh=True): with
+        it the staged xl.meta is not read back, and src is taken for a
+        staging directory that holds that file and the data dir only."""
+        with telemetry.span("disk.rename_data") as sp:
+            dst = self._rename_data(src_volume, src_path, data_dir,
+                                    dst_volume, dst_path, version_id, fi)
+            if sp is not None:
+                sp.attrs.update(src_read=int(fi is None), dst=dst)
+
+    def _dst_journal(self, volume: str, path: str
+                     ) -> tuple[XLMetaV2, str, bool]:
+        """The journal a commit into volume/path merges into, what was
+        found there — `journal` (an xl.meta), `legacy` (an xl.json,
+        migrated), `fresh` (neither) — and whether the object
+        directory was made here, empty. A fresh key costs one failed
+        open and the mkdir it needs anyway; only a directory that is
+        there without an xl.meta pays for the legacy probe."""
+        obj = self._file_path(volume, path)
+        try:
+            with open(os.path.join(obj, XL_STORAGE_FORMAT_FILE), "rb") as f:
+                buf = f.read()
+        except FileNotFoundError:
+            if self._make_object_dir(volume, obj):
+                return XLMetaV2(), "fresh", True
+            found = "legacy"           # a legacy drive, or a prefix
+        except OSError:
+            found = "journal"          # read_all names the error
+        else:
+            return XLMetaV2.loads(buf), "journal", False
+        try:
+            return self._read_xl_meta(volume, path), found, False
+        except errors.FileNotFound:
+            return XLMetaV2(), "fresh", False
+
+    def _make_object_dir(self, volume: str, obj: str) -> bool:
+        """mkdir an object directory; False when it is there already."""
+        try:
+            try:
+                os.mkdir(obj)
+            except FileNotFoundError:
+                # parents missing: a nested key, or no such volume
+                if not os.path.isdir(self._vol_dir(volume)):
+                    raise errors.VolumeNotFound(volume) from None
+                os.makedirs(obj, exist_ok=True)
+            return True
+        except FileExistsError:
+            return False
+        except NotADirectoryError:
+            raise errors.FileParentIsFile(obj) from None
+        except OSError as e:
+            raise errors.FaultyDisk(str(e)) from e
 
     def _rename_data(self, src_volume: str, src_path: str, data_dir: str,
                      dst_volume: str, dst_path: str,
-                     version_id: str = "") -> None:
-        src_meta = self._read_xl_meta(src_volume, src_path)
-        # the staged multipart session meta holds the session
-        # placeholder AND the final version — "latest by mod time" is
-        # wrong for version-faithful replays (preserved mod times sort
-        # behind the placeholder), so the commit names its version
-        fi = src_meta.to_file_info(dst_volume, dst_path, version_id)
-        try:
-            dst_meta = self._read_xl_meta(dst_volume, dst_path)
-        except errors.FileNotFound:
-            dst_meta = XLMetaV2()
+                     version_id: str = "",
+                     fi: Optional[FileInfo] = None) -> str:
+        staged = fi is not None
+        if not staged:
+            _SRC_READS.inc()
+            # the staged multipart session meta holds the session
+            # placeholder AND the final version — "latest by mod time"
+            # is wrong for version-faithful replays (preserved mod
+            # times sort behind the placeholder), so the commit names
+            # its version
+            fi = self._read_xl_meta(src_volume, src_path).to_file_info(
+                dst_volume, dst_path, version_id)
+        dst_meta, dst, made = self._dst_journal(dst_volume, dst_path)
         dst_meta.add_version(fi)
 
         if data_dir:
@@ -769,9 +855,14 @@ class XLStorage(StorageAPI):
             dst_data = self._file_path(dst_volume,
                                        os.path.join(dst_path, data_dir))
             try:
-                os.makedirs(os.path.dirname(dst_data), exist_ok=True)
-                if os.path.isdir(dst_data):
-                    shutil.rmtree(dst_data)
+                if not made:
+                    os.makedirs(os.path.dirname(dst_data), exist_ok=True)
+                    if os.path.isdir(dst_data):
+                        # a replayed commit (its staging already gone)
+                        # must not take the committed data dir with it
+                        if not os.path.isdir(src_data):
+                            raise errors.FileNotFound(src_path)
+                        shutil.rmtree(dst_data)
                 os.replace(src_data, dst_data)
             except FileNotFoundError:
                 raise errors.FileNotFound(src_path) from None
@@ -782,13 +873,33 @@ class XLStorage(StorageAPI):
         # the single-drive torn window: data dir in place, xl.meta not
         # yet rewritten — restart-side fsck must reclaim the orphan
         crashpoint.hit("storage.rename_data.before_meta")
-        self.write_all(dst_volume,
-                       os.path.join(dst_path, XL_STORAGE_FORMAT_FILE),
-                       dst_meta.dumps())
+        self._commit_file(
+            self._file_path(dst_volume,
+                            os.path.join(dst_path, XL_STORAGE_FORMAT_FILE)),
+            dst_meta.dumps(), made=True)
+        if not (staged and self._drop_staging(src_volume, src_path)):
+            try:
+                self.delete_file(src_volume, src_path, recursive=True)
+            except errors.FileNotFound:
+                pass
+        return dst
+
+    def _drop_staging(self, volume: str, path: str) -> bool:
+        """Remove a staging directory that, its data dir renamed away,
+        holds its xl.meta and nothing else: an unlink and an rmdir.
+        False when it holds more (or is gone): the caller's recursive
+        delete decides."""
+        fp = self._file_path(volume, path)
         try:
-            self.delete_file(src_volume, src_path, recursive=True)
-        except errors.FileNotFound:
+            os.unlink(os.path.join(fp, XL_STORAGE_FORMAT_FILE))
+        except OSError:
             pass
+        try:
+            os.rmdir(fp)
+        except OSError:
+            return False
+        self._cleanup_empty_parents(volume, os.path.dirname(fp))
+        return True
 
     # -- integrity ---------------------------------------------------------
 

@@ -33,7 +33,7 @@ from typing import Optional
 from . import knobs
 
 __all__ = ["fsync_enabled", "fsync_file", "fsync_dir", "write_atomic",
-           "load_json_doc"]
+           "write_in_place", "load_json_doc"]
 
 
 def fsync_enabled() -> bool:
@@ -70,15 +70,28 @@ def fsync_dir(path: str) -> None:
         os.close(dfd)
 
 
+def _write_file(path: str, data: bytes) -> None:
+    """create → write → (fsync) → close on a bare descriptor: three
+    syscalls where a buffered file object adds an fstat and an isatty
+    probe of its own, each one a drop and retake of the interpreter
+    lock under a fan-out of drive threads."""
+    fd = os.open(path, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o666)
+    try:
+        view = memoryview(data)
+        while len(view):
+            view = view[os.write(fd, view):]
+        fsync_file(fd)
+    finally:
+        os.close(fd)
+
+
 def write_atomic(path: str, data: bytes) -> None:
     """write-temp → (fsync) → rename → (dirsync): the one sanctioned
     raw-file commit. Cleans up the temp on any failure. Callers map
     OSError to their own error taxonomy."""
     tmp = path + "." + _uuid.uuid4().hex[:8] + ".tmp"
     try:
-        with open(tmp, "wb") as f:
-            f.write(data)
-            fsync_file(f)
+        _write_file(tmp, data)
         os.replace(tmp, path)
     except BaseException:
         try:
@@ -86,6 +99,16 @@ def write_atomic(path: str, data: bytes) -> None:
         except OSError:
             pass
         raise
+    fsync_dir(os.path.dirname(path) or ".")
+
+
+def write_in_place(path: str, data: bytes) -> None:
+    """write → (fsync) → (dirsync) under the final name, for a file in
+    a private staging directory: nothing reads it before the directory
+    itself is committed or swept, so a crash leaves a torn copy only
+    where no reader looks, and the temp sibling and its rename buy
+    nothing. The barriers are write_atomic's."""
+    _write_file(path, data)
     fsync_dir(os.path.dirname(path) or ".")
 
 

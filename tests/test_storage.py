@@ -13,6 +13,7 @@ from minio_tpu.storage import (BLOCK_SIZE_V1, FileInfo, FormatErasureV3,
                                get_format_in_quorum, hash_order,
                                new_file_info, new_format_erasure_v3)
 from minio_tpu.storage.xl_meta import is_xl2_v1_format
+from minio_tpu.utils import telemetry
 
 
 # ---------------------------------------------------------------------------
@@ -258,6 +259,207 @@ def test_drive_rename_data_two_phase_commit(drive):
     got2 = drive.read_version("b", "obj")
     assert got2.data_dir == fi2.data_dir
     assert len(drive.read_versions("b", "obj")) == 1  # null replaced
+
+
+TMP_VOL = ".minio.sys/tmp"
+# nanoseconds that no float holds exactly: the journal entry of a
+# handed FileInfo must be the one a read-back of the staged file gives
+AWKWARD_MTIME = 1727983196.1234567
+
+
+def _tree(root):
+    """{relative path: bytes} of every file under a drive's root, and
+    every directory (as None) — the on-disk state, byte for byte."""
+    out = {}
+    for dirpath, dirs, files in os.walk(root):
+        rel = os.path.relpath(dirpath, root)
+        out[rel] = None
+        for f in files:
+            with open(os.path.join(dirpath, f), "rb") as fh:
+                out[os.path.join(rel, f)] = fh.read()
+    return out
+
+
+def _stage_and_commit(drive, fi, key, handed, tmp_id=None, body=b"shard"):
+    """One drive's share of a PUT commit: shard in tmp, staged journal,
+    rename_data — `handed`: the way engine._commit does it (fresh
+    staging, the FileInfo passed on); else the way multipart complete
+    and heal do (journal read back)."""
+    tmp_id = tmp_id or str(uuid.uuid4())
+    drive.write_all(TMP_VOL, f"{tmp_id}/{fi.data_dir}/part.1", body)
+    if handed:
+        drive.write_metadata(TMP_VOL, tmp_id, fi, fresh=True)
+        drive.rename_data(TMP_VOL, tmp_id, fi.data_dir, "b", key, fi=fi)
+    else:
+        drive.write_metadata(TMP_VOL, tmp_id, fi)
+        drive.rename_data(TMP_VOL, tmp_id, fi.data_dir, "b", key)
+    return tmp_id
+
+
+def _legacy_object(drive, key):
+    import json
+    obj_dir = os.path.join(drive.root, "b", key)
+    os.makedirs(obj_dir)
+    v1 = {"version": "1.0.1", "format": "xl",
+          "stat": {"size": 7, "modTime": "2020-09-01T12:00:00Z"},
+          "erasure": {"algorithm": "klauspost/reedsolomon/vandermonde",
+                      "data": 4, "parity": 2, "blockSize": 1048576,
+                      "index": 3, "distribution": [3, 4, 5, 6, 1, 2],
+                      "checksum": [{"name": "part.1",
+                                    "algorithm": "highwayhash256S",
+                                    "hash": ""}]},
+          "minio": {"release": "RELEASE.2020"},
+          "meta": {"etag": "abcd"},
+          "parts": [{"number": 1, "name": "part.1", "etag": "abcd",
+                     "size": 7, "actualSize": 7}]}
+    with open(os.path.join(obj_dir, "xl.json"), "w") as f:
+        json.dump(v1, f)
+
+
+@pytest.mark.parametrize("case", ["fresh", "nested", "overwrite",
+                                  "versioned", "legacy", "prefix-dir"])
+def test_rename_data_handed_fi_commits_the_same_bytes(tmp_path, case):
+    """rename_data with the FileInfo handed over and without leave the
+    same tree on the drive, byte for byte: the committed xl.meta, the
+    data directory, nothing in tmp."""
+    key = "a/b/obj" if case == "nested" else "obj"
+    first = _sample_fi(version_id=str(uuid.uuid4())
+                       if case == "versioned" else "", mod_time=1000.25)
+    second = _sample_fi(version_id=str(uuid.uuid4())
+                        if case in ("versioned", "legacy") else "",
+                        mod_time=AWKWARD_MTIME)
+    second.erasure.index = 3
+    trees, found = [], []
+    for handed in (True, False):
+        d = XLStorage(str(tmp_path / f"drive-{int(handed)}"))
+        d.make_vol_bulk(".minio.sys", TMP_VOL, "b")
+        if case in ("overwrite", "versioned"):
+            _stage_and_commit(d, first, key, handed=False, body=b"old")
+        elif case == "legacy":
+            _legacy_object(d, key)
+        elif case == "prefix-dir":
+            _stage_and_commit(d, first, key + "/under", handed=False)
+        telemetry.SPANS.record_begin()
+        try:
+            with telemetry.trace("t"):
+                _stage_and_commit(d, second, key, handed, tmp_id="stg")
+        finally:
+            spans = telemetry.SPANS.record_end()["spans"]
+        found.append([(sp["attrs"]["src_read"], sp["attrs"]["dst"])
+                      for sp in spans if sp["name"] == "disk.rename_data"])
+        trees.append(_tree(d.root))
+        got = d.read_version("b", key, second.version_id)
+        assert got.data_dir == second.data_dir and got.erasure.index == 3
+        assert d.read_all("b", f"{key}/{second.data_dir}/part.1") \
+            == b"shard"
+        assert d.list_dir(TMP_VOL, "") == []        # staging is gone
+        n = len(d.read_versions("b", key))
+        assert n == (2 if case in ("versioned", "legacy") else 1)
+        if case == "legacy":
+            assert not os.path.exists(
+                os.path.join(d.root, "b", key, "xl.json"))
+    assert trees[0] == trees[1]
+    dst = {"overwrite": "journal", "versioned": "journal",
+           "legacy": "legacy"}.get(case, "fresh")
+    assert found == [[(0, dst)], [(1, dst)]]
+
+
+def test_rename_data_handed_fi_never_opens_the_staged_journal(
+        drive, monkeypatch):
+    drive.make_vol_bulk(TMP_VOL, "b")
+    fi = _sample_fi(mod_time=AWKWARD_MTIME)
+    reads = []
+    real = drive.read_all
+    monkeypatch.setattr(
+        drive, "read_all",
+        lambda vol, path: (reads.append((vol, path)), real(vol, path))[1])
+    _stage_and_commit(drive, fi, "obj", handed=True, tmp_id="stg")
+    assert not [r for r in reads if r[0] == TMP_VOL], reads
+    reads.clear()
+    fi2 = _sample_fi(mod_time=2000.0)
+    _stage_and_commit(drive, fi2, "obj", handed=False, tmp_id="stg2")
+    assert (TMP_VOL, "stg2/xl.meta") in reads       # the spy does see
+
+
+def test_write_metadata_fresh_writes_what_write_metadata_writes(drive):
+    drive.make_vol_bulk(TMP_VOL)
+    fi = _sample_fi(mod_time=AWKWARD_MTIME, n_parts=2)
+    os.makedirs(os.path.join(drive.root, TMP_VOL, "made"))
+    drive.write_metadata(TMP_VOL, "made", fi, fresh=True)
+    drive.write_metadata(TMP_VOL, "plain", fi)
+    # no writer has made this staging directory (a 0-byte object
+    # through a writer that makes none): the write makes it
+    drive.write_metadata(TMP_VOL, "unmade/deeper", fi, fresh=True)
+    want = drive.read_all(TMP_VOL, "plain/xl.meta")
+    assert drive.read_all(TMP_VOL, "made/xl.meta") == want
+    assert drive.read_all(TMP_VOL, "unmade/deeper/xl.meta") == want
+    # written in place: no temp sibling was renamed over it or left
+    assert drive.list_dir(TMP_VOL, "made") == ["xl.meta"]
+
+
+@pytest.mark.parametrize("stray", [False, True])
+def test_rename_data_drops_the_staging_directory(drive, stray):
+    """An unlink and an rmdir where the staging directory holds its
+    journal and no more; the recursive delete where it holds more."""
+    drive.make_vol_bulk(TMP_VOL, "b")
+    fi = _sample_fi()
+    if stray:
+        drive.write_all(TMP_VOL, "stg/stray.bin", b"?")
+        drive.write_all(TMP_VOL, "stg/sub/deeper.bin", b"?")
+    _stage_and_commit(drive, fi, "obj", handed=True, tmp_id="stg")
+    assert drive.list_dir(TMP_VOL, "") == []
+    assert drive.read_version("b", "obj").data_dir == fi.data_dir
+
+
+def test_rename_data_replayed_leaves_the_committed_data_dir(drive):
+    """The same commit asked for twice (a retried call): the second
+    finds no staging and fails — with the FileInfo handed over nothing
+    is read from it first, so the data dir check is what stops it."""
+    drive.make_vol_bulk(TMP_VOL, "b")
+    fi = _sample_fi()
+    _stage_and_commit(drive, fi, "obj", handed=True, tmp_id="stg")
+    for handed_fi in (fi, None):
+        with pytest.raises(errors.FileNotFound):
+            drive.rename_data(TMP_VOL, "stg", fi.data_dir, "b", "obj",
+                              fi=handed_fi)
+        assert drive.read_all("b", f"obj/{fi.data_dir}/part.1") == b"shard"
+        assert drive.read_version("b", "obj").data_dir == fi.data_dir
+
+
+def test_rename_data_into_a_missing_volume_is_volume_not_found(drive):
+    drive.make_vol_bulk(TMP_VOL)
+    fi = _sample_fi()
+    for handed in (True, False):
+        with pytest.raises(errors.VolumeNotFound):
+            _stage_and_commit(drive, fi, "obj", handed)
+        assert not os.path.exists(os.path.join(drive.root, "b"))
+
+
+@pytest.mark.parametrize("wrapper", ["naughty", "diskid"])
+def test_wrappers_pass_fresh_and_fi_through(drive, wrapper):
+    from minio_tpu.storage.diskid_check import DiskIDCheck
+    from minio_tpu.storage.naughty import NaughtyDisk
+    drive.make_vol_bulk(TMP_VOL, "b")
+    seen = {}
+    real_wm, real_rd = drive.write_metadata, drive.rename_data
+
+    def write_metadata(volume, path, fi, fresh=False):
+        seen["fresh"] = fresh
+        return real_wm(volume, path, fi, fresh)
+
+    def rename_data(sv, sp, dd, dv, dp, version_id="", fi=None):
+        seen["fi"] = fi
+        return real_rd(sv, sp, dd, dv, dp, version_id, fi)
+
+    drive.write_metadata, drive.rename_data = write_metadata, rename_data
+    w = NaughtyDisk(drive) if wrapper == "naughty" \
+        else DiskIDCheck(drive, drive.get_disk_id())
+    fi = _sample_fi()
+    _stage_and_commit(w, fi, "obj", handed=True)
+    assert seen == {"fresh": True, "fi": fi}
+    _stage_and_commit(w, _sample_fi(mod_time=2000.0), "obj", handed=False)
+    assert seen == {"fresh": False, "fi": None}
+    assert w.read_version("b", "obj").mod_time == 2000.0
 
 
 def test_drive_walk(drive):

@@ -104,6 +104,55 @@ def test_metadata_verbs(cluster):
         r.read_version("v", "obj")
 
 
+def test_commit_verbs_round_trip_fresh_and_fi(cluster):
+    """write_metadata's `fresh` and rename_data's `fi` cross the wire:
+    the remote drive runs the drive code it was handed them for, and a
+    call without them runs the read-back as before."""
+    locals_, remotes = cluster
+    r, local = remotes[3], locals_["/d3"]
+    r.make_vol("v")
+    local.make_vol_bulk(".minio.sys/tmp")
+    seen = []
+    real_wm, real_rd = local.write_metadata, local.rename_data
+
+    def write_metadata(volume, path, fi, fresh=False):
+        seen.append(("wm", fresh))
+        return real_wm(volume, path, fi, fresh)
+
+    def rename_data(sv, sp, dd, dv, dp, version_id="", fi=None):
+        seen.append(("rd", fi))
+        return real_rd(sv, sp, dd, dv, dp, version_id, fi)
+
+    local.write_metadata, local.rename_data = write_metadata, rename_data
+    fi = new_file_info("v/obj", 4, 2)
+    fi.volume, fi.name, fi.size = "v", "obj", 5
+    fi.mod_time = 1234567890.5
+    fi.data_dir = "11111111-2222-3333-4444-555555555555"
+    fi.metadata = {"etag": "deadbeef"}
+    fi.add_object_part(1, "deadbeef", 5, 5)
+    fi.erasure.index = 4
+    fi.erasure.checksums = [ChecksumInfo(1, "highwayhash256S", b"")]
+    tmp = ".minio.sys/tmp"
+    r.append_file(tmp, f"stg/{fi.data_dir}/part.1", b"shard")
+    r.write_metadata(tmp, "stg", fi, fresh=True)
+    r.rename_data(tmp, "stg", fi.data_dir, "v", "obj", fi=fi)
+    assert seen[0] == ("wm", True)
+    assert seen[1][0] == "rd" and fi_to_dict(seen[1][1]) == fi_to_dict(fi)
+    got = r.read_version("v", "obj")
+    assert got.erasure.index == 4 and got.data_dir == fi.data_dir
+    assert r.read_all("v", f"obj/{fi.data_dir}/part.1") == b"shard"
+    assert r.list_dir(tmp, "") == []
+    # the same commit, nothing handed over: byte-identical journal
+    committed = local.read_all("v", "obj/xl.meta")
+    r.delete_version("v", "obj", got)
+    del seen[:]
+    r.append_file(tmp, f"stg/{fi.data_dir}/part.1", b"shard")
+    r.write_metadata(tmp, "stg", fi)
+    r.rename_data(tmp, "stg", fi.data_dir, "v", "obj")
+    assert seen == [("wm", False), ("rd", None)]
+    assert local.read_all("v", "obj/xl.meta") == committed
+
+
 def test_fi_codec_roundtrip():
     fi = new_file_info("b/o", 12, 4)
     fi.volume, fi.name, fi.size = "b", "o", 999
