@@ -279,3 +279,41 @@ def heal_step(survivors: jax.Array, matrix_bits: jax.Array, r: int,
     recovered, digests = _reconstruct_and_hash(
         survivors, matrix_bits, r, k, shard_len, key, algo)
     return recovered, digests[:, :k], digests[:, k:]
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def put_step_ragged(data: jax.Array, lengths: jax.Array, k: int, m: int,
+                    key: bytes = b"", algo: str = "highwayhash"
+                    ) -> tuple[jax.Array, jax.Array]:
+    """`put_step` for a launch some of whose blocks are SHORT: the
+    short last block of an object rides the group of its whole blocks.
+
+    data: (B, k, S) uint8 at the geometry's full-block S; a short
+    block's shards fill the first lengths[b] columns of its rows and
+    the rest is zero. lengths: (B,) int32, each block's own shard
+    length (ceil(block bytes / k); S for a whole block and for a pad
+    block). GF coding is column-independent, so the zero columns encode
+    to zero parity and the first lengths[b] columns are exactly what
+    the block alone would encode to; the digests cover lengths[b] bytes
+    of each of the block's k+m rows (ops/highwayhash_jax.
+    hh256_batch_ragged — the lengths are an operand, so one program a
+    (B, k, S) serves every mix of short blocks). Returns (parity
+    (B, m, S), digests (B, k+m, 32)) as `put_step` does; the host keeps
+    parity[b, :, :lengths[b]]. Only HighwayHash has the ragged kernel.
+    """
+    b, k_, s = data.shape
+    assert k_ == k and algo == "highwayhash"
+    from ..bitrot import MAGIC_HIGHWAYHASH_KEY
+    from ..ops import highwayhash_jax
+    pm = np.asarray(rs_matrix.parity_matrix(k, m))
+    m2 = rs_tpu._bit_expand_cached(pm.tobytes(), pm.shape)
+    parity = _rs_matmul(m2, data, m, k)
+    with jax.named_scope("pack"):
+        rows = jnp.concatenate([data, parity],
+                               axis=-2).reshape(b * (k + m), s)
+        row_lengths = jnp.repeat(lengths.astype(jnp.int32), k + m)
+    with jax.named_scope("bitrot_hash"):
+        digests = highwayhash_jax._hh256_ragged_impl(
+            rows, row_lengths, bytes(key or MAGIC_HIGHWAYHASH_KEY))
+    with jax.named_scope("pack"):
+        return parity, digests.reshape(b, k + m, 32)

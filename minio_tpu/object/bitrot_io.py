@@ -97,9 +97,12 @@ class StreamingBitrotWriter:
         in one call. A local append handle takes them in ONE vectored
         write — the handle's business how (an O_DIRECT handle stages
         them through its aligned buffer) — where a write a digest and a
-        write a block crossed the interpreter lock 2 x B times. Remote
-        drives keep the buffered append_file batches. Returns (write
-        calls issued, whether the handle took the list vectored)."""
+        write a block crossed the interpreter lock 2 x B times. The
+        blocks need not be of one length: a group that ends in an
+        object's short last block goes out in the same one call (the
+        vector is 2 x B buffers of whatever sizes). Remote drives keep
+        the buffered append_file batches. Returns (write calls issued,
+        whether the handle took the list vectored)."""
         if self._use_appender:
             frames = [buf for pair in zip(digests, blocks) for buf in pair]
             try:

@@ -23,7 +23,7 @@ import numpy as np
 
 from ..models import pipeline
 from ..ops import gf256, rs_matrix, rs_ref, rs_tpu
-from ..utils import device, knobs, native
+from ..utils import device, eventlog, knobs, native
 
 # Batches at least this large go to the device (dispatch+transfer amortized).
 DEVICE_MIN_BYTES = knobs.get_int("MINIO_TPU_DEVICE_MIN_BYTES")
@@ -67,9 +67,11 @@ def data_path_line() -> str:
 class _Fused(NamedTuple):
     """One fused device program of the data path: what differs between
     the five, and nothing else — `Codec._launch` holds what they share.
-    `FUSED` names a row by the Codec method that enters it; `static`
-    below is that method's arguments between the data and the bitrot
-    algorithm, the per-row arrays left out.
+    `FUSED` names a row by the Codec method that enters it (the ragged
+    row: by its method and `.ragged` — `encode_and_hash_batch` takes
+    it when a block of the launch is short); `static` below is that
+    method's arguments between the data and the bitrot algorithm, the
+    per-row arrays left out.
 
     step       jitted step in models/pipeline.py, looked up there at
                call time
@@ -142,6 +144,12 @@ FUSED = {
     "verify_and_recover_batch": _Fused(
         "heal_step", "recover", _recover_operands,
         mesh="mesh_verify_and_recover", shared_at=1),
+    # a launch some of whose blocks are short (an object's short last
+    # block rides the group of its whole blocks): each block's shard
+    # length rides as a per-row array. The mesh has no ragged program:
+    # on a mesh host this launches on one device
+    "encode_and_hash_batch.ragged": _Fused(
+        "put_step_ragged", "encode", _encode_operands),
 }
 
 
@@ -267,18 +275,22 @@ class Codec:
         its `.lower` (boot's load), so both make the same program."""
         return step(arrays[0], *lead, *arrays[1:], *tail, algo=kernel)
 
-    def load_encode_program(self, blocks: int, cuts, algo) -> None:
+    def load_encode_program(self, blocks: int, cuts, algo,
+                            ragged: bool = False) -> None:
         """Lower and compile, without running them, the programs an
         encode launch at rung `blocks` can need: the fused step through
         the table row and call form `_launch` uses — so the loaded
         executable is the one a request hits — and the cut of its
         outputs to each real count in `cuts`. Boot's loader
-        (parallel/ladder.load_encode) asks."""
-        row = FUSED["encode_and_hash_batch"]
+        (parallel/ladder.load_encode) asks; `ragged`: for the row of a
+        launch that carries short blocks."""
+        row = FUSED["encode_and_hash_batch" + (".ragged" if ragged else "")]
         _shared, lead, tail = row.operands(self)
-        data = jax.ShapeDtypeStruct(
-            (blocks, self.k, self.shard_size), np.uint8)
-        step = self._step_call(getattr(pipeline, row.step).lower, (data,),
+        arrays = (jax.ShapeDtypeStruct(
+            (blocks, self.k, self.shard_size), np.uint8),)
+        if ragged:
+            arrays += (jax.ShapeDtypeStruct((blocks,), np.int32),)
+        step = self._step_call(getattr(pipeline, row.step).lower, arrays,
                                lead, tail, self._device_hash_kernel(algo))
         step.compile()
         for n in cuts:
@@ -286,7 +298,8 @@ class Codec:
 
     def _launch(self, row: _Fused, data: np.ndarray, row_arrays, static,
                 algo, *, force: str = "", stage_cb=None,
-                blocks: Optional[int] = None):
+                blocks: Optional[int] = None,
+                nbytes: Optional[int] = None):
         """One launch of the fused program `row` names over data
         (B, k, S), the per-row arrays beside it and the entry's static
         arguments -> the entry's result tuple, or None when the batch
@@ -311,12 +324,18 @@ class Codec:
         former's staging buffer) and how many of its rows are real; the
         small per-row arrays are still brought up to it. A padded
         launch's pad rows are cut off ON THE DEVICE, so no result
-        holds one and none crosses back."""
+        holds one and none crosses back.
+
+        `nbytes`, when given, is what the launch holds of REAL bytes
+        (a ragged launch: its blocks' own lengths, not the zero
+        columns beside them) and is what the route is asked with."""
         kernel = self._device_hash_kernel(algo)
         if kernel is None:
             return None
         real = data if blocks is None else data[:blocks]
-        mesh = self._mesh_route(real.nbytes, force) if row.mesh else None
+        if nbytes is None:
+            nbytes = real.nbytes
+        mesh = self._mesh_route(nbytes, force) if row.mesh else None
         if mesh is not None:
             from ..parallel import mesh as pmesh
             t0 = time.perf_counter()
@@ -326,15 +345,14 @@ class Codec:
                 if stage_cb is not None:
                     stage_cb("compute", time.perf_counter() - t0)
                 return out
-        if (force or self._route(real.nbytes)) != "device":
+        if (force or self._route(nbytes)) != "device":
             return None
         operands = row.operands(self, *static)
         if operands is None:
             return None
         shared, lead, tail = operands
         from ..parallel import ladder
-        n, to = (data.shape[0], ladder.rung(row.verb, data.shape[0])) \
-            if blocks is None else (blocks, data.shape[0])
+        n, to = ladder.launch_size(row.verb, data.shape[0], blocks)
         arrays = tuple(ladder.pad_blocks(a, to)
                        for a in (data, *row_arrays))
         if stage_cb is not None:
@@ -363,7 +381,8 @@ class Codec:
     # The five entries: arguments -> table row + operands -> `_launch`,
     # whose keyword arguments (force, stage_cb, blocks) they pass on.
 
-    def encode_and_hash_batch(self, data: np.ndarray, algo, **launch):
+    def encode_and_hash_batch(self, data: np.ndarray, algo, lengths=None,
+                              **launch):
         """Fused device path for the PUT hot loop: one program computes
         parity AND every shard's HighwayHash256 digest (the reference's
         Erasure.Encode + streaming-bitrot work, cmd/erasure-encode.go:75 +
@@ -373,11 +392,52 @@ class Codec:
         (B, k+m, 32)) on every route, or None. Only parity + digests
         cross back from the device: the k data rows stay the caller's
         own bytes.
+
+        lengths: (B,) each block's own shard length, for a launch that
+        carries SHORT blocks (an object's last block, laid out as
+        `split` lays it in the first lengths[b] columns of its rows,
+        zero beyond): the digests then cover lengths[b] bytes a row and
+        the caller keeps parity[b, :, :lengths[b]]. A launch none of
+        whose blocks is short runs the static program as if `lengths`
+        had not been given; one with a short block runs the ragged row
+        at the same rung, is routed by its real bytes, and — when the
+        bitrot algorithm has no ragged kernel — goes to the host whole.
         """
         if self.m == 0:
             return None
+        if lengths is not None:
+            from ..parallel import ladder
+            n, to = ladder.launch_size("encode", data.shape[0],
+                                       launch.get("blocks"))
+            lengths = np.asarray(lengths, np.int32)[:n]
+            if (lengths < data.shape[2]).any():
+                return self._encode_ragged(data, algo, lengths, to,
+                                           **launch)
         return self._launch(FUSED["encode_and_hash_batch"], data, (), (),
                             algo, **launch)
+
+    def _encode_ragged(self, data: np.ndarray, algo, lengths: np.ndarray,
+                       to: int, **launch):
+        """`encode_and_hash_batch` for a launch with a short block:
+        `lengths` of its real blocks, `to` the rung it runs at."""
+        from ..parallel import ladder
+        if self._device_hash_kernel(algo) != "highwayhash":
+            eventlog.emit_once("device.decline", stage="encode",
+                               reason="no-ragged-kernel")
+            return None
+        # pad blocks at the full length, as the static program has them
+        at_rung = np.full(to, data.shape[2], np.int32)
+        at_rung[:len(lengths)] = lengths
+        real = int(lengths.sum()) * self.k
+        if not launch.get("force") and self._route(real) == "device":
+            # the first launch with a short block that the device takes
+            # starts the load of the row's rungs (a node's own small
+            # objects are short blocks too, and stay on the host: boot
+            # loads none of this), and every launch waits for its own
+            ladder.load_encode_ragged(self, algo, want=to)
+            ladder.await_ragged(self, algo, to)
+        return self._launch(FUSED["encode_and_hash_batch.ragged"], data,
+                            (at_rung,), (), algo, nbytes=real, **launch)
 
     def encrypt_encode_and_hash_batch(self, data: np.ndarray, keys,
                                       nonces, pkg_bytes: int, algo,

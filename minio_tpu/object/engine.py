@@ -481,8 +481,11 @@ class ErasureObjects:
                     item["rows"] = self._sse_encode(codec, data, item,
                                                     fut, sse)
                 else:
+                    lengths = item["lengths"]
                     item["rows"] = self._unpack_fused(
-                        codec, data, self._fused_encode(codec, data, fut))
+                        codec, data,
+                        self._fused_encode(codec, data, fut, lengths),
+                        lengths=lengths)
             stage_s[1] += t.seconds
             return item
 
@@ -493,17 +496,20 @@ class ErasureObjects:
                     for rows, parity, dd, dp in (
                             item["rows_multi"] if "rows_multi" in item
                             else [item["rows"]]):
-                        self._write_shards_batch(rows, parity, dd, dp,
-                                                 writers, write_quorum)
+                        self._write_shards_batch(
+                            rows, parity, dd, dp, writers, write_quorum,
+                            lengths=item.get("lengths"))
             finally:
                 recycle(item)
                 stage_s[2] += t.seconds
 
         pipe = None
 
-        def feed(data) -> None:
+        def feed(data, lengths=None) -> None:
             """Hand the CURRENT buffer (if any) plus `data` to the
             pipeline, spinning the stage threads up on first use.
+            `lengths`: of the stream's last group, when it ends in a
+            short block (`_lay_short_block`).
             Buffer ownership transfers to the item BEFORE submit — if
             submit raises a pending stage error, on_drop recycles the
             item's buffer and the caller's finally must not recycle it
@@ -516,7 +522,7 @@ class ErasureObjects:
                                         depth=pl.DEPTH, name="put-pipe",
                                         on_drop=recycle)
             owned, buf = buf, None
-            item = {"buf": owned, "data": data}
+            item = {"buf": owned, "data": data, "lengths": lengths}
             if sse is not None:
                 # per-row key/nonce word arrays ride the dispatch; the
                 # bucket key carries only their shape, so concurrent
@@ -530,7 +536,8 @@ class ErasureObjects:
                     if self.scheduler is not None else None)
             else:
                 fut = (self.scheduler.submit(codec, data,
-                                             self.bitrot_algo)
+                                             self.bitrot_algo,
+                                             lengths=lengths)
                        if self.scheduler is not None else None)
             item["fut"] = fut
             pipe.submit(item)
@@ -554,6 +561,7 @@ class ErasureObjects:
         buf = None
         enc_off = 0       # plaintext stream offset of the next sse batch
         tail_pt = b""     # short last block (plaintext) under sse
+        lengths = None    # of the last group, when it ends short
         # pulling the body through the hash reader into the ring: one
         # span per group of blocks (busy time + call count), not one
         # per block
@@ -589,39 +597,25 @@ class ErasureObjects:
                         # after them in stage FIFO order
                         tail_pt = bytes(arr[nb][:n])
                         break
-                    # short last block: its shard length differs —
-                    # flush the pending full rows first, then the
-                    # short block alone (split copies it out of the
-                    # ring; whichever item takes the buffer recycles
-                    # it)
-                    with telemetry.span("put.split"):
-                        data = codec.split(arr[nb][:n])[None, ...]
-                    if pipe is None:
-                        # unknown-length stream that fit one batch:
-                        # encode+write inline — no stage threads
-                        reads.flush(blocks=nb + 1)
-                        if nb:
-                            self._encode_write(
-                                codec, arr[:nb].reshape(nb, k, s_len),
-                                writers, write_quorum)
-                        self._encode_write(codec, data, writers,
-                                           write_quorum)
-                    else:
-                        if nb:
-                            feed(arr[:nb].reshape(nb, k, s_len))
-                        feed(data)
-                    nb = 0
+                    # short last block: it is the last row of the
+                    # group of the whole blocks before it, and goes
+                    # with them below
+                    lengths = _lay_short_block(arr, nb, n, k, s_len)
+                    nb += 1
                     break
             reads.flush(blocks=nb)
             if nb:
                 if pipe is None:
+                    # a stream that fit one batch (an unknown-length
+                    # one too): encode+write inline, no stage threads
                     self._encode_write(codec,
                                        arr[:nb].reshape(nb, k, s_len),
                                        writers, write_quorum,
-                                       sse=sse, sse_off=enc_off)
+                                       sse=sse, sse_off=enc_off,
+                                       lengths=lengths)
                     enc_off += nb * bs
                 else:
-                    feed(arr[:nb].reshape(nb, k, s_len))
+                    feed(arr[:nb].reshape(nb, k, s_len), lengths)
             if sse is not None:
                 if pipe is None:
                     for rows in self._sse_finish_rows(codec, sse,
@@ -669,6 +663,7 @@ class ErasureObjects:
         nb = 0
         enc_off = 0
         tail_pt = b""
+        lengths = None    # of the last group, when it ends short
         reads = telemetry.accum("put.read_stream")
 
         def flush_full(n_rows: int) -> None:
@@ -678,7 +673,8 @@ class ErasureObjects:
                 self._encode_write(codec,
                                    buf[:n_rows].reshape(n_rows, k, s_len),
                                    writers, write_quorum,
-                                   sse=sse, sse_off=enc_off)
+                                   sse=sse, sse_off=enc_off,
+                                   lengths=lengths)
                 enc_off += n_rows * bs
 
         while True:
@@ -700,13 +696,10 @@ class ErasureObjects:
                     # in the finish batches (after flush_full below)
                     tail_pt = bytes(row[:n])
                     break
-                # short last block: its shard length differs — encode
-                # the pending full rows first, then it alone
-                flush_full(nb)
-                nb = 0
-                with telemetry.span("put.split"):
-                    data = codec.split(row[:n])[None, ...]
-                self._encode_write(codec, data, writers, write_quorum)
+                # short last block: the last row of the group of the
+                # whole blocks before it — one encode, one fan-out
+                lengths = _lay_short_block(buf, nb, n, k, s_len)
+                nb += 1
                 break
         flush_full(nb)
         if sse is not None:
@@ -717,22 +710,25 @@ class ErasureObjects:
             return encrypted_size(total)
         return total
 
-    def _fused_encode(self, codec: Codec, data: np.ndarray, fut=None):
+    def _fused_encode(self, codec: Codec, data: np.ndarray, fut=None,
+                      lengths=None):
         """(parity, digests) of one plain batch off the device — from
         its future, the shared batch former, or the codec itself when
-        the engine runs without a former — or None (local CPU path)."""
+        the engine runs without a former — or None (local CPU path).
+        `lengths`: of a group that ends in a short block."""
         if fut is not None:
             # check: allow(deadline) device dispatch; scheduler close() flushes waiters
             return fut.result()
         if self.scheduler is not None:
             # the cross-request scheduler coalesces concurrent PUT
             # streams into shared dispatches
-            return self.scheduler.encode_and_hash(codec, data,
-                                                  self.bitrot_algo)
-        return codec.encode_and_hash_batch(data, self.bitrot_algo)
+            return self.scheduler.encode_and_hash(
+                codec, data, self.bitrot_algo, lengths=lengths)
+        return codec.encode_and_hash_batch(data, self.bitrot_algo,
+                                           lengths=lengths)
 
     def _unpack_fused(self, codec: Codec, data: np.ndarray, fused,
-                      ciphertext: bool = False
+                      ciphertext: bool = False, lengths=None
                       ) -> tuple[np.ndarray, np.ndarray, np.ndarray,
                                  np.ndarray]:
         """(data_rows, parity, data_digests, parity_digests) from one
@@ -741,13 +737,38 @@ class ErasureObjects:
         is (parity, digests): the data rows stay views of the caller's
         staging buffer, on the device path as on the CPU path. Under
         SSE (`ciphertext`) it is (full, digests) and the data rows are
-        the ciphertext the device made."""
+        the ciphertext the device made. `lengths`: of a group that ends
+        in a short block — the CPU fallback encodes and hashes that row
+        over its own length, so the bytes `_write_shards_batch` frames
+        are the same on every route."""
         if fused is not None:
             rows, digests = fused
             dd, dp = digests[:, :codec.k], digests[:, codec.k:]
             if ciphertext:
                 return rows[:, :codec.k], rows[:, codec.k:], dd, dp
             return data, rows, dd, dp
+        if lengths is None or lengths[-1] == data.shape[2]:
+            return (data, *self._host_encode(codec, data))
+        # the whole rows together, then the short row over its own
+        # length; its parity lies zero-padded among the others'
+        b_, s_len = data.shape[0], data.shape[2]
+        parts = [self._host_encode(
+            codec, np.ascontiguousarray(data[lo:hi, :, :cols]))
+            for lo, hi, cols in ((0, b_ - 1, s_len),
+                                 (b_ - 1, b_, int(lengths[-1])))
+            if lo < hi]
+        parity = np.zeros((b_, codec.m, s_len), dtype=np.uint8)
+        at = 0
+        for part, _dd, _dp in parts:
+            parity[at:at + part.shape[0], :, :part.shape[2]] = part
+            at += part.shape[0]
+        return (data, parity, np.concatenate([p[1] for p in parts]),
+                np.concatenate([p[2] for p in parts]))
+
+    def _host_encode(self, codec: Codec, data: np.ndarray
+                     ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """(parity, data_digests, parity_digests) of one (B, k, S)
+        batch on the local CPU path."""
         b_ = data.shape[0]
         parity = codec.encode_parity_batch(data)
         dd = bitrot_mod.hash_shards_batch(
@@ -759,16 +780,17 @@ class ErasureObjects:
             ).reshape(b_, codec.m, -1)
         else:
             dp = np.zeros((b_, 0, dd.shape[-1]), dtype=np.uint8)
-        return data, parity, dd, dp
+        return parity, dd, dp
 
     def _encode_write(self, codec: Codec, data: np.ndarray, writers,
-                      write_quorum: int, sse=None, sse_off: int = 0
-                      ) -> None:
+                      write_quorum: int, sse=None, sse_off: int = 0,
+                      lengths=None) -> None:
         """Encode+digest one (B, k, S) batch and fan the framed shard
         writes out — data rows go to the writers as views of `data`.
         With `sse`, the batch rows are PLAINTEXT full blocks starting
         at stream offset `sse_off` and the cipher fuses in (or falls
-        back to the in-place CPU cipher)."""
+        back to the in-place CPU cipher). `lengths`: of a plain group
+        that ends in a short block (`_lay_short_block`)."""
         with telemetry.span("pipeline.encode", blocks=data.shape[0]):
             if sse is not None:
                 item = {"sse_kn": sse.batch_params(
@@ -784,10 +806,12 @@ class ErasureObjects:
                 # fused device encode+digest when routed there (one
                 # program, one round-trip)
                 data_rows, parity, dd, dp = self._unpack_fused(
-                    codec, data, self._fused_encode(codec, data))
+                    codec, data,
+                    self._fused_encode(codec, data, lengths=lengths),
+                    lengths=lengths)
         with telemetry.span("pipeline.shard_write"):
             self._write_shards_batch(data_rows, parity, dd, dp, writers,
-                                     write_quorum)
+                                     write_quorum, lengths=lengths)
 
     def _sse_encode(self, codec: Codec, data: np.ndarray, item, fut,
                     sse) -> tuple[np.ndarray, np.ndarray, np.ndarray,
@@ -844,7 +868,8 @@ class ErasureObjects:
 
     def _write_shards_batch(self, data: np.ndarray, parity: np.ndarray,
                             dd: np.ndarray, dp: np.ndarray,
-                            writers, write_quorum: int) -> None:
+                            writers, write_quorum: int,
+                            lengths=None) -> None:
         """parallelWriter.Write, batched: writer i gets ALL B of its
         [digest‖block] frames in one call (cmd/erasure-encode.go:38-72's
         per-disk goroutine — but fanned out once per encode batch, not
@@ -852,8 +877,12 @@ class ErasureObjects:
         over as rows of the encode output, which a local drive takes in
         one vectored write, copy-free down to the kernel). Data and
         parity arrive as separate arrays so the data rows stay views of
-        the read buffer."""
+        the read buffer. `lengths`: each block's own shard length, of a
+        group that ends in a short block — its frame is
+        [digest][lengths[b] bytes], in the same write as the others."""
         B, k = data.shape[0], data.shape[1]
+        ends = [data.shape[2]] * B if lengths is None \
+            else [int(n) for n in lengths]
 
         def write(i: int, w) -> None:
             rows, digs, j = (data, dd, i) if i < k else \
@@ -864,7 +893,8 @@ class ErasureObjects:
                 # C-contiguous as it is; one that is not is copied
                 # alone, never the group
                 writes, vectored = w.write_frames(
-                    [np.ascontiguousarray(rows[bi, j]) for bi in range(B)],
+                    [np.ascontiguousarray(rows[bi, j, :ends[bi]])
+                     for bi in range(B)],
                     [np.ascontiguousarray(digs[bi, j]) for bi in range(B)])
                 t.annotate(writes=writes, vectored=int(vectored))
             healthtrack.observe_disk(w.disk, "write", t.seconds)
@@ -2401,6 +2431,28 @@ def _read_full(reader, n: int) -> bytes:
             break
         buf += chunk
     return buf
+
+
+def _lay_short_block(buf: np.ndarray, row: int, n: int, k: int,
+                     s_len: int) -> np.ndarray:
+    """The short last block of a stream, read into the first n bytes of
+    buf[row] (a (k * s_len,) row of a staging buffer), laid out in
+    place as `Codec.split` lays it — shard i = bytes [i*S_t, (i+1)*S_t)
+    of the block, S_t = ceil(n / k), in the first S_t columns of row
+    i of the (k, s_len) view, zero everywhere else — so that it is one
+    more block of its group at the full shard length. -> the group's
+    (row + 1,) shard lengths: s_len for the whole blocks, S_t last."""
+    s_t = -(-n // k)
+    block = buf[row, :n].copy()
+    buf[row] = 0
+    shards = buf[row].reshape(k, s_len)
+    whole, rest = divmod(n, s_t)
+    shards[:whole, :s_t] = block[:whole * s_t].reshape(whole, s_t)
+    if rest:
+        shards[whole, :rest] = block[whole * s_t:]
+    lengths = np.full(row + 1, s_len, np.int32)
+    lengths[row] = s_t
+    return lengths
 
 
 def _read_full_into(reader, view: np.ndarray) -> int:

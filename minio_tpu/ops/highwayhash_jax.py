@@ -389,3 +389,126 @@ def hh256_batch(key: bytes, data) -> jax.Array:
     if data.ndim != 2:
         raise ValueError("data must be (N, L)")
     return _hh256_impl(data, data.shape[1], bytes(key))
+
+
+# -- ragged rows: the row lengths are an operand ------------------------------
+# A program of its own beside the static one, which stays as it is: the
+# launches of whole blocks run exactly what they ran.
+
+def _remainder_sources() -> np.ndarray:
+    """(32, 32) int32, row n = where each byte of the remainder packet
+    of an n-byte remainder comes from: an index into those n bytes, or
+    -1 for a zero byte. `_update_remainder`'s packet, as a table."""
+    src = np.full((32, 32), -1, np.int32)
+    for n in range(1, 32):
+        mod4, base = n & 3, n & ~3
+        src[n, :base] = np.arange(base)
+        if n & 16:
+            src[n, 28:] = base + mod4 - 4 + np.arange(4)
+        elif mod4:
+            src[n, 16:19] = (base, base + (mod4 >> 1), base + mod4 - 1)
+    return src
+
+
+_REMAINDER_SRC = _remainder_sources()
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _hh256_ragged_impl(data: jnp.ndarray, lengths: jnp.ndarray,
+                       key: bytes) -> jnp.ndarray:
+    n_in, width = data.shape
+    g = _groups()
+    lengths = lengths.astype(jnp.int32)
+    pad_rows = (-n_in) % g
+    if pad_rows:
+        data = jnp.concatenate(
+            [data, jnp.zeros((pad_rows, width), jnp.uint8)])
+        lengths = jnp.concatenate(
+            [lengths, jnp.zeros((pad_rows,), jnp.int32)])
+    n = n_in + pad_rows
+    cols = n // g
+    full = width // 32
+
+    def laid(x):
+        # a value a row -> the state's (2G, N/G) layout: stream s is
+        # column s % (N/G) of group s // (N/G), in both lane blocks
+        x = x.reshape(g, cols)
+        return jnp.concatenate([x, x])
+
+    def keep(live, new, old):
+        return jax.tree.map(lambda a, b: jnp.where(live, a, b), new, old)
+
+    whole = laid(lengths // 32)           # whole packets of each row
+    st = _init_state(key, g, cols)
+
+    if full:
+        words = _words_grouped(
+            data[:, :full * 32].reshape(n, full, 32), g)  # (F, 8G, N/G)
+        u = min(_unroll(), full)
+        main = (full // u) * u
+
+        def packet_round(st, w, i):
+            # every row takes the round in lockstep; a row whose
+            # packets have run out keeps the state it had
+            pe, po = _packet_from_rows(w, g)
+            return keep(i < whole, _update(st, pe, po), st)
+
+        def body(st, xs):
+            w, at = xs
+            for j in range(u):
+                st = packet_round(st, w[j * 8 * g:(j + 1) * 8 * g],
+                                  at * u + j)
+            return st, None
+
+        st, _ = lax.scan(body, st, (
+            words[:main].reshape(full // u, u * 8 * g, cols),
+            jnp.arange(full // u, dtype=jnp.int32)))
+        for j in range(main, full):
+            st = packet_round(st, words[j], j)
+
+    # the remainder step, n = length % 32 a row: its 32-byte window is
+    # cut where the row's whole packets end (clamped into the array,
+    # the table's indices shifted by as much)
+    rem = lengths % 32
+    if width < 32:
+        data = jnp.concatenate(
+            [data, jnp.zeros((n, 32 - width), jnp.uint8)], axis=1)
+    at = lengths - rem
+    lo = jnp.minimum(at, max(width, 32) - 32)
+    window = jax.vmap(
+        lambda row, o: lax.dynamic_slice(row, (o,), (32,)))(data, lo)
+    src = jnp.asarray(_REMAINDER_SRC)[rem]                 # (N, 32)
+    packet = jnp.where(
+        src >= 0,
+        jnp.take_along_axis(
+            window, jnp.clip(src + (at - lo)[:, None], 0, 31), axis=1),
+        jnp.uint8(0))
+    nl = laid(rem.astype(U32))
+    after = dict(st)
+    for tag in ("e", "o"):
+        after["v0" + tag] = _add64(st["v0" + tag], (nl, nl))
+        after["v1" + tag] = tuple((x << nl) | (x >> (U32(32) - nl))
+                                  for x in st["v1" + tag])
+    pe, po = _packet_from_rows(
+        _words_grouped(packet[:, None, :], g)[0], g)
+    st = keep(nl > 0, _update(after, pe, po), st)
+
+    out = _finalize256(st, g)
+    digests = lax.bitcast_convert_type(
+        jnp.transpose(out, (1, 0)), jnp.uint8).reshape(n, 32)
+    return digests[:n_in]
+
+
+def hh256_batch_ragged(key: bytes, data, lengths) -> jax.Array:
+    """HighwayHash-256 of the first lengths[i] bytes of row i of an
+    (N, L) uint8 array -> (N, 32): `hh256_batch` with the row lengths
+    an OPERAND, so one program serves every mix of lengths up to L.
+    All rows run the L // 32 packet rounds in lockstep and a row keeps
+    its state once its own packets are through; the remainder step
+    takes n = length % 32 a row. Byte-identical to the static program
+    a row at a time, and with every length == L to `hh256_batch`."""
+    data = jnp.asarray(data, jnp.uint8)
+    if data.ndim != 2:
+        raise ValueError("data must be (N, L)")
+    return _hh256_ragged_impl(data, jnp.asarray(lengths, jnp.int32),
+                              bytes(key))

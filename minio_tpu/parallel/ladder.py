@@ -93,6 +93,14 @@ def rung(verb: str, blocks: int, cap: int = 0) -> int:
     return next(r for r in ladder if r >= blocks)
 
 
+def launch_size(verb: str, rows: int, blocks=None) -> tuple[int, int]:
+    """(real blocks, the B the step runs at) of a launch over an array
+    of `rows` blocks: the array is padded to its rung already when the
+    caller says how many of its rows are real (`blocks`: the batch
+    former's staging buffer), and is brought up to it otherwise."""
+    return (rows, rung(verb, rows)) if blocks is None else (blocks, rows)
+
+
 def pad_blocks(arr: np.ndarray, to: int) -> np.ndarray:
     """`arr` with zero blocks appended along axis 0 up to `to` rows;
     `arr` itself when it has them."""
@@ -104,7 +112,8 @@ def pad_blocks(arr: np.ndarray, to: int) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# boot: load the encode rungs for the geometry the drive set declares
+# boot: load the encode rungs for the geometry the drive set declares;
+# the first short block: load the rungs of the row that carries them
 # ---------------------------------------------------------------------------
 
 _TLS = threading.local()
@@ -129,10 +138,12 @@ def _listen() -> None:
     mon.register_event_duration_secs_listener(on_duration)
 
 
-def _load_one(parent, codec, blocks: int, cuts, algo) -> dict:
+def _load_one(parent, codec, blocks: int, cuts, algo,
+              ragged: bool = False) -> dict:
     """One encode rung's programs, through the codec's own jitted
-    entry points: the step at B = `blocks`, and the cuts that take a
-    padded launch's outputs back to each real count in `cuts`."""
+    entry points: the step at B = `blocks` (`ragged`: the step of a
+    launch that carries short blocks), and the cuts that take a padded
+    launch's outputs back to each real count in `cuts`."""
     verb = "encode"
     with telemetry.span("boot.load_program", parent=parent, verb=verb,
                         B=blocks, S=codec.shard_size,
@@ -140,7 +151,7 @@ def _load_one(parent, codec, blocks: int, cuts, algo) -> dict:
         _TLS.hits = _TLS.built = 0
         t0 = time.perf_counter()
         try:
-            codec.load_encode_program(blocks, cuts, algo)
+            codec.load_encode_program(blocks, cuts, algo, ragged=ragged)
             how = "hit" if _TLS.hits else \
                 "compiled" if _TLS.built else "resident"
         except Exception as e:  # noqa: BLE001 — the node still boots:
@@ -159,25 +170,123 @@ def _load_one(parent, codec, blocks: int, cuts, algo) -> dict:
 
 
 def load_encode(codec, algo, cap: int = 0,
-                workers: int = LOAD_WORKERS) -> list[dict]:
+                workers: int = LOAD_WORKERS, ragged: bool = False,
+                trigger: str = "boot", demand=None) -> list[dict]:
     """Lower and compile — from the persistent compile cache when it
     is warm — every encode rung of `codec`'s geometry at its
     full-block S, `workers` at a time, largest first. -> one record a
     program. On a host without a TPU (or with the mesh route on, whose
-    programs are not these) nothing is loaded."""
+    programs are not these) nothing is loaded. `ragged`: the rungs of
+    the row a launch with a short block runs (`load_encode_ragged`
+    asks; their cuts are the static row's, resident since boot), with
+    a `demand`: the rungs launches are waiting for go first, and each
+    is told as its program is in."""
     from ..object.codec import _device_is_tpu, _mesh_active
     if not _device_is_tpu() or _mesh_active() is not None \
             or codec.m == 0 or codec._device_hash_kernel(algo) is None:
         return []
     _listen()
     ladder = rungs_of("encode", cap)
-    with telemetry.span("boot.load_programs", verb="encode",
-                        k=codec.k, m=codec.m, S=codec.shard_size,
-                        programs=len(ladder), workers=workers) as sp:
-        jobs = [(b, tuple(range(below + 1, b)))
-                for below, b in zip((0,) + ladder, ladder)]
-        with ThreadPoolExecutor(max_workers=max(1, workers),
+    row = "encode_and_hash_batch" + (".ragged" if ragged else "")
+    with telemetry.span("boot.load_programs", verb="encode", row=row,
+                        trigger=trigger, k=codec.k, m=codec.m,
+                        S=codec.shard_size, programs=len(ladder),
+                        workers=workers) as sp:
+        todo = sorted(((b, tuple(range(below + 1, b)))
+                       for below, b in zip((0,) + ladder, ladder)),
+                      reverse=True)
+        mu = threading.Lock()
+
+        def load_next(_worker) -> list[dict]:
+            done = []
+            while True:
+                with mu:
+                    if not todo:
+                        return done
+                    blocks, cuts = todo.pop(
+                        demand.first(todo) if demand else 0)
+                try:
+                    done.append(_load_one(sp, codec, blocks, cuts, algo,
+                                          ragged))
+                finally:
+                    if demand:
+                        demand.met(blocks)
+        workers = max(1, min(workers, len(todo)))
+        with ThreadPoolExecutor(max_workers=workers,
                                 thread_name_prefix="boot-load") as pool:
-            return list(pool.map(
-                lambda job: _load_one(sp, codec, job[0], job[1], algo),
-                sorted(jobs, reverse=True)))
+            return [rec for done in pool.map(load_next, range(workers))
+                    for rec in done]
+
+
+class _Demand:
+    """The ragged rungs of one geometry while they load: which of them
+    launches are waiting for, and an event a rung set as it is in."""
+
+    def __init__(self, rungs):
+        self.events = {b: threading.Event() for b in rungs}
+        self.wanted: set[int] = set()
+
+    def first(self, todo) -> int:
+        """Index in `todo` of the job to load next: the largest rung a
+        launch waits for, else the largest there is."""
+        return next((i for i, (b, _cuts) in enumerate(todo)
+                     if b in self.wanted), 0)
+
+    def met(self, blocks: int) -> None:
+        self.events[blocks].set()
+
+    def wait(self, blocks: int) -> None:
+        if blocks in self.events:
+            self.wanted.add(blocks)
+            # check: allow(deadline) a program load; load_encode_ragged sets every event when it ends
+            self.events[blocks].wait()
+
+
+# geometry + algorithm -> its ragged rungs' load: made by the first
+# launch with a short block that routes to the device, never at boot
+_RAGGED: dict[tuple, _Demand] = {}
+_RAGGED_MU = threading.Lock()
+
+
+def load_encode_ragged(codec, algo, cap: int = 0, want: int = 0) -> bool:
+    """Start, ONCE a geometry and a process, the load of the rungs of
+    the encode row that carries short blocks — in the background, on
+    the boot-load pool: the codec calls this when a launch with a
+    short block first routes to the device, so a store whose short
+    blocks never reach it (or that stores none) never pays for them
+    (ROADMAP A6 i: the rule for every program boot does not need).
+    `want`: the rung the launch that asks is about to wait for.
+    -> whether this call started it."""
+    key = (codec.k, codec.m, codec.shard_size, algo.value)
+    with _RAGGED_MU:
+        if key in _RAGGED:
+            return False
+        demand = _RAGGED[key] = _Demand(rungs_of("encode", cap))
+        demand.wanted.add(want)
+
+    def run() -> None:
+        try:
+            # a trace of its own: no request's, and boot's is closed
+            with telemetry.trace("node.load_programs"):
+                load_encode(codec, algo, cap, ragged=True,
+                            trigger="first_short_block", demand=demand)
+        finally:
+            for b in demand.events:
+                demand.met(b)
+    threading.Thread(target=run, name="boot-load-ragged",
+                     daemon=True).start()
+    return True
+
+
+def await_ragged(codec, algo, blocks: int) -> None:
+    """A ragged launch at rung `blocks` whose program is still loading
+    waits for that load — which it moves to the head of the loader's
+    queue — as any jit call waits for its compile: a second compile of
+    the same program beside the loader's would take as long and twice
+    the cores. Nothing to wait for when no load was started (an engine
+    without a former) or the rung is off the ladder (it compiles in
+    the call)."""
+    demand = _RAGGED.get(
+        (codec.k, codec.m, codec.shard_size, algo.value))
+    if demand is not None:
+        demand.wait(blocks)

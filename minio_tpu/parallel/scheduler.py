@@ -100,6 +100,14 @@ _COALESCED_TOTAL = telemetry.REGISTRY.counter(
 # one-group launch copies nothing), "h2d" (upload of the fused input),
 # "compute" (launch + device program + sync), "fetch" (device->host
 # readback of what the device made).
+_SHORT_BLOCKS_TOTAL = telemetry.REGISTRY.counter(
+    "minio_tpu_encode_short_blocks_total",
+    "Short last blocks of objects that rode an encode launch on the "
+    "device, in the group of their object's whole blocks")
+_RAGGED_LAUNCHES_TOTAL = telemetry.REGISTRY.counter(
+    "minio_tpu_encode_ragged_launches_total",
+    "Encode launches on the device that carried a short block (the "
+    "ragged program; every other launch runs the static one)")
 # Sub-ms buckets: a dispatch stage on a warm path is 10µs-100ms.
 _STAGE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
                   0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 10.0)
@@ -145,11 +153,12 @@ telemetry.REGISTRY.register_collector(_collect_scheduler_metrics)
 
 
 class _Pending:
-    __slots__ = ("data", "payload", "blocks", "event", "out", "error",
-                 "span", "t_submit_ns", "t_taken_ns")
+    __slots__ = ("data", "payload", "blocks", "lengths", "event", "out",
+                 "error", "span", "t_submit_ns", "t_taken_ns")
 
     def __init__(self, data: Optional[np.ndarray] = None,
-                 payload=None, blocks: Optional[int] = None):
+                 payload=None, blocks: Optional[int] = None,
+                 lengths: Optional[np.ndarray] = None):
         # erasure verbs carry one (B, k, S) array and, as payload, the
         # per-row arrays that ride beside it (none, or cipher words);
         # the scan verb carries its typed page arrays as an opaque
@@ -158,6 +167,9 @@ class _Pending:
         self.data = data
         self.payload = payload
         self.blocks = int(data.shape[0]) if blocks is None else blocks
+        # encode only: each block's own shard length, when one of them
+        # (an object's last) is short; None: every block is whole
+        self.lengths = lengths
         self.event = threading.Event()
         self.out = None
         self.error: Optional[Exception] = None
@@ -228,14 +240,23 @@ class BatchScheduler:
         self.batches = 0              # dispatch counter (tests/metrics)
         self.coalesced = 0            # groups that shared a dispatch
         self.dispatched_blocks = 0    # blocks through the device path
-        # blocks: real ones; pad_blocks: the zero blocks that brought
-        # launches up to their ladder rung; staged_bytes: gathered
-        # into a staging buffer before upload, pad included (0 for a
-        # one-group launch on a rung); fetched_bytes: what crossed back
-        self.verb_stats = {v: {"batches": 0, "coalesced": 0, "blocks": 0,
-                               "pad_blocks": 0,
+        # groups: submissions; blocks: real ones; pad_blocks: the zero
+        # blocks that brought launches up to their ladder rung;
+        # staged_bytes: gathered into a staging buffer before upload,
+        # pad included (0 for a one-group launch on a rung);
+        # fetched_bytes: what crossed back; uploaded_bytes: the data
+        # arrays of the device launches, at their rungs, and of them
+        # pad_bytes: zeros (pad blocks, and a short block's columns
+        # past its own length); ragged_batches: device launches that
+        # carried short_blocks short blocks of short_shard_bytes shard
+        # bytes in all (the encode verb alone has them)
+        self.verb_stats = {v: {"groups": 0, "batches": 0, "coalesced": 0,
+                               "blocks": 0, "pad_blocks": 0,
                                "cpu_routed": 0, "errors": 0,
-                               "staged_bytes": 0, "fetched_bytes": 0}
+                               "staged_bytes": 0, "fetched_bytes": 0,
+                               "uploaded_bytes": 0, "pad_bytes": 0,
+                               "ragged_batches": 0, "short_blocks": 0,
+                               "short_shard_bytes": 0}
                            for v in VERBS}
         # stage attribution (queue/transfer/compute/fetch histograms +
         # per-dispatch child spans); `off` is the overhead-A/B escape
@@ -324,28 +345,36 @@ class BatchScheduler:
         return declined
 
     def _enqueue(self, entry: str, codec, data: np.ndarray, algo,
-                 static: tuple = (), row_arrays: tuple = ()
-                 ) -> DispatchFuture:
+                 static: tuple = (), row_arrays: tuple = (),
+                 lengths=None) -> DispatchFuture:
         """One (B, k, S) group for the fused program the Codec method
         `entry` enters (codec.FUSED), with that method's static
         arguments and the per-row arrays that ride beside the data.
         The arrays ride the batch like the data does; the bucket key
         carries only their GEOMETRY, so groups of different objects,
-        under different keys, coalesce into one launch."""
+        under different keys, coalesce into one launch. `lengths`
+        (encode): each block's own shard length, of a group that ends
+        in a short block — the group arrives at the full S, so the key
+        is that of a whole group and the two fuse."""
         if self._declined(codec, algo):
             return DispatchFuture()
+        if lengths is not None:
+            lengths = np.ascontiguousarray(lengths, np.int32)
+            if not (lengths < data.shape[-1]).any():
+                lengths = None
         key = (FUSED[entry].verb, entry, codec.k, codec.m, data.shape[-1],
                algo.value, static, tuple(a.shape[1:] for a in row_arrays))
         return self._enqueue_pending(key, _Pending(
             np.ascontiguousarray(data, np.uint8),
             payload=tuple(np.ascontiguousarray(a, np.uint32)
-                          for a in row_arrays)))
+                          for a in row_arrays), lengths=lengths))
 
     def _enqueue_pending(self, key: tuple, p: _Pending) -> DispatchFuture:
         p.span = telemetry.current_span()
         with self._mu:
             if self._stop:
                 return DispatchFuture()
+            self.verb_stats[key[0]]["groups"] += 1
             self._buckets.setdefault(key, []).append(p)
             self._bucket_blocks[key] = \
                 self._bucket_blocks.get(key, 0) + p.blocks
@@ -353,7 +382,7 @@ class BatchScheduler:
         return DispatchFuture(p)
 
     def submit(self, codec, data: np.ndarray, algo,
-               sse=None) -> DispatchFuture:
+               sse=None, lengths=None) -> DispatchFuture:
         """Non-blocking fused encode+digest dispatch: enqueue the
         (B, k, S) group on the batch former and return immediately. The
         future resolves to (parity (B, m, S), digests (B, k+m, 32)) —
@@ -366,9 +395,17 @@ class BatchScheduler:
         dispatch into the fused cipher+RS+digest program (codec.
         encrypt_encode_and_hash_batch). The device changed the data
         rows, so the future then resolves to (full (B, k+m, S),
-        digests): CIPHERTEXT data rows with parity appended."""
+        digests): CIPHERTEXT data rows with parity appended.
+
+        lengths = (B,) each block's own shard length, for a group whose
+        last block is SHORT (laid out as codec.split lays it in the
+        first lengths[b] columns of its rows, zero beyond): the group
+        still arrives at the full S and fuses with whole groups; the
+        digests cover lengths[b] bytes a row and the caller keeps
+        parity[b, :, :lengths[b]] (codec.encode_and_hash_batch)."""
         if sse is None:
-            return self._enqueue("encode_and_hash_batch", codec, data, algo)
+            return self._enqueue("encode_and_hash_batch", codec, data, algo,
+                                 lengths=lengths)
         keys, nonces, pkg_bytes = sse
         return self._enqueue("encrypt_encode_and_hash_batch", codec, data,
                              algo, (pkg_bytes,), (keys, nonces))
@@ -421,11 +458,13 @@ class BatchScheduler:
                      blocks=pages.n_pages)
         return self._enqueue_pending(key, p)
 
-    def encode_and_hash(self, codec, data: np.ndarray, algo, sse=None
+    def encode_and_hash(self, codec, data: np.ndarray, algo, sse=None,
+                        lengths=None
                         ) -> Optional[tuple[np.ndarray, np.ndarray]]:
         """Blocking fused encode+digest via the shared batch former
-        (submit + wait); `sse` as in submit()."""
-        return self.submit(codec, data, algo, sse=sse).result()
+        (submit + wait); `sse` and `lengths` as in submit()."""
+        return self.submit(codec, data, algo, sse=sse,
+                           lengths=lengths).result()
 
     # -- collector ---------------------------------------------------------
 
@@ -547,7 +586,8 @@ class BatchScheduler:
             stages[stage] = (
                 time.perf_counter_ns() - int(seconds * 1e9), seconds)
         t0_ns = time.perf_counter_ns()
-        staged = fetched = pad = 0
+        staged = fetched = pad = uploaded = pad_bytes = 0
+        short: list[int] = []       # shard lengths of the short blocks
         nb = sum(p.blocks for p in group)
         # what moved, on the erasure stages' spans
         stage_attrs: dict[str, dict] = {}
@@ -569,9 +609,17 @@ class BatchScheduler:
             if out is not None:
                 fetched = sum(a.nbytes for a in out
                               if isinstance(a, np.ndarray))
+            k, s = key[2], key[4]
+            short = [int(n) for p in group if p.lengths is not None
+                     for n in p.lengths[p.lengths < s]]
+            uploaded = (nb + pad) * k * s
+            pad_bytes = k * (pad * s + len(short) * s - sum(short))
             stage_attrs = {
                 "transfer": {"groups": len(group), "bytes": staged,
-                             "rung": nb + pad, "pad_blocks": pad},
+                             "rung": nb + pad, "pad_blocks": pad,
+                             "short_blocks": len(short),
+                             "pad_bytes": pad_bytes},
+                "compute": {"ragged": int(bool(short))},
                 "fetch": {"bytes": fetched}}
         t1_ns = time.perf_counter_ns()
         # a dispatch that DECLINED to the device (out is None: CPU
@@ -592,12 +640,20 @@ class BatchScheduler:
                 vs["pad_blocks"] += pad
                 vs["staged_bytes"] += staged
                 vs["fetched_bytes"] += fetched
+                vs["uploaded_bytes"] += uploaded
+                vs["pad_bytes"] += pad_bytes
+                vs["ragged_batches"] += bool(short)
+                vs["short_blocks"] += len(short)
+                vs["short_shard_bytes"] += sum(short)
             else:
                 vs["cpu_routed"] += 1
         if ran:
             _BATCHES_TOTAL.inc(verb=verb)
             if len(group) > 1:
                 _COALESCED_TOTAL.inc(len(group) - 1, verb=verb)
+            if short:
+                _RAGGED_LAUNCHES_TOTAL.inc()
+                _SHORT_BLOCKS_TOTAL.inc(len(short))
         if attrib and ran:
             for p in group:
                 taken = p.t_taken_ns or t0_ns
@@ -700,12 +756,20 @@ class BatchScheduler:
         arrays = tuple(cols[0] if len(cols) == 1 else np.concatenate(cols)
                        for cols in zip(*(p.payload for p in group)))
         at = FUSED[entry].rows_at
+        # a launch any of whose blocks is short hands the method every
+        # block's own length (a whole group's: the full S); a launch
+        # with none is called exactly as before
+        ragged = {}
+        if any(p.lengths is not None for p in group):
+            ragged["lengths"] = np.concatenate(
+                [np.full(p.blocks, s, np.int32) if p.lengths is None
+                 else p.lengths for p in group])
         # the method is looked up on the codec NOW: a wrapper planted
         # on the class (a fault, a test) is the one that runs
         return getattr(Codec(k, m, s * k), entry)(
             data, *static[:at], *arrays, *static[at:],
             bitrot_mod.BitrotAlgorithm.from_string(algo_value),
-            stage_cb=stage_cb, blocks=nb)
+            stage_cb=stage_cb, blocks=nb, **ragged)
 
     @staticmethod
     def _run_scan(group: list, stage_cb=None):

@@ -103,7 +103,28 @@ def _thread_leak_sentinel():
         + " — the owning fixture must close() its workers")
 
 
+@pytest.fixture(autouse=True)
+def _no_background_program_loads(request, monkeypatch):
+    """On a TPU the codec starts, once a geometry, a background load of
+    the ten ragged encode rungs when a launch with a short block first
+    routes to the device (parallel/ladder.load_encode_ragged). A test
+    that fakes the TPU on XLA-CPU would compile those programs behind
+    its back, on threads that outlive it; it launches its ragged
+    programs through the jit call instead. The tests of the load
+    itself carry the marker `ragged_loads` and get the real one."""
+    if request.node.get_closest_marker("ragged_loads") is None:
+        from minio_tpu.parallel import ladder
+        monkeypatch.setattr(ladder, "load_encode_ragged",
+                            lambda *_a, **_kw: False)
+    yield
+
+
 def pytest_configure(config):
+    config.addinivalue_line(
+        "markers",
+        "ragged_loads: the test wants the real background load of the "
+        "ragged encode rungs (parallel/ladder.load_encode_ragged), "
+        "which every other test gets as a stub")
     config.addinivalue_line(
         "markers",
         "native: exercises the C++ library under ASan/UBSan "

@@ -490,3 +490,79 @@ def test_list_buckets_quorum_merge(neng):
     neng.disks[1].offline = False
     names = [v.name for v in neng.list_buckets()]
     assert "b-new" not in names
+
+
+# ---------------------------------------------------------------------------
+# a group may end in a short block: both PUT loops, both routes, against
+# the plain reference
+# ---------------------------------------------------------------------------
+
+_SIZES = {"1B": 1, "bs-1": BLOCK - 1, "bs+1": BLOCK + 1,
+          "2.5bs": 5 * BLOCK // 2, "8bs+1": 8 * BLOCK + 1,
+          "9.5bs": 9 * BLOCK + BLOCK // 2}
+
+
+def _reference():
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from benchlib import reference
+    return reference
+
+
+@pytest.mark.parametrize("size", sorted(_SIZES), ids=str)
+@pytest.mark.parametrize("route", ["host", "device"])
+@pytest.mark.parametrize("loop", ["serial", "pipelined"])
+def test_object_ending_in_a_short_block_matches_the_reference(
+        tmp_path, monkeypatch, loop, route, size):
+    """Objects that are not a whole number of blocks, through each PUT
+    loop, on the host route (an engine without a former) and on the
+    device route (a former, XLA-CPU standing in for the chip): every
+    drive's part file is the plain reference's byte for byte, a GET
+    returns the body, and the short block rode the group of the whole
+    blocks before it - one submission a group of at most 8 blocks."""
+    import glob
+
+    from minio_tpu.object import codec as codec_mod
+    from minio_tpu.parallel import pipeline as pl
+    from minio_tpu.parallel.scheduler import BatchScheduler
+    reference = _reference()
+    monkeypatch.setattr(pl, "ENABLED", loop == "pipelined")
+    sched = None
+    if route == "device":
+        monkeypatch.setattr(codec_mod, "_device_is_tpu", lambda: True)
+        monkeypatch.setattr(codec_mod, "DEVICE_MIN_BYTES", 0)
+        sched = BatchScheduler(max_wait=0.001)
+    nbytes = _SIZES[size]
+    body = payload(nbytes, seed=nbytes)
+    try:
+        e = make_engine(tmp_path)
+        e.scheduler = sched
+        e.make_bucket("b")
+        # an unknown length always takes the pipelined loop
+        e.put_object("b", "obj", io.BytesIO(body),
+                     size=-1 if loop == "pipelined" else nbytes)
+        st = sched.stats()["verbs"]["encode"] if sched else None
+        _oi, it = e.get_object("b", "obj")
+        assert b"".join(it) == body
+    finally:
+        if sched is not None:
+            sched.close()
+    want = reference.part_files(body, K, M, BLOCK)
+    for j, shard in enumerate(reference.shard_of_drive("b", "obj", NDISKS)):
+        (path,) = glob.glob(str(tmp_path / f"d{j}" / "b" / "obj" / "*"
+                                / "part.1"))
+        with open(path, "rb") as f:
+            assert f.read() == want[shard], (j, shard)
+    if st is not None:
+        blocks = -(-nbytes // BLOCK)
+        s_t = -(-(nbytes % BLOCK) // K)
+        assert st["groups"] == -(-blocks // 8) and st["errors"] == 0
+        assert st["blocks"] == blocks and st["cpu_routed"] == 0
+        # bs-1 is a short block whose shard length is the full one
+        short = int(0 < s_t < BLOCK // K)
+        assert (st["short_blocks"], st["short_shard_bytes"]) \
+            == (short, short * s_t)
+        assert st["ragged_batches"] == short

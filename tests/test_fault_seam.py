@@ -47,9 +47,10 @@ def plant(monkeypatch):
     return faults.plant
 
 
-def _put(tmp_path, sched, blocks: int):
-    """PUT one object of `blocks` blocks -> (body, part file by shard
-    index as the drives hold it)."""
+def _put(tmp_path, sched, blocks: float):
+    """PUT one object of `blocks` blocks (2.5: two whole blocks and a
+    short one) -> (body, part file by shard index as the drives hold
+    it)."""
     fmts = new_format_erasure_v3(1, N)
     disks = []
     for j in range(N):
@@ -58,8 +59,8 @@ def _put(tmp_path, sched, blocks: int):
         disks.append(d)
     eng = ErasureSetObjects(disks, K, M, block_size=BLOCK, scheduler=sched)
     eng.make_bucket("b")
-    body = np.random.default_rng(blocks).integers(
-        0, 256, blocks * BLOCK, dtype=np.uint8).tobytes()
+    body = np.random.default_rng(int(blocks)).integers(
+        0, 256, int(blocks * BLOCK), dtype=np.uint8).tobytes()
     eng.put_object("b", "obj", io.BytesIO(body), len(body))
     files: list = [None] * N
     for j, shard in enumerate(reference.shard_of_drive("b", "obj", N)):
@@ -134,3 +135,49 @@ def test_device_put_writes_data_rows_out_of_the_streams_buffer(
     for data, fused, out in seen:
         assert fused is not None and fused[0].shape[1:] == (M, SHARD)
         assert out[0] is data and not data.flags.owndata
+
+
+@pytest.mark.parametrize("fault", ["", "encode-parity-altered",
+                                   "encode-digest-skipped"])
+def test_planted_encode_fault_reaches_the_drives_through_a_ragged_launch(
+        tmp_path, plant, fault):
+    """The seam holds for the ragged row: an object of 2.5 blocks is
+    ONE group whose launch enters through `Codec.encode_and_hash_batch`
+    - the method the faults wrap - with `lengths` among its keywords;
+    the clean run equals the reference, a planted fault reaches the
+    drives in the short block's frames as in the whole ones."""
+    if fault:
+        plant(fault)
+    sched = BatchScheduler(max_wait=0.01)
+    try:
+        body, files = _put(tmp_path, sched, 2.5)
+        st = sched.stats()
+    finally:
+        sched.close()
+    enc = st["verbs"]["encode"]
+    assert (enc["groups"], enc["batches"], enc["ragged_batches"],
+            enc["short_blocks"]) == (1, 1, 1, 1)
+    assert st["errors"]["encode"] == 0
+    want = [np.frombuffer(f, dtype=np.uint8)
+            for f in reference.part_files(body, K, M, BLOCK)]
+    assert [len(f) for f in files] == [len(w) for w in want] \
+        == [2 * FRAME + reference.DIGEST_BYTES + SHARD // 2] * N
+    wrong = [np.flatnonzero(f != w) for f, w in zip(files, want)]
+    for i in range(K):
+        assert wrong[i].size == 0           # data rows: never touched
+    if not fault:
+        assert all(w.size == 0 for w in wrong)
+    elif fault == "encode-parity-altered":
+        # one bit, first byte of the launch's first parity row
+        assert all(w.size == 0 for w in wrong[K + 1:])
+        assert wrong[K].tolist() == [reference.DIGEST_BYTES]
+        assert files[K][wrong[K][0]] ^ want[K][wrong[K][0]] == 1
+    else:
+        # every parity frame, the short block's too: digest left zero
+        starts = (0, FRAME, 2 * FRAME)
+        for i in range(K, N):
+            for at in starts:
+                assert not files[i][at:at + reference.DIGEST_BYTES].any()
+            assert all(any(at <= w < at + reference.DIGEST_BYTES
+                           for at in starts) for w in wrong[i])
+            assert wrong[i].size > 2 * reference.DIGEST_BYTES

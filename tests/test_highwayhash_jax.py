@@ -139,3 +139,85 @@ def test_codec_decode_stacked_matches_numpy():
     for force in ("numpy", "device"):
         out = codec.decode_stacked(stacked, mask, force=force)
         assert (out == data).all(), force
+
+
+# ---------------------------------------------------------------------------
+# the row lengths as an OPERAND (hh256_batch_ragged): one program a
+# padded width, rows hashed over their own first `length` bytes
+# ---------------------------------------------------------------------------
+
+_RAGGED_WIDTH = 203      # 6 whole packets + 11: scan, leftover, remainder
+
+
+def _ragged(data: np.ndarray, lengths) -> np.ndarray:
+    from minio_tpu.ops.highwayhash_jax import hh256_batch_ragged
+    got = np.asarray(hh256_batch_ragged(KEY, data, np.asarray(lengths)))
+    assert got.shape == (data.shape[0], 32)
+    return got
+
+
+@pytest.mark.parametrize("rem", range(32))
+def test_hh256_ragged_every_remainder_equals_the_host_hash(rem):
+    """Rows of ONE padded array whose lengths leave remainder `rem`
+    after 0, 1, 3 and 5 whole packets: each digest is the host hash of
+    the row's own bytes, whatever lies beyond them in the array."""
+    rng = np.random.default_rng(rem)
+    lengths = [rem, 32 + rem, 96 + rem, 160 + rem]
+    data = rng.integers(0, 256, (len(lengths), _RAGGED_WIDTH),
+                        dtype=np.uint8)
+    got = _ragged(data, lengths)
+    for i, n in enumerate(lengths):
+        assert got[i].tobytes() == _want(data[i, :n].tobytes()), (i, n)
+
+
+@pytest.mark.parametrize("width", [1, 7, 31, 32, 33, 64, 100, 129, 1000])
+def test_hh256_ragged_at_full_length_is_the_static_program(width):
+    """lengths == L on every row: the static program's digests, bit for
+    bit — arrays narrower than a packet included."""
+    rng = np.random.default_rng(width)
+    data = rng.integers(0, 256, (5, width), dtype=np.uint8)
+    got = _ragged(data, [width] * 5)
+    assert np.array_equal(got, np.asarray(hh256_batch(KEY, data)))
+
+
+@pytest.mark.parametrize("rows", [1, 3, 4, 16, 33])
+def test_hh256_ragged_mixed_batch(rows):
+    """A batch that mixes empty rows, rows under a packet, rows that
+    end on a packet, whole rows and everything between, at row counts
+    on and off the sublane groups."""
+    rng = np.random.default_rng(rows)
+    width = 349
+    pool = [0, 1, 5, 31, 32, 33, 64, 174, 175, 320, 348, 349]
+    lengths = [pool[int(i)] for i in rng.integers(0, len(pool), rows)]
+    lengths[-1] = width
+    data = rng.integers(0, 256, (rows, width), dtype=np.uint8)
+    got = _ragged(data, lengths)
+    for i, n in enumerate(lengths):
+        assert got[i].tobytes() == _want(data[i, :n].tobytes()), (i, n)
+
+
+def test_hh256_ragged_is_one_program_a_width():
+    """The lengths are an operand: new lengths at the same (N, L) build
+    nothing."""
+    from minio_tpu.ops.highwayhash_jax import _hh256_ragged_impl
+    rng = np.random.default_rng(3)
+    data = rng.integers(0, 256, (4, 77), dtype=np.uint8)
+    _ragged(data, [77, 1, 40, 0])
+    before = _hh256_ragged_impl._cache_size()
+    _ragged(data, [3, 76, 32, 64])
+    assert _hh256_ragged_impl._cache_size() == before
+
+
+def test_remainder_table_is_the_scalar_algorithms_packet(monkeypatch):
+    """The 32 x 32 source-index table against the remainder packet the
+    scalar implementation (pinned to the published vectors) builds."""
+    from minio_tpu.ops.highwayhash_jax import _REMAINDER_SRC
+    packets = []
+    monkeypatch.setattr(HighwayHash, "_update_packet",
+                        lambda self, p: packets.append(bytes(p)))
+    assert (_REMAINDER_SRC[0] == -1).all()
+    for n in range(1, 32):
+        tail = bytes(range(1, n + 1))           # byte i holds i + 1
+        HighwayHash(KEY)._update_remainder(tail)
+        assert bytes(tail[at] if at >= 0 else 0
+                     for at in _REMAINDER_SRC[n]) == packets[-1], n

@@ -9,6 +9,7 @@ from __future__ import annotations
 import hashlib
 import os
 import sys
+import time
 
 import jax.monitoring
 import numpy as np
@@ -387,9 +388,9 @@ def asked(monkeypatch):
     """What boot asks the codec to load, without loading it."""
     calls: list = []
 
-    def load_encode_program(self, blocks, cuts, algo):
+    def load_encode_program(self, blocks, cuts, algo, ragged=False):
         calls.append(("encode", blocks, self.k, self.m, self.shard_size,
-                      tuple(cuts), algo))
+                      tuple(cuts), algo, ragged))
     monkeypatch.setattr(Codec, "load_encode_program", load_encode_program)
     return calls
 
@@ -428,6 +429,8 @@ def test_boot_on_a_tpu_asks_for_the_encode_rungs_once_each(
     assert {c[0] for c in asked} == {"encode"}
     assert {c[2:5] for c in asked} == {(4, 2, (1 << 16) // 4)}
     assert {c[6] for c in asked} == {bitrot_mod.DEFAULT_BITROT_ALGORITHM}
+    # the static row only: the ragged rungs wait for a short block
+    assert {c[7] for c in asked} == {False}
     # the cuts of a rung: every block count that pads up to it
     assert sorted(n for c in asked for n in c[5]) \
         == [b for b in range(1, CAP + 1) if b not in rungs]
@@ -437,6 +440,8 @@ def test_boot_on_a_tpu_asks_for_the_encode_rungs_once_each(
     (boot,) = [sp for sp in spans if sp["name"] == "node.boot"]
     (load,) = [sp for sp in spans if sp["name"] == "boot.load_programs"]
     assert load["parent_id"] == boot["span_id"]
+    assert (load["attrs"]["row"], load["attrs"]["trigger"]) \
+        == ("encode_and_hash_batch", "boot")
     kids = [sp for sp in spans if sp["name"] == "boot.load_program"]
     assert all(sp["parent_id"] == load["span_id"] for sp in kids)
     assert sorted(sp["attrs"]["B"] for sp in kids) == list(rungs)
@@ -453,7 +458,7 @@ def test_boot_with_the_mesh_route_on_loads_nothing(asked, monkeypatch):
 
 def test_a_program_that_does_not_load_does_not_stop_boot(device_codec,
                                                          monkeypatch):
-    def load_encode_program(self, blocks, cuts, algo):
+    def load_encode_program(self, blocks, cuts, algo, ragged=False):
         if blocks == 6:
             raise RuntimeError("compiler on fire")
     monkeypatch.setattr(Codec, "load_encode_program", load_encode_program)
@@ -493,3 +498,195 @@ def test_a_loaded_program_is_the_one_a_launch_hits(device_codec, built):
         sched.close()
     assert (built("put_step"), built("head_blocks")) \
         == (len(rungs), CAP - len(rungs))
+
+
+# ---------------------------------------------------------------------------
+# the rungs of the row that carries short blocks: loaded once, by the
+# first short block a former sees, never at boot
+# ---------------------------------------------------------------------------
+
+@pytest.fixture()
+def no_ragged_loads(monkeypatch):
+    """Each test starts as a fresh process does: no geometry's ragged
+    rungs asked for yet."""
+    monkeypatch.setattr(ladder, "_RAGGED", {})
+
+
+def _loader_done() -> None:
+    """The background load has run to its end, its span closed."""
+    import threading
+    for t in threading.enumerate():
+        if t.name == "boot-load-ragged":
+            t.join(120)
+            assert not t.is_alive()
+
+
+def _short_group(seed: int, b: int, k: int, s: int, s_t: int):
+    data = _blocks(seed, b, k, s)
+    data[-1, :, s_t:] = 0
+    lengths = np.full(b, s, np.int32)
+    lengths[-1] = s_t
+    return data, lengths
+
+
+@pytest.mark.ragged_loads
+def test_the_first_short_block_loads_the_ragged_rungs_once(
+        device_codec, asked, no_ragged_loads):
+    k, m, s = 4, 2, 160
+    codec = Codec(k, m, k * s)
+    rungs = ladder.rungs_of("encode")
+    sched = BatchScheduler(max_wait=0.001)
+    telemetry.SPANS.record_begin()
+    try:
+        # whole groups, with lengths or without: nothing is asked for
+        sched.submit(codec, _blocks(1, 2, k, s), HH).result(60)
+        sched.submit(codec, _blocks(2, 2, k, s), HH,
+                     lengths=np.full(2, s, np.int32)).result(60)
+        assert asked == [] and ladder._RAGGED == {}
+        # the first launch with a short block asks for every ragged
+        # rung; later ones for nothing
+        for seed in (3, 4, 5):
+            data, lengths = _short_group(seed, 3, k, s, 77)
+            parity, digests = sched.submit(codec, data, HH,
+                                           lengths=lengths).result(60)
+            assert parity.shape == (3, m, s)
+        _loader_done()
+    finally:
+        sched.close()
+        win = telemetry.SPANS.record_end()
+    assert sorted(c[1] for c in asked) == list(rungs)
+    assert {c[7] for c in asked} == {True}
+    assert {c[2:5] for c in asked} == {(k, m, s)}
+    (load,) = [sp for sp in win["spans"]
+               if sp["name"] == "boot.load_programs"]
+    assert (load["attrs"]["row"], load["attrs"]["trigger"]) \
+        == ("encode_and_hash_batch.ragged", "first_short_block")
+    kids = [sp for sp in win["spans"] if sp["name"] == "boot.load_program"]
+    assert sorted(sp["attrs"]["B"] for sp in kids) == list(rungs)
+    assert all(sp["parent_id"] == load["span_id"] for sp in kids)
+
+
+@pytest.mark.ragged_loads
+def test_a_ragged_launch_waits_for_its_rungs_load(device_codec,
+                                                  no_ragged_loads,
+                                                  monkeypatch):
+    """A launch at a rung whose ragged program is still loading waits
+    for that load and for no other rung's."""
+    import threading
+    k, m, s = 4, 2, 96
+    codec = Codec(k, m, k * s)
+    gate = threading.Event()
+    loading = threading.Event()
+    real = Codec.load_encode_program
+
+    def load_encode_program(self, blocks, cuts, algo, ragged=False):
+        if ragged and blocks == 4:
+            loading.set()
+            assert gate.wait(60)
+        return real(self, blocks, cuts, algo, ragged=ragged)
+    monkeypatch.setattr(Codec, "load_encode_program", load_encode_program)
+    assert ladder.load_encode_ragged(codec, HH)
+    assert not ladder.load_encode_ragged(codec, HH)      # once
+    assert loading.wait(60)
+    data, lengths = _short_group(9, 3, k, s, 50)         # rung 4
+    out: list = []
+    launch = threading.Thread(target=lambda: out.append(
+        codec.encode_and_hash_batch(data, HH, lengths=lengths)))
+    launch.start()
+    # a launch at another rung is not held up by rung 4's load
+    one, one_len = _short_group(10, 1, k, s, 50)
+    ladder.await_ragged(codec, HH, 1)
+    assert codec.encode_and_hash_batch(one, HH, lengths=one_len) is not None
+    launch.join(0.3)
+    assert launch.is_alive() and out == []
+    gate.set()
+    launch.join(60)
+    assert not launch.is_alive() and out[0][0].shape == (3, m, s)
+    _loader_done()
+
+
+@pytest.mark.ragged_loads
+def test_the_rung_a_launch_waits_for_loads_next(device_codec,
+                                                no_ragged_loads,
+                                                monkeypatch):
+    """The loader takes its rungs largest first, but one a launch is
+    waiting for before the rest: the rung of the launch that started
+    the load, and any asked for while it runs."""
+    import threading
+    codec = Codec(4, 2, 4 * 64)
+    gate = threading.Event()
+    order: list = []
+
+    def load_encode_program(self, blocks, cuts, algo, ragged=False):
+        order.append(blocks)
+        assert gate.wait(60)
+    monkeypatch.setattr(Codec, "load_encode_program", load_encode_program)
+    assert ladder.load_encode_ragged(codec, HH, want=4)
+    deadline = time.monotonic() + 60
+    while len(order) < ladder.LOAD_WORKERS and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert order[0] == 4 and sorted(order[1:]) == [20, 24, 32]
+    waiter = threading.Thread(target=ladder.await_ragged,
+                              args=(codec, HH, 2))
+    waiter.start()
+    while 2 not in ladder._RAGGED[4, 2, 64, HH.value].wanted \
+            and time.monotonic() < deadline:
+        time.sleep(0.01)
+    gate.set()
+    waiter.join(60)
+    _loader_done()
+    assert not waiter.is_alive()
+    assert order[4] == 2 and sorted(order) == list(ladder.rungs_of("encode"))
+
+
+@pytest.mark.ragged_loads
+def test_a_loaded_ragged_program_is_the_one_a_launch_hits(
+        device_codec, built, no_ragged_loads):
+    """The ragged rungs load through the call form a launch uses: after
+    the load, launches of every B with a short block build nothing, and
+    the cuts are the static row's."""
+    k, m, s = 5, 2, 136
+    codec = Codec(k, m, k * s)
+    rungs = ladder.rungs_of("encode")
+    ladder.load_encode(codec, HH, workers=2)              # boot
+    cuts = built("head_blocks")
+    assert (built("put_step"), built("put_step_ragged")) == (len(rungs), 0)
+    assert ladder.load_encode_ragged(codec, HH)
+    _loader_done()
+    assert built("put_step_ragged") == len(rungs)
+    assert built("head_blocks") == cuts
+    enc = reference.encode_matrix(k, m)
+    for b in range(1, CAP + 1):
+        data, lengths = _short_group(b, b, k, s, 1 + 5 * b % s)
+        parity, digests = codec.encode_and_hash_batch(data, HH,
+                                                      lengths=lengths)
+        n = int(lengths[-1])
+        assert np.array_equal(parity[:-1], reference.rs_rows(
+            enc[k:], data[:-1]))
+        last = np.ascontiguousarray(data[-1:, :, :n])
+        want = np.concatenate(
+            [last, reference.rs_rows(enc[k:], last)], axis=1)
+        assert np.array_equal(parity[-1, :, :n], want[0, k:])
+        assert not parity[-1, :, n:].any()
+        assert np.array_equal(digests[-1],
+                              reference.hh256_many(want[0]))
+    assert (built("put_step"), built("put_step_ragged"),
+            built("head_blocks")) == (len(rungs), len(rungs), cuts)
+
+
+@pytest.mark.ragged_loads
+def test_boot_never_loads_the_ragged_rungs(tmp_path, asked, monkeypatch,
+                                           no_ragged_loads):
+    """A store that never sees a short block never pays for the row
+    that carries them: boot on a TPU asks for the static rungs alone."""
+    monkeypatch.setattr(codec_mod, "_device_is_tpu", lambda: True)
+    telemetry.SPANS.record_begin()
+    try:
+        nd = _boot(tmp_path)
+        nd.shutdown()
+    finally:
+        win = telemetry.SPANS.record_end()
+    assert asked and not any(c[7] for c in asked)
+    assert ladder._RAGGED == {}
+    assert [sp["attrs"]["trigger"] for sp in win["spans"]
+            if sp["name"] == "boot.load_programs"] == ["boot"]

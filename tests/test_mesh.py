@@ -278,12 +278,21 @@ def test_e2e_multidevice_server_roundtrip(mesh_serving, tmp_path):
         [str(tmp_path / f"md{i}") for i in range(6)], 1, 6, 2,
         block_size=1 << 16)
     try:
+        sets.make_bucket("meshbkt")
+        # a group that ends in a short block launches the ragged row,
+        # which has no mesh program (codec.FUSED): it runs on one
+        # device, and must round-trip all the same
         before = pmesh.DISPATCHES.value
         payload = os.urandom((1 << 16) * 3 + 12345)
-        sets.make_bucket("meshbkt")
         sets.put_object("meshbkt", "obj", payload)
         _info, stream = sets.get_object("meshbkt", "obj")
         assert b"".join(stream) == payload
+        assert pmesh.DISPATCHES.value == before
+        # whole blocks shard over the mesh
+        whole = os.urandom((1 << 16) * 3)
+        sets.put_object("meshbkt", "whole", whole)
+        _info, stream = sets.get_object("meshbkt", "whole")
+        assert b"".join(stream) == whole
         assert pmesh.DISPATCHES > before, \
             "PUT did not dispatch through the mesh"
 

@@ -366,3 +366,46 @@ def test_staging_ring_sized_from_admission_budget(monkeypatch):
         assert pl.configure_pool_buffers(100) == 7
     finally:
         pl.POOL_BUFFERS = old
+
+
+# ---------------------------------------------------------------------------
+# put_step_ragged: a launch some of whose blocks are short
+# ---------------------------------------------------------------------------
+
+_RK, _RM, _RS = 4, 2, 203       # S = 203: 6 whole packets + 11 bytes
+
+
+@pytest.mark.parametrize("lengths", [
+    (203,), (1,), (203, 102), (203, 203, 31), (203, 203, 203, 203),
+    (32, 203, 33, 202, 64, 1), (203, 203, 101, 203, 7, 203, 203, 160),
+], ids=lambda t: "-".join(map(str, t)))
+def test_put_step_ragged_is_put_step_a_block_at_a_time(lengths):
+    """Parity of the first lengths[b] columns and all k+m digests of
+    every block equal the static put_step's over that block alone at
+    its own shard length; parity beyond the length is zero."""
+    from minio_tpu.models import pipeline as steps
+    rng = np.random.default_rng(len(lengths) * 1000 + lengths[-1])
+    b = len(lengths)
+    data = np.zeros((b, _RK, _RS), np.uint8)
+    for i, n in enumerate(lengths):
+        data[i, :, :n] = rng.integers(0, 256, (_RK, n), dtype=np.uint8)
+    parity, digests = steps.put_step_ragged(
+        data, np.asarray(lengths, np.int32), _RK, _RM)
+    parity, digests = np.asarray(parity), np.asarray(digests)
+    assert parity.shape == (b, _RM, _RS)
+    assert digests.shape == (b, _RK + _RM, 32)
+    for i, n in enumerate(lengths):
+        want_p, want_d = steps.put_step(
+            np.ascontiguousarray(data[i:i + 1, :, :n]), _RK, _RM)
+        assert np.array_equal(parity[i, :, :n], np.asarray(want_p)[0]), i
+        assert not parity[i, :, n:].any(), i
+        assert np.array_equal(digests[i], np.asarray(want_d)[0]), i
+
+
+def test_put_step_ragged_is_one_program_a_shape():
+    from minio_tpu.models import pipeline as steps
+    data = np.zeros((2, _RK, _RS), np.uint8)
+    steps.put_step_ragged(data, np.array([203, 5], np.int32), _RK, _RM)
+    before = steps.put_step_ragged._cache_size()
+    steps.put_step_ragged(data, np.array([77, 203], np.int32), _RK, _RM)
+    assert steps.put_step_ragged._cache_size() == before

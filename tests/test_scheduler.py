@@ -423,6 +423,18 @@ def _obj(seed: int, b: int) -> SimpleNamespace:
     o.rebuilt = np.stack([gf256.gf_matmul(np.asarray(dm, np.uint8), sv)
                           for sv in o.surv])
     assert (o.rebuilt == o.full[:, list(o.missing)]).all()
+    # the same blocks as an object that ENDS SHORT: its last block's
+    # shards fill their first `lengths[-1]` columns, zero beyond
+    o.lengths = np.full(b, _S, np.int32)
+    o.lengths[-1] = _S // 2 + seed % 29
+    o.short = o.plain.copy()
+    o.short[-1, :, o.lengths[-1]:] = 0
+    o.short_parity = np.zeros((b, _M, _S), np.uint8)
+    o.short_digests = np.empty((b, _K + _M, 32), np.uint8)
+    for i, n in enumerate(o.lengths):
+        full = rs_ref.encode(np.ascontiguousarray(o.short[i, :, :n]), _M)
+        o.short_parity[i, :, :n] = full[_K:]
+        o.short_digests[i] = _host_digests(full)
     return o
 
 
@@ -432,7 +444,8 @@ def _joined(objs) -> SimpleNamespace:
         missing=objs[0].missing,
         kn=tuple(np.concatenate(cols)
                  for cols in zip(*(o.kn for o in objs))))
-    for name in ("plain", "surv", "surv_ct", "full", "full_ct", "rebuilt"):
+    for name in ("plain", "surv", "surv_ct", "full", "full_ct", "rebuilt",
+                 "lengths", "short", "short_parity", "short_digests"):
         setattr(both, name, np.concatenate(
             [getattr(o, name) for o in objs]))
     return both
@@ -470,6 +483,12 @@ def _fused_cases(pkg: int):
                                              _S, HH),
             lambda o: (o.full[:, lost], lost, _host_digests(o.surv),
                        _host_digests(o.full[:, lost]))),
+        # the same entry with a length a block: every object ends short
+        "encode_and_hash_batch.ragged": (
+            lambda c, o, **kw: c.encode_and_hash_batch(
+                o.short, HH, lengths=o.lengths, **kw),
+            lambda s, c, o: s.submit(c, o.short, HH, lengths=o.lengths),
+            lambda o: (o.short_parity, o.short_digests)),
     }
 
 
@@ -640,3 +659,146 @@ def test_sse_encode_still_returns_ciphertext_rows(device_codec, monkeypatch,
                            for buf in sched._staging)
     finally:
         sched.close()
+
+
+# ---------------------------------------------------------------------------
+# a group may end in a short block: it is submitted at the full S, so it
+# shares a bucket - and a launch - with whole groups
+# ---------------------------------------------------------------------------
+
+def _spy_steps(monkeypatch):
+    """-> the (step name, data shape) of every fused encode launch."""
+    from minio_tpu.models import pipeline as steps
+    seen = []
+    for name in ("put_step", "put_step_ragged"):
+        real = getattr(steps, name)
+
+        def step(data, *a, _real=real, _name=name, **kw):
+            seen.append((_name, tuple(data.shape)))
+            return _real(data, *a, **kw)
+        monkeypatch.setattr(steps, name, step)
+    return seen
+
+
+def test_a_ragged_and_a_whole_group_fuse_into_one_launch(device_codec,
+                                                         monkeypatch):
+    """An object of 2.5 blocks and one of 2 whole blocks: ONE launch of
+    5 blocks at rung 6 through the ragged step, each future gets its
+    own blocks, the counters say what moved."""
+    from minio_tpu.utils import telemetry
+    seen = _spy_steps(monkeypatch)
+    codec = Codec(_K, _M, _BLOCK)
+    short, whole = _obj(70, 3), _obj(71, 2)
+    sched = BatchScheduler(max_wait=0.5)
+    try:
+        with telemetry.trace("test.root") as root:
+            f1 = sched.submit(codec, short.short, HH, lengths=short.lengths)
+            f2 = sched.submit(codec, whole.plain, HH)
+            got_short, got_whole = f1.result(120), f2.result(120)
+        st = sched.stats()["verbs"]["encode"]
+    finally:
+        sched.close()
+    _assert_same(got_short, (short.short_parity, short.short_digests))
+    _assert_same(got_whole, (whole.full[:, _K:], _host_digests(whole.full)))
+    assert seen == [("put_step_ragged", (6, _K, _S))]
+    s_t = int(short.lengths[-1])
+    assert (st["groups"], st["batches"], st["coalesced"], st["blocks"],
+            st["pad_blocks"]) == (2, 1, 1, 5, 1)
+    assert (st["ragged_batches"], st["short_blocks"],
+            st["short_shard_bytes"]) == (1, 1, s_t)
+    assert st["uploaded_bytes"] == 6 * _K * _S
+    assert st["pad_bytes"] == _K * (_S + _S - s_t)
+    # the launch's stages hang under each of its two groups' dispatch
+    spans = {name: [sp.attrs for sp in root.walk() if sp.name == name]
+             for name in ("sched.transfer", "sched.compute")}
+    assert [(a["short_blocks"], a["pad_bytes"])
+            for a in spans["sched.transfer"]] == [(1, st["pad_bytes"])] * 2
+    assert [a["ragged"] for a in spans["sched.compute"]] == [1, 1]
+    text = telemetry.REGISTRY.render()
+    assert "# TYPE minio_tpu_encode_short_blocks_total counter" in text
+    assert "# TYPE minio_tpu_encode_ragged_launches_total counter" in text
+
+
+@pytest.mark.parametrize("lengths", ["none", "all-full"])
+def test_a_launch_with_no_short_block_runs_the_static_program(
+        device_codec, monkeypatch, lengths):
+    """Whole groups - submitted without lengths, or with lengths that
+    are all the full S - launch exactly what they launched before: the
+    static put_step at the same rung, `lengths` not passed on."""
+    from minio_tpu.utils import telemetry
+    seen = _spy_steps(monkeypatch)
+    calls = []
+    real = Codec.encode_and_hash_batch
+
+    def entry(self, data, algo, **kw):
+        calls.append(sorted(kw))
+        return real(self, data, algo, **kw)
+    monkeypatch.setattr(Codec, "encode_and_hash_batch", entry)
+    codec = Codec(_K, _M, _BLOCK)
+    objs = [_obj(72, 2), _obj(73, 3)]
+    kw = {} if lengths == "none" else {"lengths": np.full(8, _S, np.int32)}
+    sched = BatchScheduler(max_wait=0.5)
+    try:
+        with telemetry.trace("test.root") as root:
+            futs = [sched.submit(codec, o.plain, HH,
+                                 **({k: v[:o.plain.shape[0]]
+                                     for k, v in kw.items()}))
+                    for o in objs]
+            outs = [f.result(120) for f in futs]
+        st = sched.stats()["verbs"]["encode"]
+    finally:
+        sched.close()
+    for o, out in zip(objs, outs):
+        _assert_same(out, (o.full[:, _K:], _host_digests(o.full)))
+    assert seen == [("put_step", (6, _K, _S))]
+    assert calls == [["blocks", "stage_cb"]]
+    assert (st["ragged_batches"], st["short_blocks"],
+            st["short_shard_bytes"]) == (0, 0, 0)
+    assert st["pad_bytes"] == _K * _S            # the pad block alone
+    assert [sp.attrs["ragged"] for sp in root.walk()
+            if sp.name == "sched.compute"] == [0, 0]
+
+
+def test_a_ragged_launch_is_routed_by_its_real_bytes(monkeypatch):
+    """The route is asked with the sum of the blocks' own lengths x k,
+    not with the padded array: a short block alone stays on the host
+    where its padded array would have crossed the threshold."""
+    from minio_tpu.object import codec as codec_mod
+    monkeypatch.setattr(codec_mod, "_device_is_tpu", lambda: True)
+    one = _obj(74, 1)                           # a lone short block
+    real = int(one.lengths.sum()) * _K
+    assert real < one.short.nbytes
+    codec = Codec(_K, _M, _BLOCK)
+    monkeypatch.setattr(codec_mod, "DEVICE_MIN_BYTES", real + 1)
+    assert codec.encode_and_hash_batch(one.short, HH,
+                                       lengths=one.lengths) is None
+    monkeypatch.setattr(codec_mod, "DEVICE_MIN_BYTES", real)
+    _assert_same(codec.encode_and_hash_batch(one.short, HH,
+                                             lengths=one.lengths),
+                 (one.short_parity, one.short_digests))
+    # through the former: host-routed, counted as such, nothing uploaded
+    monkeypatch.setattr(codec_mod, "DEVICE_MIN_BYTES", real + 1)
+    sched = BatchScheduler(max_wait=0.001)
+    try:
+        assert sched.submit(codec, one.short, HH,
+                            lengths=one.lengths).result(60) is None
+        st = sched.stats()["verbs"]["encode"]
+    finally:
+        sched.close()
+    assert (st["groups"], st["cpu_routed"], st["batches"],
+            st["uploaded_bytes"], st["short_blocks"]) == (1, 1, 0, 0, 0)
+
+
+def test_a_short_block_under_sha256_takes_the_host_route_whole(
+        device_codec):
+    """SHA-256 has no ragged kernel: a group with a short block is
+    declined to the host (`no-ragged-kernel`), a whole group is not."""
+    from minio_tpu.utils import eventlog
+    sha = bitrot_mod.BitrotAlgorithm.SHA256
+    codec = Codec(_K, _M, _BLOCK)
+    o = _obj(75, 2)
+    assert codec.encode_and_hash_batch(o.plain, sha) is not None
+    assert codec.encode_and_hash_batch(o.short, sha,
+                                       lengths=o.lengths) is None
+    assert any(e["attrs"].get("reason") == "no-ragged-kernel"
+               for e in eventlog.JOURNAL.recent(classes={"device.decline"}))

@@ -749,6 +749,28 @@ def test_write_frames_is_byte_identical_to_frame_by_frame(
             assert vectored is False
 
 
+@pytest.mark.parametrize("whole", (0, 2, 7))
+@pytest.mark.parametrize("kind", HANDLE_KINDS)
+def test_write_frames_takes_frames_of_unequal_length_in_one_call(
+        kind, whole, tmp_path, monkeypatch):
+    """A group that ENDS in a short block - `whole` frames at the full
+    shard length, then one at a short one - is one write_frames call:
+    the file is the frames in order, and a local handle makes ONE
+    syscall of it (writes == 1)."""
+    disk, drive, handle = _drive_of_kind(kind, tmp_path, monkeypatch)
+    for size in SHARD_SIZES:
+        blocks, digests, want = _frames(whole, size)
+        tb, td, tail = _frames(1, size // 2, salt=2)
+        w = _writer(disk, f"ragged-{size}", size)
+        writes, vectored = w.write_frames(blocks + tb, digests + td)
+        w.close()
+        assert drive.read_all("v", f"ragged-{size}") == want + tail
+        if handle is not None and handle.vectored:
+            assert (writes, vectored) == (1, True)
+        else:
+            assert vectored is False
+
+
 def _short_writev(monkeypatch, limit_of_call):
     """os.writev that takes at most limit_of_call(n) bytes on its n-th
     call (None = all): what ENOSPC or a signal leaves behind."""
