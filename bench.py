@@ -58,7 +58,8 @@ def _iqr(xs: list) -> float:
 def bench_device() -> tuple[float, dict]:
     import jax
     from minio_tpu import bitrot as bitrot_mod
-    from minio_tpu.models.pipeline import get_step, heal_step, put_step
+    from minio_tpu.models.pipeline import (get_step, heal_step,
+                                           host_rows, put_step)
     from minio_tpu.ops import gf256, rs_matrix, rs_ref, rs_tpu
     from minio_tpu.utils import device
 
@@ -76,7 +77,8 @@ def bench_device() -> tuple[float, dict]:
     # ---- identity gates (shards AND digests vs the host oracle) ------
     hh = bitrot_mod.BitrotAlgorithm.HIGHWAYHASH256
     parity, digests = put_step(dd[:1], K, M)
-    parity, digests = np.asarray(parity)[0], np.asarray(digests)[0]
+    parity = host_rows(np.asarray(parity), S)[0]
+    digests = np.asarray(digests)[0]
     want = rs_ref.encode(data[0], M)
     assert (parity == want[K:]).all(), "device encode diverges from oracle"
     for row in (0, K, N_SHARDS - 1):
@@ -98,7 +100,7 @@ def bench_device() -> tuple[float, dict]:
         step = get_step if mode == "decode" else heal_step
         ops[mode] = (lambda step, m2, r: lambda x: step(x, m2, r, K, S)
                      )(step, m2, r)
-        got = [np.asarray(o) for o in ops[mode](dd[:1])]
+        got = [host_rows(np.asarray(o), S) for o in ops[mode](dd[:1])]
         want_rows = gf256.gf_matmul(mat, data[0])
         assert (got[0][0] == want_rows).all(), f"device {mode} diverges"
         want_dg = bitrot_mod.hash_shard(data[0][0].tobytes(), hh)
@@ -116,7 +118,7 @@ def bench_device() -> tuple[float, dict]:
         0, 256, (BATCH, k5, s5)).astype(np.uint8)
     dd5 = jax.device_put(data5)
     p5, dg5 = put_step(dd5[:1], k5, m5, 0, b"", "sha256")
-    p5, dg5 = np.asarray(p5)[0], np.asarray(dg5)[0]
+    p5, dg5 = host_rows(np.asarray(p5), s5)[0], np.asarray(dg5)[0]
     want5 = rs_ref.encode(data5[0], m5)
     assert (p5 == want5[k5:]).all(), "config5 encode diverges"
     import hashlib

@@ -14,17 +14,19 @@ launch a batch:
   * heal_step     — verify, rebuild exactly the lost shards through the
     recover matrix, and digest them for their new frames
     (cmd/erasure-lowlevel-heal.go:28-48 collapsed to one device op).
-  * head_blocks   — the first n blocks of a step's outputs: where a
-    padded launch's pad rows end.
 
 object/codec.py's table of fused programs (`FUSED`) says how each is
 called; `Codec._launch` is their one caller. All are shape-static per
 (k, m, S, B) and cached. The B ladder lives in parallel/ladder.py: a
 launch of any block count is padded with zero blocks up to its rung (by
 the batch former in its staging buffer, by object/codec.py on the
-direct route) and cut back on the device (`head_blocks`), so a geometry
-launches a closed set of programs at full-block S — which boot loads
-for the encode verb.
+direct route), so a geometry launches a closed set of programs at
+full-block S — which boot loads for the encode verb.
+
+Every S-wide output of a step (parity, rebuilt rows, ciphertext)
+leaves it in the LINK FORM, `link_rows`: the rows' bytes as 32-bit
+words. `host_rows` is the other side: a (B, r, S) uint8 view of what
+crossed, no byte copied. Digests are 32 bytes a row and stay uint8.
 """
 
 from __future__ import annotations
@@ -50,6 +52,50 @@ from ..ops import chacha20_jax, rs_matrix, rs_tpu
 # by XLA's serial numbers (`while.83`), which any edit renumbers.
 
 
+# bytes of a word of the link form
+_WORD = 4
+# a result of fewer blocks AND fewer rows a block than this is brought
+# to words at this many blocks (see `link_rows`)
+_SMALL = 8
+
+
+def link_rows(rows: jax.Array) -> jax.Array:
+    """(B, r, S) uint8 rows -> (B, r, ceil(S / 4)) uint32: the form an
+    S-wide output crosses the host-device link in. The same bytes in
+    the same order — a row's four consecutive bytes are one
+    little-endian word; an S that is no multiple of the word is padded
+    with zero columns (12+4: 349526 -> 349528) — so the host gets its
+    rows back as a view (`host_rows`). Why: a uint8 array lies on the
+    chip with four ROWS' bytes interleaved in each word (tiling
+    (8,128)(4,1)); a result of 32 MiB or more in that form (8+8's
+    parity) reads back at 0.64 GiB/s, a uint32 array of the same
+    bytes at 2.4-3.0 (PERF.md §6, PR 34: tools/readback_probe.py).
+
+    A SMALL result — under 8 blocks of under 8 rows: the low rungs at
+    12+4, a decode of a few blocks — is padded with zero blocks to 8
+    for the conversion and cut back, still on the device: XLA's TPU
+    lowering of this conversion takes 30-55 s to COMPILE at those
+    shapes and under 2 s at 8 blocks (the conversion's run time is
+    flat in B), and boot loads those rungs."""
+    b, r, s = rows.shape
+    with jax.named_scope("pack"):
+        more = _SMALL - b if b < _SMALL and r < _SMALL else 0
+        if more or s % _WORD:
+            rows = jnp.pad(rows, ((0, more), (0, 0), (0, -s % _WORD)))
+        return jax.lax.bitcast_convert_type(
+            rows.reshape(b + more, r, -1, _WORD), jnp.uint32)[:b]
+
+
+def host_rows(crossed: np.ndarray, shard_len: int) -> np.ndarray:
+    """What a step's output crossed back as -> what it IS, as a view:
+    an array in the link form (uint32 words) -> (B, r, shard_len)
+    uint8, every row C-contiguous; any other output (the digests) as
+    it is."""
+    if crossed.dtype != np.uint32:
+        return crossed
+    return crossed.view(np.uint8)[..., :shard_len]
+
+
 def _rs_matmul(matrix_bits, shards, r: int, k: int):
     with jax.named_scope("rs_matmul"):
         return rs_tpu._apply_matrix_impl(
@@ -71,10 +117,10 @@ def put_step(data: jax.Array, k: int, m: int, shard_len: int = 0,
     shard_len (< = S, default S) is the true shard byte-length the bitrot
     digests must cover. algo: "highwayhash" (keyed HH256, the default
     bitrot) or "sha256".
-    Returns (parity (B, m, S) uint8, digests (B, k+m, 32) uint8 in shard
-    order data-then-parity) — byte-identical to the CPU bitrot path
-    (minio_tpu/bitrot.py). The caller already holds the data rows, so
-    only parity + digests cross back to the host.
+    Returns (parity (B, m, S) in the link form, digests (B, k+m, 32)
+    uint8 in shard order data-then-parity) — byte-identical to the CPU
+    bitrot path (minio_tpu/bitrot.py). The caller already holds the
+    data rows, so only parity + digests cross back to the host.
     """
     b, k_, s = data.shape
     assert k_ == k
@@ -91,7 +137,7 @@ def put_step(data: jax.Array, k: int, m: int, shard_len: int = 0,
                                axis=-2).reshape(b * (k + m), s)
     digests = _hash_rows(rows, shard_len, key, algo)
     with jax.named_scope("pack"):
-        return parity, digests.reshape(b, k + m, 32)
+        return link_rows(parity), digests.reshape(b, k + m, 32)
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
@@ -115,10 +161,10 @@ def sse_put_step(data: jax.Array, keys: jax.Array, nonces: jax.Array,
             uint32 per-row per-package nonce words (features/crypto.
             DeviceSSE.batch_params — rows of DIFFERENT objects coalesce
             because the bucket key carries only these arrays' shapes).
-    Returns (full (B, k+m, S) uint8 — ciphertext data shards with
-    parity appended, digests (B, k+m, 32)). Unlike put_step the data
-    rows DO cross back: the caller staged plaintext and must write (and
-    tag) ciphertext.
+    Returns (full (B, k+m, S) in the link form — ciphertext data
+    shards with parity appended, digests (B, k+m, 32)). Unlike put_step
+    the data rows DO cross back: the caller staged plaintext and must
+    write (and tag) ciphertext.
     """
     b, k_, s = data.shape
     assert k_ == k
@@ -140,7 +186,7 @@ def sse_put_step(data: jax.Array, keys: jax.Array, nonces: jax.Array,
         flat = rows.reshape(b * (k + m), s)
     digests = _hash_rows(flat, shard_len or s, key, algo)
     with jax.named_scope("pack"):
-        return rows, digests.reshape(b, k + m, 32)
+        return link_rows(rows), digests.reshape(b, k + m, 32)
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10))
@@ -163,8 +209,9 @@ def sse_get_step(survivors: jax.Array, matrix_bits: jax.Array,
     order), src 1 takes reconstructed[:, idx] (in `missing` order).
     keys (B, 8) / nonces (B, P, 3): word arrays for the block's
     packages, plaintext span = P·pkg_bytes of the flat (B, kd·S) view.
-    Returns (plain (B, kd, S) deciphered data shards, missing (B, r, S)
-    reconstructed CIPHERTEXT shards — what a heal would write back,
+    Returns (plain (B, kd, S) deciphered data shards in the link form,
+    missing (B, r, S) uint8 reconstructed CIPHERTEXT shards — what a
+    heal would write back; they stay on the device, so as they are —
     survivor digests (B, k, 32) for host bitrot comparison).
     """
     b, k_, s = survivors.shape
@@ -184,15 +231,7 @@ def sse_get_step(survivors: jax.Array, matrix_bits: jax.Array,
                 [ks, jnp.zeros((b, kd * s - ct_bytes), jnp.uint8)],
                 axis=-1)
         plain = (stacked.reshape(b, kd * s) ^ ks).reshape(b, kd, s)
-    return plain, out, digests[:, :k]
-
-
-@functools.partial(jax.jit, static_argnums=(1,))
-def head_blocks(outputs: tuple, n: int) -> tuple:
-    """The first n blocks of every output of a step that ran at a
-    ladder rung above its launch's block count: the pad blocks' rows
-    and digests end here, on the device, and never cross to the host."""
-    return tuple(o[:n] for o in outputs)
+    return link_rows(plain), out, digests[:, :k]
 
 
 def _hash_rows(rows: jax.Array, shard_len: int, key: bytes,
@@ -225,14 +264,14 @@ def get_step(survivors: jax.Array, matrix_bits: jax.Array, r: int,
     matrix_bits: (8r, 8k) 0/1 — bit-expanded missing-data matrix (only
                  the rows a GET actually needs, not the full k x k).
     shard_len:   true payload bytes per shard frame (digest coverage).
-    Returns (missing (B, r, S) uint8 — the reconstructed shards in
-    `missing` index order, digests (B, k, 32) uint8 — computed frame
-    digests of the survivors, for the host to compare against the frame
-    digests read from disk).
+    Returns (missing (B, r, S) in the link form — the reconstructed
+    shards in `missing` index order, digests (B, k, 32) uint8 —
+    computed frame digests of the survivors, for the host to compare
+    against the frame digests read from disk).
     """
     missing, digests = _reconstruct_and_hash(
         survivors, matrix_bits, r, k, shard_len, key, algo)
-    return missing, digests[:, :k]
+    return link_rows(missing), digests[:, :k]
 
 
 def _reconstruct_and_hash(survivors, matrix_bits, r, k, shard_len,
@@ -271,14 +310,15 @@ def heal_step(survivors: jax.Array, matrix_bits: jax.Array, r: int,
     survivors:   (B, k, S) uint8 in recover_matrix `used` order.
     matrix_bits: (8r, 8k) bit-expanded recover matrix (r = lost shards,
                  data and parity rows both).
-    Returns (recovered (B, r, S), survivor_digests (B, k, 32),
-    recovered_digests (B, r, 32)) — the last are the digests the healer
-    writes into the rebuilt shards' streaming-bitrot frames.
+    Returns (recovered (B, r, S) in the link form, survivor_digests
+    (B, k, 32), recovered_digests (B, r, 32)) — the last are the
+    digests the healer writes into the rebuilt shards'
+    streaming-bitrot frames.
     """
     b, k_, s = survivors.shape
     recovered, digests = _reconstruct_and_hash(
         survivors, matrix_bits, r, k, shard_len, key, algo)
-    return recovered, digests[:, :k], digests[:, k:]
+    return link_rows(recovered), digests[:, :k], digests[:, k:]
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
@@ -298,8 +338,9 @@ def put_step_ragged(data: jax.Array, lengths: jax.Array, k: int, m: int,
     of each of the block's k+m rows (ops/highwayhash_jax.
     hh256_batch_ragged — the lengths are an operand, so one program a
     (B, k, S) serves every mix of short blocks). Returns (parity
-    (B, m, S), digests (B, k+m, 32)) as `put_step` does; the host keeps
-    parity[b, :, :lengths[b]]. Only HighwayHash has the ragged kernel.
+    (B, m, S) in the link form, digests (B, k+m, 32)) as `put_step`
+    does; the host keeps parity[b, :, :lengths[b]]. Only HighwayHash
+    has the ragged kernel.
     """
     b, k_, s = data.shape
     assert k_ == k and algo == "highwayhash"
@@ -316,4 +357,4 @@ def put_step_ragged(data: jax.Array, lengths: jax.Array, k: int, m: int,
         digests = highwayhash_jax._hh256_ragged_impl(
             rows, row_lengths, bytes(key or MAGIC_HIGHWAYHASH_KEY))
     with jax.named_scope("pack"):
-        return parity, digests.reshape(b, k + m, 32)
+        return link_rows(parity), digests.reshape(b, k + m, 32)

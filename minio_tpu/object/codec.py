@@ -275,26 +275,23 @@ class Codec:
         its `.lower` (boot's load), so both make the same program."""
         return step(arrays[0], *lead, *arrays[1:], *tail, algo=kernel)
 
-    def load_encode_program(self, blocks: int, cuts, algo,
+    def load_encode_program(self, blocks: int, algo,
                             ragged: bool = False) -> None:
-        """Lower and compile, without running them, the programs an
-        encode launch at rung `blocks` can need: the fused step through
+        """Lower and compile, without running it, the program an
+        encode launch at rung `blocks` runs: the fused step through
         the table row and call form `_launch` uses — so the loaded
-        executable is the one a request hits — and the cut of its
-        outputs to each real count in `cuts`. Boot's loader
-        (parallel/ladder.load_encode) asks; `ragged`: for the row of a
-        launch that carries short blocks."""
+        executable is the one a request hits (a padded launch needs
+        no other: its pad rows are cut off the host view). Boot's
+        loader (parallel/ladder.load_encode) asks; `ragged`: for the
+        row of a launch that carries short blocks."""
         row = FUSED["encode_and_hash_batch" + (".ragged" if ragged else "")]
         _shared, lead, tail = row.operands(self)
         arrays = (jax.ShapeDtypeStruct(
             (blocks, self.k, self.shard_size), np.uint8),)
         if ragged:
             arrays += (jax.ShapeDtypeStruct((blocks,), np.int32),)
-        step = self._step_call(getattr(pipeline, row.step).lower, arrays,
-                               lead, tail, self._device_hash_kernel(algo))
-        step.compile()
-        for n in cuts:
-            pipeline.head_blocks.lower(tuple(step.out_info), n).compile()
+        self._step_call(getattr(pipeline, row.step).lower, arrays, lead,
+                        tail, self._device_hash_kernel(algo)).compile()
 
     def _launch(self, row: _Fused, data: np.ndarray, row_arrays, static,
                 algo, *, force: str = "", stage_cb=None,
@@ -308,23 +305,31 @@ class Codec:
 
         force: "" auto-route, "device" (tests).
 
-        stage_cb(stage, seconds), when given, is called as each stage
-        ENDS: "h2d" (the fused input's upload, waited for — one extra
-        host wake-up per launch, so without a callback the arrays go
-        to the step as they are), "compute" (launch + device program +
-        sync) and "fetch" (the device→host readback of what crosses
-        back) — the batch scheduler's dispatch attribution. The mesh
-        route reports a single "compute" stage (its sharded programs
-        return host arrays in one step).
+        stage_cb(stage, seconds, **attrs), when given, is called as
+        each stage ENDS: "h2d" (the fused input's upload, waited for —
+        one extra host wake-up per launch, so without a callback the
+        arrays go to the step as they are), "compute" (launch + device
+        program + sync) and "fetch" (what is left of the device→host
+        readback once the program has ended; `form=`: the array the
+        first output crossed as) — the batch scheduler's dispatch
+        attribution. The readback is asked for AT the launch
+        (`copy_to_host_async`), so it starts when the program ends
+        with no host wake-up in between: the two stages' SUM is the
+        time from launch to host arrays. The mesh route reports a
+        single "compute" stage (its sharded programs return host
+        arrays in one step).
 
         The single-device launch runs at its ladder rung
         (parallel/ladder.py): the arrays are padded here, with zero
         blocks, up to the rung of their block count. `blocks`, when
         given, says that `data` is padded to it already (the batch
         former's staging buffer) and how many of its rows are real; the
-        small per-row arrays are still brought up to it. A padded
-        launch's pad rows are cut off ON THE DEVICE, so no result
-        holds one and none crosses back.
+        small per-row arrays are still brought up to it. The rung's
+        whole result crosses back in the steps' link form
+        (models/pipeline.link_rows) and is handed on as VIEWS of what
+        crossed: each S-wide output (B, r, S) uint8 again, a padded
+        launch's pad rows cut off, no byte copied — so no result holds
+        a pad row.
 
         `nbytes`, when given, is what the launch holds of REAL bytes
         (a ragged launch: its blocks' own lengths, not the zero
@@ -365,15 +370,19 @@ class Codec:
                                tail, kernel)
         if row.keep:
             outs = tuple(outs[i] for i in row.keep)
+        for o in outs:
+            o.copy_to_host_async()
         if stage_cb is not None:
             jax.block_until_ready(outs)
             t1 = time.perf_counter()
             stage_cb("compute", t1 - t0)
-        if to != n:
-            outs = pipeline.head_blocks(outs, n)
-        host = tuple(np.asarray(o) for o in outs)
+        crossed = tuple(np.asarray(o) for o in outs)
+        host = tuple(pipeline.host_rows(a, data.shape[2])[:n]
+                     for a in crossed)
         if stage_cb is not None:
-            stage_cb("fetch", time.perf_counter() - t1)
+            stage_cb("fetch", time.perf_counter() - t1,
+                     form=f"{crossed[0].dtype.name}"
+                          f"{list(crossed[0].shape)}")
         if row.shared_at is None:
             return host
         return (*host[:row.shared_at], shared, *host[row.shared_at:])

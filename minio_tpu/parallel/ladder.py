@@ -6,10 +6,12 @@ without a ladder any B in 1..max_batch may launch — each a compile of
 17-56 s at 12+4 on a v5e, or a 4-5 s load from the compile cache,
 inside a request. Here a launch's block count maps to its RUNG: the
 launch is padded with zero blocks up to the rung, the step runs at the
-rung's B, and the pad blocks' rows and digests are cut off on the
-device before anything is fetched. The set of programs a geometry can
-launch at full-block S is then the rungs — finite, and enumerable by
-the program itself, so boot loads it (`load_encode`).
+rung's B, and the pad blocks' rows and digests are cut off the host's
+view of what the step made (a pad block over the link costs less than
+one more program on the device: PERF.md §6, PR 34). The set of
+programs a geometry can launch at full-block S is then the rungs —
+finite, and enumerable by the program itself, so boot loads it
+(`load_encode`).
 
 The rungs are derived from the constants the program already has —
 the verb's group size (`engine.ENCODE_BATCH_BLOCKS`,
@@ -138,20 +140,18 @@ def _listen() -> None:
     mon.register_event_duration_secs_listener(on_duration)
 
 
-def _load_one(parent, codec, blocks: int, cuts, algo,
+def _load_one(parent, codec, blocks: int, algo,
               ragged: bool = False) -> dict:
-    """One encode rung's programs, through the codec's own jitted
-    entry points: the step at B = `blocks` (`ragged`: the step of a
-    launch that carries short blocks), and the cuts that take a padded
-    launch's outputs back to each real count in `cuts`."""
+    """One encode rung's program, through the codec's own jitted
+    entry point: the step at B = `blocks` (`ragged`: the step of a
+    launch that carries short blocks)."""
     verb = "encode"
     with telemetry.span("boot.load_program", parent=parent, verb=verb,
-                        B=blocks, S=codec.shard_size,
-                        cuts=len(cuts)) as sp:
+                        B=blocks, S=codec.shard_size) as sp:
         _TLS.hits = _TLS.built = 0
         t0 = time.perf_counter()
         try:
-            codec.load_encode_program(blocks, cuts, algo, ragged=ragged)
+            codec.load_encode_program(blocks, algo, ragged=ragged)
             how = "hit" if _TLS.hits else \
                 "compiled" if _TLS.built else "resident"
         except Exception as e:  # noqa: BLE001 — the node still boots:
@@ -178,9 +178,8 @@ def load_encode(codec, algo, cap: int = 0,
     program. On a host without a TPU (or with the mesh route on, whose
     programs are not these) nothing is loaded. `ragged`: the rungs of
     the row a launch with a short block runs (`load_encode_ragged`
-    asks; their cuts are the static row's, resident since boot), with
-    a `demand`: the rungs launches are waiting for go first, and each
-    is told as its program is in."""
+    asks), with a `demand`: the rungs launches are waiting for go
+    first, and each is told as its program is in."""
     from ..object.codec import _device_is_tpu, _mesh_active
     if not _device_is_tpu() or _mesh_active() is not None \
             or codec.m == 0 or codec._device_hash_kernel(algo) is None:
@@ -192,9 +191,7 @@ def load_encode(codec, algo, cap: int = 0,
                         trigger=trigger, k=codec.k, m=codec.m,
                         S=codec.shard_size, programs=len(ladder),
                         workers=workers) as sp:
-        todo = sorted(((b, tuple(range(below + 1, b)))
-                       for below, b in zip((0,) + ladder, ladder)),
-                      reverse=True)
+        todo = sorted(ladder, reverse=True)
         mu = threading.Lock()
 
         def load_next(_worker) -> list[dict]:
@@ -203,11 +200,9 @@ def load_encode(codec, algo, cap: int = 0,
                 with mu:
                     if not todo:
                         return done
-                    blocks, cuts = todo.pop(
-                        demand.first(todo) if demand else 0)
+                    blocks = todo.pop(demand.first(todo) if demand else 0)
                 try:
-                    done.append(_load_one(sp, codec, blocks, cuts, algo,
-                                          ragged))
+                    done.append(_load_one(sp, codec, blocks, algo, ragged))
                 finally:
                     if demand:
                         demand.met(blocks)
@@ -229,7 +224,7 @@ class _Demand:
     def first(self, todo) -> int:
         """Index in `todo` of the job to load next: the largest rung a
         launch waits for, else the largest there is."""
-        return next((i for i, (b, _cuts) in enumerate(todo)
+        return next((i for i, b in enumerate(todo)
                      if b in self.wanted), 0)
 
     def met(self, blocks: int) -> None:

@@ -98,8 +98,9 @@ _COALESCED_TOTAL = telemetry.REGISTRY.counter(
 # acquire — while it lasts EVERY bucket stands still), "transfer" (the
 # gather of a multi-group launch into the slot's staging buffer; a
 # one-group launch copies nothing), "h2d" (upload of the fused input),
-# "compute" (launch + device program + sync), "fetch" (device->host
-# readback of what the device made).
+# "compute" (launch + device program + sync), "fetch" (what is left,
+# once the program has ended, of the device->host readback that was
+# asked for at the launch: compare the two stages' sum).
 _SHORT_BLOCKS_TOTAL = telemetry.REGISTRY.counter(
     "minio_tpu_encode_short_blocks_total",
     "Short last blocks of objects that rode an encode launch on the "
@@ -244,7 +245,9 @@ class BatchScheduler:
         # blocks that brought launches up to their ladder rung;
         # staged_bytes: gathered into a staging buffer before upload,
         # pad included (0 for a one-group launch on a rung);
-        # fetched_bytes: what crossed back; uploaded_bytes: the data
+        # fetched_bytes: what crossed back (the rung's whole result)
+        # in fetch_seconds of the launches' "fetch" stage (counted
+        # under `attrib` alone); uploaded_bytes: the data
         # arrays of the device launches, at their rungs, and of them
         # pad_bytes: zeros (pad blocks, and a short block's columns
         # past its own length); ragged_batches: device launches that
@@ -254,6 +257,7 @@ class BatchScheduler:
                                "blocks": 0, "pad_blocks": 0,
                                "cpu_routed": 0, "errors": 0,
                                "staged_bytes": 0, "fetched_bytes": 0,
+                               "fetch_seconds": 0.0,
                                "uploaded_bytes": 0, "pad_bytes": 0,
                                "ragged_batches": 0, "short_blocks": 0,
                                "short_shard_bytes": 0}
@@ -579,12 +583,15 @@ class BatchScheduler:
         attrib = self.attrib
         # stage -> (start ns, seconds) for this dispatch: the codec /
         # kernel callback reports each stage as it ENDS, so its start
-        # is on the spans' clock without the callee knowing it
+        # is on the spans' clock without the callee knowing it; what
+        # else it says of a stage (`form=`) goes on the stage's span
         stages: dict[str, tuple[int, float]] = {}
+        said: dict[str, dict] = {}
 
-        def stage_cb(stage: str, seconds: float) -> None:
+        def stage_cb(stage: str, seconds: float, **attrs) -> None:
             stages[stage] = (
                 time.perf_counter_ns() - int(seconds * 1e9), seconds)
+            said[stage] = attrs
         t0_ns = time.perf_counter_ns()
         staged = fetched = pad = uploaded = pad_bytes = 0
         short: list[int] = []       # shard lengths of the short blocks
@@ -607,8 +614,11 @@ class BatchScheduler:
                 return tuple(a if i == shared_at else a[lo:hi]
                              for i, a in enumerate(out))
             if out is not None:
+                # the rung's whole result crosses back, pad rows and
+                # all: every per-block array by rung / real blocks
                 fetched = sum(a.nbytes for a in out
-                              if isinstance(a, np.ndarray))
+                              if isinstance(a, np.ndarray)) \
+                    * (nb + pad) // nb
             k, s = key[2], key[4]
             short = [int(n) for p in group if p.lengths is not None
                      for n in p.lengths[p.lengths < s]]
@@ -620,7 +630,7 @@ class BatchScheduler:
                              "short_blocks": len(short),
                              "pad_bytes": pad_bytes},
                 "compute": {"ragged": int(bool(short))},
-                "fetch": {"bytes": fetched}}
+                "fetch": {"bytes": fetched, **said.get("fetch", {})}}
         t1_ns = time.perf_counter_ns()
         # a dispatch that DECLINED to the device (out is None: CPU
         # routing) launched nothing: it must feed neither the dispatch
@@ -640,6 +650,7 @@ class BatchScheduler:
                 vs["pad_blocks"] += pad
                 vs["staged_bytes"] += staged
                 vs["fetched_bytes"] += fetched
+                vs["fetch_seconds"] += stages.get("fetch", (0, 0.0))[1]
                 vs["uploaded_bytes"] += uploaded
                 vs["pad_bytes"] += pad_bytes
                 vs["ragged_batches"] += bool(short)

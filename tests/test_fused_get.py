@@ -61,7 +61,10 @@ def test_get_step_reconstructs_and_digests():
     surv = np.stack([full[:, u] for u in used], axis=1)  # (B, k, S)
     m2 = rs_tpu._bit_expand_cached(dm.tobytes(), dm.shape)
     out, digests = pipeline.get_step(surv, m2, dm.shape[0], k, s)
-    out, digests = np.asarray(out), np.asarray(digests)
+    # the rows cross in the link form; host_rows is the (B, r, S) view
+    out = pipeline.host_rows(np.asarray(out), s)
+    digests = np.asarray(digests)
+    assert out.shape == (b, len(missing), s) and out.dtype == np.uint8
     # reconstructed rows byte-identical
     for r, mi in enumerate(missing):
         assert (out[:, r] == full[:, mi]).all()
@@ -99,7 +102,8 @@ def test_heal_step_recovers_and_digests_outputs():
     surv = np.stack([full[:, u] for u in used], axis=1)
     m2 = rs_tpu._bit_expand_cached(rec.tobytes(), rec.shape)
     out, sdig, odig = pipeline.heal_step(surv, m2, rec.shape[0], k, s)
-    out, sdig, odig = np.asarray(out), np.asarray(sdig), np.asarray(odig)
+    out = pipeline.host_rows(np.asarray(out), s)
+    sdig, odig = np.asarray(sdig), np.asarray(odig)
     for r, mi in enumerate(missing):
         assert (out[:, r] == full[:, mi]).all()
         for bi in range(b):

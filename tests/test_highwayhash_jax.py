@@ -46,14 +46,16 @@ def test_hh256_batch_matches_bitrot_hasher():
 
 
 def test_put_step_fused_oracle():
-    from minio_tpu.models.pipeline import put_step
+    from minio_tpu.models.pipeline import host_rows, put_step
     k, m = 4, 2
     s = 1031  # odd length exercises the remainder path
     rng = np.random.default_rng(5)
     data = rng.integers(0, 256, (2, k, s), dtype=np.uint8)
     parity, digests = put_step(data, k, m)
-    parity, digests = np.asarray(parity), np.asarray(digests)
-    assert parity.shape == (2, m, s)
+    # parity crosses as 32-bit words (1031 -> 258 of them a row)
+    assert parity.dtype == np.uint32 and parity.shape == (2, m, 258)
+    parity, digests = host_rows(np.asarray(parity), s), np.asarray(digests)
+    assert parity.shape == (2, m, s) and parity.dtype == np.uint8
     assert digests.shape == (2, k + m, 32)
     for b in range(2):
         want = rs_ref.encode(data[b], m)
@@ -65,7 +67,7 @@ def test_put_step_fused_oracle():
 def test_put_step_padded_shard_len():
     """Zero-padded columns must not change the digests of the true
     shard_len prefix (the engine pads S up for kernel alignment)."""
-    from minio_tpu.models.pipeline import put_step
+    from minio_tpu.models.pipeline import host_rows, put_step
     k, m = 4, 2
     s, pad = 500, 140
     rng = np.random.default_rng(6)
@@ -73,7 +75,8 @@ def test_put_step_padded_shard_len():
     padded = np.pad(data, ((0, 0), (0, 0), (0, pad)))
     par_p, dg_p = put_step(padded, k, m, s)
     par, dg = put_step(data, k, m)
-    assert (np.asarray(par_p)[..., :s] == np.asarray(par)).all()
+    assert (host_rows(np.asarray(par_p), s)
+            == host_rows(np.asarray(par), s)).all()
     assert (np.asarray(dg_p) == np.asarray(dg)).all()
 
 

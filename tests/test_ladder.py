@@ -55,9 +55,16 @@ jax.monitoring.register_event_duration_secs_listener(_on_compile)
 @pytest.fixture()
 def built():
     """-> how many programs of a jitted function were built (compiled,
-    or loaded from a compile cache) since the test began."""
+    or loaded from a compile cache) since the test began; `.steps()`:
+    the names of those that take or make device arrays of a launch
+    (the fused steps, and anything that would cut their outputs)."""
     start = len(_BUILT)
-    return lambda name: _BUILT[start:].count(f"jit({name})")
+
+    def count(name):
+        return _BUILT[start:].count(f"jit({name})")
+    count.steps = lambda: {n for n in _BUILT[start:]
+                           if "step" in n or "head" in n or "cut" in n}
+    return count
 
 
 @pytest.fixture()
@@ -179,8 +186,9 @@ def test_encode_launch_of_every_b_matches_the_reference(
             rung = ladder.rung("encode", b)
             assert after["blocks"] - before["blocks"] == b
             assert after["pad_blocks"] - before["pad_blocks"] == rung - b
+            # the rung's whole result crosses back, pad rows and all
             assert after["fetched_bytes"] - before["fetched_bytes"] \
-                == got_p.nbytes + got_d.nbytes
+                == (got_p.nbytes + got_d.nbytes) * rung // b
             assert after["staged_bytes"] - before["staged_bytes"] \
                 == (0 if rung == b else rung * k * s)
         assert sched.stats()["dispatched_blocks"] == CAP * (CAP + 1) // 2
@@ -189,8 +197,32 @@ def test_encode_launch_of_every_b_matches_the_reference(
     # every B in 1..cap launched, by the former and by the codec alone:
     # the process holds one program a rung, not one a block count
     assert built("put_step") == len(ladder.rungs_of("encode"))
-    # (a cut depends on the outputs' shapes alone: both hashes share it)
-    assert built("head_blocks") <= CAP - len(ladder.rungs_of("encode"))
+    # and no program beside the step: a padded launch's pad rows are
+    # cut off the host's view
+    assert built.steps() == {"jit(put_step)"}
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_the_cut_of_a_padded_launch_is_a_view_of_the_steps_output(geometry):
+    """What replaced the cut program: the step's `out_info` at a rung
+    says what crosses back — parity as 32-bit words, S rounded up to
+    the word — and `host_rows(...)[:n]` of an array of that form is
+    the launch's (n, m, S) uint8 with no byte copied."""
+    import jax
+    k, m, s = GEOMETRIES[geometry]
+    lowered = pipeline.put_step.lower(
+        jax.ShapeDtypeStruct((6, k, s), np.uint8), k, m, algo="highwayhash")
+    parity, digests = lowered.out_info
+    assert (parity.dtype, parity.shape) == (np.uint32, (6, m, -(-s // 4)))
+    assert (digests.dtype, digests.shape) == (np.uint8, (6, k + m, 32))
+    crossed = np.arange(6 * m * -(-s // 4), dtype=np.uint32
+                        ).reshape(parity.shape)
+    cut = pipeline.host_rows(crossed, s)[:5]
+    assert (cut.dtype, cut.shape) == (np.uint8, (5, m, s))
+    assert np.shares_memory(cut, crossed)
+    assert cut.tobytes() == crossed.view(np.uint8)[:5, :, :s].tobytes()
+    dig = np.zeros(digests.shape, np.uint8)
+    assert pipeline.host_rows(dig, s) is dig
 
 
 def test_program_signatures_are_the_rung_set(device_codec, monkeypatch):
@@ -233,7 +265,7 @@ def test_gathered_groups_resolve_with_their_own_blocks(
         assert (st["batches"], st["coalesced"], st["blocks"],
                 st["pad_blocks"]) == (1, 2, 5, 1)
         assert st["staged_bytes"] == 6 * k * s
-        assert st["fetched_bytes"] == 5 * (m * s + (k + m) * 32)
+        assert st["fetched_bytes"] == 6 * (m * s + (k + m) * 32)
     finally:
         sched.close()
 
@@ -388,9 +420,9 @@ def asked(monkeypatch):
     """What boot asks the codec to load, without loading it."""
     calls: list = []
 
-    def load_encode_program(self, blocks, cuts, algo, ragged=False):
+    def load_encode_program(self, blocks, algo, ragged=False):
         calls.append(("encode", blocks, self.k, self.m, self.shard_size,
-                      tuple(cuts), algo, ragged))
+                      algo, ragged))
     monkeypatch.setattr(Codec, "load_encode_program", load_encode_program)
     return calls
 
@@ -410,7 +442,7 @@ def test_boot_on_a_cpu_host_loads_nothing(tmp_path, asked, built):
     finally:
         win = telemetry.SPANS.record_end()
     assert asked == []
-    assert built("put_step") == built("head_blocks") == 0
+    assert built("put_step") == 0 and built.steps() == set()
     names = {sp["name"] for sp in win["spans"]}
     assert "node.boot" in names and "boot.load_programs" not in names
 
@@ -428,14 +460,12 @@ def test_boot_on_a_tpu_asks_for_the_encode_rungs_once_each(
     assert sorted(c[1] for c in asked) == list(rungs)
     assert {c[0] for c in asked} == {"encode"}
     assert {c[2:5] for c in asked} == {(4, 2, (1 << 16) // 4)}
-    assert {c[6] for c in asked} == {bitrot_mod.DEFAULT_BITROT_ALGORITHM}
+    assert {c[5] for c in asked} == {bitrot_mod.DEFAULT_BITROT_ALGORITHM}
     # the static row only: the ragged rungs wait for a short block
-    assert {c[7] for c in asked} == {False}
-    # the cuts of a rung: every block count that pads up to it
-    assert sorted(n for c in asked for n in c[5]) \
-        == [b for b in range(1, CAP + 1) if b not in rungs]
-    assert all(ladder.rung("encode", n) == c[1]
-               for c in asked for n in c[5])
+    assert {c[6] for c in asked} == {False}
+    # one program a rung and no other: every block count pads up to one
+    assert {ladder.rung("encode", b) for b in range(1, CAP + 1)} \
+        == {c[1] for c in asked}
     spans = win["spans"]
     (boot,) = [sp for sp in spans if sp["name"] == "node.boot"]
     (load,) = [sp for sp in spans if sp["name"] == "boot.load_programs"]
@@ -458,7 +488,7 @@ def test_boot_with_the_mesh_route_on_loads_nothing(asked, monkeypatch):
 
 def test_a_program_that_does_not_load_does_not_stop_boot(device_codec,
                                                          monkeypatch):
-    def load_encode_program(self, blocks, cuts, algo, ragged=False):
+    def load_encode_program(self, blocks, algo, ragged=False):
         if blocks == 6:
             raise RuntimeError("compiler on fire")
     monkeypatch.setattr(Codec, "load_encode_program", load_encode_program)
@@ -473,16 +503,16 @@ def test_a_program_that_does_not_load_does_not_stop_boot(device_codec,
 
 def test_a_loaded_program_is_the_one_a_launch_hits(device_codec, built):
     """load_encode compiles without running; the first launch of every
-    B after it finds its step and its cut in the process."""
+    B after it finds its step in the process, and needs no other."""
     k, m, s = 5, 2, 200
     codec = Codec(k, m, k * s)
     loaded = ladder.load_encode(codec, HH, workers=2)
     rungs = ladder.rungs_of("encode")
     assert sorted(rec["B"] for rec in loaded) == list(rungs)
     assert {rec["cached"] for rec in loaded} <= {"compiled", "hit"}
-    # one step a rung, one cut a padded block count
-    assert (built("put_step"), built("head_blocks")) \
-        == (len(rungs), CAP - len(rungs))
+    # one step a rung, and nothing for a padded block count
+    assert built("put_step") == len(rungs)
+    assert built.steps() == {"jit(put_step)"}
     assert [rec["cached"] for rec in ladder.load_encode(codec, HH)] \
         == ["resident"] * len(rungs)
     sched = BatchScheduler(max_wait=0.001)
@@ -496,8 +526,8 @@ def test_a_loaded_program_is_the_one_a_launch_hits(device_codec, built):
             assert np.array_equal(dig, dig2)
     finally:
         sched.close()
-    assert (built("put_step"), built("head_blocks")) \
-        == (len(rungs), CAP - len(rungs))
+    assert built("put_step") == len(rungs)
+    assert built.steps() == {"jit(put_step)"}
 
 
 # ---------------------------------------------------------------------------
@@ -555,7 +585,7 @@ def test_the_first_short_block_loads_the_ragged_rungs_once(
         sched.close()
         win = telemetry.SPANS.record_end()
     assert sorted(c[1] for c in asked) == list(rungs)
-    assert {c[7] for c in asked} == {True}
+    assert {c[6] for c in asked} == {True}
     assert {c[2:5] for c in asked} == {(k, m, s)}
     (load,) = [sp for sp in win["spans"]
                if sp["name"] == "boot.load_programs"]
@@ -579,11 +609,11 @@ def test_a_ragged_launch_waits_for_its_rungs_load(device_codec,
     loading = threading.Event()
     real = Codec.load_encode_program
 
-    def load_encode_program(self, blocks, cuts, algo, ragged=False):
+    def load_encode_program(self, blocks, algo, ragged=False):
         if ragged and blocks == 4:
             loading.set()
             assert gate.wait(60)
-        return real(self, blocks, cuts, algo, ragged=ragged)
+        return real(self, blocks, algo, ragged=ragged)
     monkeypatch.setattr(Codec, "load_encode_program", load_encode_program)
     assert ladder.load_encode_ragged(codec, HH)
     assert not ladder.load_encode_ragged(codec, HH)      # once
@@ -617,7 +647,7 @@ def test_the_rung_a_launch_waits_for_loads_next(device_codec,
     gate = threading.Event()
     order: list = []
 
-    def load_encode_program(self, blocks, cuts, algo, ragged=False):
+    def load_encode_program(self, blocks, algo, ragged=False):
         order.append(blocks)
         assert gate.wait(60)
     monkeypatch.setattr(Codec, "load_encode_program", load_encode_program)
@@ -643,18 +673,15 @@ def test_the_rung_a_launch_waits_for_loads_next(device_codec,
 def test_a_loaded_ragged_program_is_the_one_a_launch_hits(
         device_codec, built, no_ragged_loads):
     """The ragged rungs load through the call form a launch uses: after
-    the load, launches of every B with a short block build nothing, and
-    the cuts are the static row's."""
+    the load, launches of every B with a short block build nothing."""
     k, m, s = 5, 2, 136
     codec = Codec(k, m, k * s)
     rungs = ladder.rungs_of("encode")
     ladder.load_encode(codec, HH, workers=2)              # boot
-    cuts = built("head_blocks")
     assert (built("put_step"), built("put_step_ragged")) == (len(rungs), 0)
     assert ladder.load_encode_ragged(codec, HH)
     _loader_done()
     assert built("put_step_ragged") == len(rungs)
-    assert built("head_blocks") == cuts
     enc = reference.encode_matrix(k, m)
     for b in range(1, CAP + 1):
         data, lengths = _short_group(b, b, k, s, 1 + 5 * b % s)
@@ -670,8 +697,9 @@ def test_a_loaded_ragged_program_is_the_one_a_launch_hits(
         assert not parity[-1, :, n:].any()
         assert np.array_equal(digests[-1],
                               reference.hh256_many(want[0]))
-    assert (built("put_step"), built("put_step_ragged"),
-            built("head_blocks")) == (len(rungs), len(rungs), cuts)
+    assert (built("put_step"), built("put_step_ragged")) \
+        == (len(rungs), len(rungs))
+    assert built.steps() == {"jit(put_step)", "jit(put_step_ragged)"}
 
 
 @pytest.mark.ragged_loads
@@ -686,7 +714,7 @@ def test_boot_never_loads_the_ragged_rungs(tmp_path, asked, monkeypatch,
         nd.shutdown()
     finally:
         win = telemetry.SPANS.record_end()
-    assert asked and not any(c[7] for c in asked)
+    assert asked and not any(c[6] for c in asked)
     assert ladder._RAGGED == {}
     assert [sp["attrs"]["trigger"] for sp in win["spans"]
             if sp["name"] == "boot.load_programs"] == ["boot"]

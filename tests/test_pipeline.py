@@ -179,6 +179,66 @@ def test_pipelined_shards_byte_identical_to_serial(tmp_path,
         assert got == want, f"drive {j} shard bytes diverge"
 
 
+@pytest.mark.parametrize("block_size", [BLOCK, BLOCK + 6],
+                         ids=["S-word-aligned", "S-not-word-aligned"])
+def test_device_parity_reaches_the_drives_as_contiguous_rows(
+        tmp_path, monkeypatch, block_size):
+    """On the device route parity crosses the link as 32-bit words and
+    is handed to `_write_shards_batch` as a VIEW of what crossed
+    (models/pipeline.host_rows): (B, m, S) uint8, every row
+    C-contiguous — a drive takes a row as one iovec, nothing is copied
+    — also when S is no multiple of the word (BLOCK + 6 over k = 4:
+    S = 16386), where the rows lie two pad bytes apart. The part files
+    are the host route's, byte for byte."""
+    import glob
+
+    from minio_tpu.object import codec as codec_mod
+    monkeypatch.setattr(engine_mod, "ENCODE_BATCH_BLOCKS", 2)
+    fmts = new_format_erasure_v3(1, NDISKS)
+
+    def mk(sub):
+        disks = []
+        for j in range(NDISKS):
+            d = XLStorage(str(tmp_path / f"{sub}d{j}"))
+            d.write_format(fmts[0][j])
+            disks.append(d)
+        e = ErasureSetObjects(disks, K, M, block_size=block_size)
+        e.make_bucket("b")
+        return e
+
+    # whole blocks: a short last block would start the background load
+    # of the ragged rungs (tests/test_ladder.py has that), which runs on
+    # under the tests that follow
+    data = payload(6 * block_size, seed=34)
+    mk("h").put_object("b", "obj", data)             # the host route
+
+    monkeypatch.setattr(codec_mod, "_device_is_tpu", lambda: True)
+    monkeypatch.setattr(codec_mod, "DEVICE_MIN_BYTES", 0)
+    seen = []
+    real = ErasureSetObjects._write_shards_batch
+
+    def spy(self, rows, parity, dd, dp, *a, **kw):
+        seen.append(parity)
+        return real(self, rows, parity, dd, dp, *a, **kw)
+    monkeypatch.setattr(ErasureSetObjects, "_write_shards_batch", spy)
+    put_pipelined(mk("v"), "obj", data)
+
+    s = -(-block_size // K)
+    device_made = [p for p in seen if not p.flags.owndata]
+    assert device_made, "no group rode the device route"
+    for parity in seen:
+        assert parity.dtype == np.uint8 and parity.shape[1:] == (M, s)
+        assert all(parity[b, j].flags.c_contiguous
+                   for b in range(parity.shape[0]) for j in range(M))
+    for j in range(NDISKS):
+        (want,) = glob.glob(
+            str(tmp_path / f"hd{j}" / "b" / "obj" / "*" / "part.1"))
+        (got,) = glob.glob(
+            str(tmp_path / f"vd{j}" / "b" / "obj" / "*" / "part.1"))
+        with open(want, "rb") as f, open(got, "rb") as g:
+            assert g.read() == f.read(), f"drive {j} shard bytes diverge"
+
+
 def test_pipeline_off_escape_hatch(tmp_path, monkeypatch):
     monkeypatch.setattr(pl, "ENABLED", False)
     called = []
@@ -391,13 +451,15 @@ def test_put_step_ragged_is_put_step_a_block_at_a_time(lengths):
         data[i, :, :n] = rng.integers(0, 256, (_RK, n), dtype=np.uint8)
     parity, digests = steps.put_step_ragged(
         data, np.asarray(lengths, np.int32), _RK, _RM)
-    parity, digests = np.asarray(parity), np.asarray(digests)
+    parity = steps.host_rows(np.asarray(parity), _RS)
+    digests = np.asarray(digests)
     assert parity.shape == (b, _RM, _RS)
     assert digests.shape == (b, _RK + _RM, 32)
     for i, n in enumerate(lengths):
         want_p, want_d = steps.put_step(
             np.ascontiguousarray(data[i:i + 1, :, :n]), _RK, _RM)
-        assert np.array_equal(parity[i, :, :n], np.asarray(want_p)[0]), i
+        assert np.array_equal(parity[i, :, :n],
+                              steps.host_rows(np.asarray(want_p), n)[0]), i
         assert not parity[i, :, n:].any(), i
         assert np.array_equal(digests[i], np.asarray(want_d)[0]), i
 
@@ -409,3 +471,31 @@ def test_put_step_ragged_is_one_program_a_shape():
     before = steps.put_step_ragged._cache_size()
     steps.put_step_ragged(data, np.array([77, 203], np.int32), _RK, _RM)
     assert steps.put_step_ragged._cache_size() == before
+
+# ---------------------------------------------------------------------------
+# the link form: S-wide outputs cross as 32-bit words
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape,at_blocks", [
+    ((1, 4, 346), 8), ((6, 3, 512), 8), ((7, 7, 33), 8),   # small: at 8
+    ((8, 4, 346), 8), ((3, 9, 346), 3), ((2, 16, 512), 2), ((20, 8, 512), 20),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_link_rows_is_the_same_bytes_as_words(shape, at_blocks):
+    """`link_rows` -> `host_rows` is the identity on the bytes, for an
+    S that is a multiple of the word and one that is not; a result of
+    under 8 blocks of under 8 rows is converted at 8 blocks (XLA's TPU
+    lowering compiles the small shapes for half a minute each) and
+    leaves at its own size."""
+    import jax
+    from minio_tpu.models import pipeline as steps
+    b, r, s = shape
+    rows = np.random.default_rng(b * r + s).integers(
+        0, 256, shape, dtype=np.uint8)
+    words = np.asarray(jax.jit(steps.link_rows)(rows))
+    assert (words.dtype, words.shape) == (np.uint32, (b, r, -(-s // 4)))
+    back = steps.host_rows(words, s)
+    assert back.dtype == np.uint8 and np.array_equal(back, rows)
+    assert np.shares_memory(back, words)
+    made = [v.aval.shape for eqn in jax.make_jaxpr(steps.link_rows)(rows).eqns
+            for v in eqn.outvars if v.aval.dtype == np.uint32]
+    assert made[0] == (at_blocks, r, -(-s // 4)) and made[-1] == words.shape

@@ -494,12 +494,19 @@ def _fused_cases(pkg: int):
 
 def _assert_same(got, want):
     """Element for element: arrays byte for byte (and row for row: a
-    pad row would change the shape), shared index lists as lists."""
+    pad row would change the shape), shared index lists as lists. An
+    array may be a read-only VIEW of what crossed the link (PR 34),
+    but it is uint8 and each of its rows lies C-contiguous: a drive
+    writes a row as one iovec, a decode copies it with one memcpy."""
     assert got is not None and len(got) == len(want)
     for g, w in zip(got, want):
         if isinstance(w, np.ndarray):
             assert isinstance(g, np.ndarray) and g.shape == w.shape
+            assert g.dtype == np.uint8
             assert g.tobytes() == w.tobytes()
+            assert all(g[i, j].flags.c_contiguous
+                       for i in range(g.shape[0])
+                       for j in range(g.shape[1]))
         else:
             assert list(g) == list(w)
 
@@ -521,7 +528,7 @@ def test_fused_program_direct_coalesced_and_host_agree(device_codec, entry):
     both = _joined(objs)
     stages = []
     got = direct(codec, both,
-                 stage_cb=lambda stage, _secs: stages.append(stage))
+                 stage_cb=lambda stage, _secs, **_said: stages.append(stage))
     assert stages == ["h2d", "compute", "fetch"]
     _assert_same(got, want(both))
     sched = BatchScheduler(max_wait=0.5)
@@ -659,6 +666,117 @@ def test_sse_encode_still_returns_ciphertext_rows(device_codec, monkeypatch,
                            for buf in sched._staging)
     finally:
         sched.close()
+
+
+# ---------------------------------------------------------------------------
+# PR 34: the S-wide outputs cross the link as 32-bit words and come back
+# as views — every fused entry still hands on what it handed on before
+# ---------------------------------------------------------------------------
+
+def _link_oracle(k: int, m: int, s: int, algo, b: int, seed: int):
+    """b blocks and what the HOST makes of them for the three verbs:
+    numpy RS, bitrot's own hashes. A data row and a parity row lost."""
+    rng = np.random.default_rng(seed)
+    o = SimpleNamespace(
+        data=rng.integers(0, 256, (b, k, s), dtype=np.uint8),
+        lost=(1, k + 1))
+
+    def digests(rows):
+        return bitrot_mod.hash_shards_batch(
+            rows.reshape(-1, rows.shape[-1]), algo
+        ).reshape(*rows.shape[:-1], 32)
+    codec = Codec(k, m, k * s)
+    o.full = codec.encode_batch(o.data, force="numpy")
+    o.encode = (o.full[:, k:], digests(o.full))
+    o.mask = sum(1 << i for i in range(k + m) if i not in o.lost)
+    dm, used, missing = rs_matrix.missing_data_matrix(k, m, o.mask)
+    o.surv = np.ascontiguousarray(o.full[:, list(used)])
+    o.decode = (o.full[:, list(missing)], list(missing), digests(o.surv))
+    _rec, used_r, _miss = rs_matrix.recover_matrix(k, m, o.mask)
+    assert tuple(used_r) == tuple(used)
+    o.recover = (o.full[:, list(o.lost)], list(o.lost), digests(o.surv),
+                 digests(o.full[:, list(o.lost)]))
+    return o
+
+
+@pytest.mark.parametrize("launch", ["padded", "gathered"])
+@pytest.mark.parametrize("verb", ["encode", "decode", "recover"])
+@pytest.mark.parametrize("algo", _ALGOS)
+@pytest.mark.parametrize("k,m,s", _GEOS)
+def test_every_entry_hands_on_the_parents_arrays(device_codec, k, m, s,
+                                                 algo, verb, launch):
+    """S = 346 is no multiple of the word (12+4's 349526 is none), 512
+    is one: a PADDED launch (the codec's own call over five blocks, at
+    rung 6) and a GATHERED launch of three groups (2 + 1 + 2, fused by
+    the former, padded too) return what the parent returned — shape,
+    uint8, bytes, no pad row — as views whose rows are contiguous."""
+    codec = Codec(k, m, k * s)
+    o = _link_oracle(k, m, s, algo, 5, seed=k * s + len(verb))
+    want = getattr(o, verb)
+    if launch == "padded":
+        got = {
+            "encode": lambda: codec.encode_and_hash_batch(o.data, algo),
+            "decode": lambda: codec.verify_and_decode_batch(
+                o.surv, o.mask, s, algo),
+            "recover": lambda: codec.verify_and_recover_batch(
+                o.surv, o.mask, set(o.lost), s, algo)}[verb]()
+        _assert_same(got, want)
+        return
+    sched = BatchScheduler(max_wait=0.5)
+    cuts = [(0, 2), (2, 3), (3, 5)]
+    try:
+        futs = [{
+            "encode": lambda a, b: sched.submit(codec, o.data[a:b], algo),
+            "decode": lambda a, b: sched.submit_decode(
+                codec, o.surv[a:b], o.mask, s, algo),
+            "recover": lambda a, b: sched.submit_recover(
+                codec, o.surv[a:b], o.mask, set(o.lost), s, algo),
+        }[verb](a, b) for a, b in cuts]
+        outs = [f.result(120) for f in futs]
+        st = sched.stats()["verbs"][verb]
+        assert (st["batches"], st["blocks"], st["pad_blocks"]) == (1, 5, 1)
+    finally:
+        sched.close()
+    for (a, b), out in zip(cuts, outs):
+        _assert_same(out, tuple(w[a:b] if isinstance(w, np.ndarray) else w
+                                for w in want))
+
+
+@pytest.mark.parametrize("k,m,s", _GEOS)
+def test_a_ragged_launch_hands_on_the_parents_arrays(device_codec, k, m, s):
+    """A launch with a short block (the ragged row; HighwayHash alone
+    has its kernel): three groups, the middle one ending short, fused
+    at rung 6 — parity at the full S, zero past a block's own length."""
+    codec = Codec(k, m, k * s)
+    rng = np.random.default_rng(s)
+    groups, lengths, wants = [], [], []
+    for b, short in ((2, 0), (1, s // 3 + 1), (2, 0)):
+        data = rng.integers(0, 256, (b, k, s), dtype=np.uint8)
+        ln = np.full(b, s, np.int32)
+        if short:
+            ln[-1] = short
+            data[-1, :, short:] = 0
+        parity = np.zeros((b, m, s), np.uint8)
+        digests = np.empty((b, k + m, 32), np.uint8)
+        for i, n in enumerate(ln):
+            full = rs_ref.encode(np.ascontiguousarray(data[i, :, :n]), m)
+            parity[i, :, :n] = full[k:]
+            digests[i] = bitrot_mod.hash_shards_batch(full, HH)
+        groups.append(data)
+        lengths.append(ln if short else None)
+        wants.append((parity, digests))
+    sched = BatchScheduler(max_wait=0.5)
+    try:
+        futs = [sched.submit(codec, g, HH, lengths=ln)
+                for g, ln in zip(groups, lengths)]
+        outs = [f.result(120) for f in futs]
+        st = sched.stats()["verbs"]["encode"]
+        assert (st["batches"], st["ragged_batches"], st["pad_blocks"]) \
+            == (1, 1, 1)
+    finally:
+        sched.close()
+    for out, want in zip(outs, wants):
+        _assert_same(out, want)
 
 
 # ---------------------------------------------------------------------------

@@ -174,8 +174,10 @@ def test_recorder_on_keeps_fast_roots_whole_and_counts_drops(monkeypatch):
         for i in range(5):
             with telemetry.trace(f"r{i}"):
                 with telemetry.span("work"):
-                    t_end = time.perf_counter() + 0.005
-                    while time.perf_counter() < t_end:
+                    # 5 ms of this thread's OWN CPU: a wall-clock spin
+                    # is not granted them on a loaded box
+                    t_end = time.thread_time() + 0.005
+                    while time.thread_time() < t_end:
                         pass
     finally:
         win = sink.record_end()
@@ -235,10 +237,17 @@ def test_dispatch_children_nest_and_collect_plus_slot_is_queue(dev_routed):
         for t in ts:
             t.join(180)
     finally:
+        stats = sched.stats()
         sched.close()
     assert len(roots) == 2
     for s in stages:
         assert hist.count(verb="encode", stage=s) > before[s], s
+    # the readback's rate a verb without a new reader: bytes that
+    # crossed over the fetch stages' seconds
+    enc = stats["verbs"]["encode"]
+    assert enc["fetched_bytes"] == sum(
+        4 * (2 * 4096 + (k + 2) * 32) for k in (4, 6))
+    assert 0 < enc["fetch_seconds"] < 60
     for root in roots:
         tree = root.to_dict()
         by = {sp["name"]: sp for sp in _flat(tree)}
@@ -246,6 +255,12 @@ def test_dispatch_children_nest_and_collect_plus_slot_is_queue(dev_routed):
         assert {c["name"] for c in d["children"]} >= {
             "sched.queue", "sched.transfer", "sched.h2d", "sched.compute",
             "sched.fetch"}
+        # what crossed back, and as what: parity (4, 2, 4096) uint8
+        # crosses as 32-bit words
+        k = int(tree["name"].rsplit("-", 1)[1])
+        assert by["sched.fetch"]["attrs"] == {
+            "bytes": 4 * (2 * 4096 + (k + 2) * 32),
+            "form": "uint32[4, 2, 1024]"}
         q = by["sched.queue"]
         assert [c["name"] for c in q["children"]] == ["sched.collect",
                                                       "sched.slot"]
