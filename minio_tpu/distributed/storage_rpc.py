@@ -160,9 +160,10 @@ class StorageRPCServer:
                                  force=a.get("force") == "true")
 
     def _writemetadata(self, a, b):
+        # a `fresh` argument from an older peer is ignored: the journal
+        # is merged either way
         self._disk(a).write_metadata(a["volume"], a["path"],
-                                     fi_from_dict(json.loads(b.decode())),
-                                     fresh=a.get("fresh") == "true")
+                                     fi_from_dict(json.loads(b.decode())))
 
     def _readversion(self, a, b):
         fi = self._disk(a).read_version(a["volume"], a["path"],
@@ -404,11 +405,8 @@ class RemoteStorage(StorageAPI):
 
     # -- metadata ----------------------------------------------------------
 
-    def write_metadata(self, volume: str, path: str, fi: FileInfo,
-                       fresh: bool = False) -> None:
-        self._call("writemetadata",
-                   {"volume": volume, "path": path,
-                    "fresh": "true" if fresh else "false"},
+    def write_metadata(self, volume: str, path: str, fi: FileInfo) -> None:
+        self._call("writemetadata", {"volume": volume, "path": path},
                    json.dumps(fi_to_dict(fi)).encode())
 
     def read_version(self, volume: str, path: str,

@@ -67,6 +67,7 @@ class StreamingBitrotWriter:
         self.shard_size, self.algo = shard_size, algo
         self._buf = io.BytesIO()
         self._started = False
+        self.closed = False
         # Local drives expose a persistent append handle: frames stream
         # straight into the OS file (one memcpy pass fewer than
         # buffer-then-append). Remote disks keep the buffered batches —
@@ -137,6 +138,10 @@ class StreamingBitrotWriter:
         self._buf = io.BytesIO()
 
     def close(self) -> None:
+        """Flush what is buffered and close the file; a second call
+        is a no-op (`closed`)."""
+        if self.closed:
+            return
         if self._use_appender:
             try:
                 if self._file is None:
@@ -148,8 +153,9 @@ class StreamingBitrotWriter:
                 raise errors.FaultyDisk(str(e)) from e
             finally:
                 self._file = None
-            return
-        self._flush()
+        else:
+            self._flush()
+        self.closed = True
 
     def digest(self) -> bytes:
         return b""  # streaming: digests live in the frames
@@ -162,6 +168,7 @@ class WholeBitrotWriter:
         self.algo = algo
         self._hasher = bitrot_mod.new_hasher(algo)
         self._buf = io.BytesIO()
+        self.closed = False
 
     def write(self, block: bytes) -> None:
         self._hasher.update(block)
@@ -178,9 +185,12 @@ class WholeBitrotWriter:
         return 0, False
 
     def close(self) -> None:
+        if self.closed:
+            return
         data = self._buf.getvalue()
         self.disk.create_file(self.volume, self.path, len(data),
                               io.BytesIO(data))
+        self.closed = True
 
     def digest(self) -> bytes:
         return self._hasher.digest()

@@ -99,14 +99,20 @@ class GetOptions:
 
 
 # fan-outs / commits says how many quorum fan-outs over the drive-io
-# pool one PUT's commit costs (2: stage, rename)
+# pool one PUT's commit costs (1: rename); 1 - close fan-outs / commits
+# is the share of PUTs whose writers closed in their last shard write
 _PUT_COMMITS = telemetry.REGISTRY.counter(
     "minio_tpu_put_commits_total",
     "Single-part PUT commits begun (shards written, under the lock)")
 _PUT_COMMIT_FANOUTS = telemetry.REGISTRY.counter(
     "minio_tpu_put_commit_fanouts_total",
     "Quorum fan-outs over the drive set issued by single-part PUT "
-    "commits (stage, rename)")
+    "commits (rename, and the fallback close)")
+_PUT_CLOSE_FANOUTS = telemetry.REGISTRY.counter(
+    "minio_tpu_put_close_fanouts_total",
+    "Single-part PUT commits that closed their shard writers in a "
+    "fan-out of their own: no group was known to be the last (a 0-byte "
+    "object, or a stream of unknown length that ended on a group)")
 
 _GET_STREAMS = None
 
@@ -493,23 +499,25 @@ class ErasureObjects:
             t = telemetry.timed("pipeline.shard_write")
             try:
                 with t:
-                    for rows, parity, dd, dp in (
-                            item["rows_multi"] if "rows_multi" in item
-                            else [item["rows"]]):
+                    groups = item["rows_multi"] if "rows_multi" in item \
+                        else [item["rows"]]
+                    for j, (rows, parity, dd, dp) in enumerate(groups):
                         self._write_shards_batch(
                             rows, parity, dd, dp, writers, write_quorum,
-                            lengths=item.get("lengths"))
+                            lengths=item.get("lengths"),
+                            last=item["last"] and j == len(groups) - 1)
             finally:
                 recycle(item)
                 stage_s[2] += t.seconds
 
         pipe = None
 
-        def feed(data, lengths=None) -> None:
+        def feed(data, lengths=None, last=False) -> None:
             """Hand the CURRENT buffer (if any) plus `data` to the
             pipeline, spinning the stage threads up on first use.
             `lengths`: of the stream's last group, when it ends in a
-            short block (`_lay_short_block`).
+            short block (`_lay_short_block`). `last`: no group follows,
+            so the write stage closes the writers in its fan-out.
             Buffer ownership transfers to the item BEFORE submit — if
             submit raises a pending stage error, on_drop recycles the
             item's buffer and the caller's finally must not recycle it
@@ -522,7 +530,8 @@ class ErasureObjects:
                                         depth=pl.DEPTH, name="put-pipe",
                                         on_drop=recycle)
             owned, buf = buf, None
-            item = {"buf": owned, "data": data, "lengths": lengths}
+            item = {"buf": owned, "data": data, "lengths": lengths,
+                    "last": last}
             if sse is not None:
                 # per-row key/nonce word arrays ride the dispatch; the
                 # bucket key carries only their shape, so concurrent
@@ -581,9 +590,11 @@ class ErasureObjects:
                 if n == bs:
                     nb += 1
                     if nb == cap:
-                        feed(arr[:nb].reshape(nb, k, s_len))
+                        at_end = 0 <= known_size == total
+                        feed(arr[:nb].reshape(nb, k, s_len),
+                             last=at_end and sse is None)
                         nb = 0
-                        if 0 <= known_size == total:
+                        if at_end:
                             # exact batch multiple: EOF is certain, so
                             # don't block on a probe buffer the stream
                             # will never write into
@@ -604,6 +615,9 @@ class ErasureObjects:
                     nb += 1
                     break
             reads.flush(blocks=nb)
+            # the read loop has ended (EOF, or a short block): without
+            # SSE the group below is the stream's last; under SSE the
+            # last of the finish batches is
             if nb:
                 if pipe is None:
                     # a stream that fit one batch (an unknown-length
@@ -612,19 +626,18 @@ class ErasureObjects:
                                        arr[:nb].reshape(nb, k, s_len),
                                        writers, write_quorum,
                                        sse=sse, sse_off=enc_off,
-                                       lengths=lengths)
+                                       lengths=lengths, last=sse is None)
                     enc_off += nb * bs
                 else:
-                    feed(arr[:nb].reshape(nb, k, s_len), lengths)
+                    feed(arr[:nb].reshape(nb, k, s_len), lengths,
+                         last=sse is None)
             if sse is not None:
                 if pipe is None:
-                    for rows in self._sse_finish_rows(codec, sse,
-                                                      tail_pt, enc_off):
-                        self._write_shards_batch(*rows, writers,
-                                                 write_quorum)
+                    self._write_sse_finish(codec, sse, tail_pt, enc_off,
+                                           writers, write_quorum)
                 else:
                     pipe.submit({"sse_finish": True, "tail": tail_pt,
-                                 "sse_off": enc_off})
+                                 "sse_off": enc_off, "last": True})
             if pipe is not None:
                 pipe.close()    # join; re-raises the first stage error
         except BaseException:
@@ -664,9 +677,10 @@ class ErasureObjects:
         enc_off = 0
         tail_pt = b""
         lengths = None    # of the last group, when it ends short
+        known_size = getattr(reader, "size", -1)
         reads = telemetry.accum("put.read_stream")
 
-        def flush_full(n_rows: int) -> None:
+        def flush_full(n_rows: int, last: bool) -> None:
             nonlocal enc_off
             reads.flush(blocks=n_rows)
             if n_rows:
@@ -674,7 +688,8 @@ class ErasureObjects:
                                    buf[:n_rows].reshape(n_rows, k, s_len),
                                    writers, write_quorum,
                                    sse=sse, sse_off=enc_off,
-                                   lengths=lengths)
+                                   lengths=lengths,
+                                   last=last and sse is None)
                 enc_off += n_rows * bs
 
         while True:
@@ -688,8 +703,11 @@ class ErasureObjects:
             if n == bs:
                 nb += 1
                 if nb == cap:
-                    flush_full(nb)
+                    at_end = 0 <= known_size == total
+                    flush_full(nb, at_end)
                     nb = 0
+                    if at_end:
+                        break
             else:
                 if sse is not None:
                     # short last block under SSE joins the tag trailer
@@ -701,12 +719,11 @@ class ErasureObjects:
                 lengths = _lay_short_block(buf, nb, n, k, s_len)
                 nb += 1
                 break
-        flush_full(nb)
+        flush_full(nb, True)
         if sse is not None:
             from ..features.crypto import encrypted_size
-            for rows in self._sse_finish_rows(codec, sse, tail_pt,
-                                              enc_off):
-                self._write_shards_batch(*rows, writers, write_quorum)
+            self._write_sse_finish(codec, sse, tail_pt, enc_off, writers,
+                                   write_quorum)
             return encrypted_size(total)
         return total
 
@@ -784,13 +801,14 @@ class ErasureObjects:
 
     def _encode_write(self, codec: Codec, data: np.ndarray, writers,
                       write_quorum: int, sse=None, sse_off: int = 0,
-                      lengths=None) -> None:
+                      lengths=None, last: bool = False) -> None:
         """Encode+digest one (B, k, S) batch and fan the framed shard
         writes out — data rows go to the writers as views of `data`.
         With `sse`, the batch rows are PLAINTEXT full blocks starting
         at stream offset `sse_off` and the cipher fuses in (or falls
         back to the in-place CPU cipher). `lengths`: of a plain group
-        that ends in a short block (`_lay_short_block`)."""
+        that ends in a short block (`_lay_short_block`). `last`: the
+        stream's last group (`_write_shards_batch`)."""
         with telemetry.span("pipeline.encode", blocks=data.shape[0]):
             if sse is not None:
                 item = {"sse_kn": sse.batch_params(
@@ -811,7 +829,8 @@ class ErasureObjects:
                     lengths=lengths)
         with telemetry.span("pipeline.shard_write"):
             self._write_shards_batch(data_rows, parity, dd, dp, writers,
-                                     write_quorum, lengths=lengths)
+                                     write_quorum, lengths=lengths,
+                                     last=last)
 
     def _sse_encode(self, codec: Codec, data: np.ndarray, item, fut,
                     sse) -> tuple[np.ndarray, np.ndarray, np.ndarray,
@@ -866,10 +885,19 @@ class ErasureObjects:
             out.append(self._unpack_fused(codec, data, None))
         return out
 
+    def _write_sse_finish(self, codec: Codec, sse, tail_pt: bytes,
+                          off: int, writers, write_quorum: int) -> None:
+        """Write an SSE stream's finish batches in order; the last is
+        the stream's last group."""
+        groups = self._sse_finish_rows(codec, sse, tail_pt, off)
+        for j, rows in enumerate(groups):
+            self._write_shards_batch(*rows, writers, write_quorum,
+                                     last=j == len(groups) - 1)
+
     def _write_shards_batch(self, data: np.ndarray, parity: np.ndarray,
                             dd: np.ndarray, dp: np.ndarray,
                             writers, write_quorum: int,
-                            lengths=None) -> None:
+                            lengths=None, last: bool = False) -> None:
         """parallelWriter.Write, batched: writer i gets ALL B of its
         [digest‖block] frames in one call (cmd/erasure-encode.go:38-72's
         per-disk goroutine — but fanned out once per encode batch, not
@@ -879,7 +907,12 @@ class ErasureObjects:
         parity arrive as separate arrays so the data rows stay views of
         the read buffer. `lengths`: each block's own shard length, of a
         group that ends in a short block — its frame is
-        [digest][lengths[b] bytes], in the same write as the others."""
+        [digest][lengths[b] bytes], in the same write as the others.
+        `last`: the stream's last group — each drive's task closes its
+        writer after the frames (the flush, and the fsync where it is
+        on), so this fan-out's quorum is the commit's barrier: write
+        quorum of drives hold every shard, closed, before any drive is
+        told to rename. A close error is that drive's write error."""
         B, k = data.shape[0], data.shape[1]
         ends = [data.shape[2]] * B if lengths is None \
             else [int(n) for n in lengths]
@@ -896,13 +929,17 @@ class ErasureObjects:
                     [np.ascontiguousarray(rows[bi, j, :ends[bi]])
                      for bi in range(B)],
                     [np.ascontiguousarray(digs[bi, j]) for bi in range(B)])
-                t.annotate(writes=writes, vectored=int(vectored))
+                if last:
+                    w.close()
+                t.annotate(writes=writes, vectored=int(vectored),
+                           closed=int(last))
             healthtrack.observe_disk(w.disk, "write", t.seconds)
 
         # quorum-ack lane: once write-quorum writers are durable, a
         # laggard past the stall grace is dropped from the fan-out
-        # (and from every later batch via writers[i] = None below) —
-        # its missing shard heals through MRF instead of setting p99
+        # (and from every later batch, and from the rename, via
+        # writers[i] = None below) — its missing shard heals through
+        # MRF instead of setting p99
         _, errs = meta.for_each_disk_quorum(
             list(writers),  # type: ignore[arg-type]
             write, write_quorum, stall_s=healthtrack.write_stall_s(),
@@ -917,34 +954,19 @@ class ErasureObjects:
 
     def _commit(self, shuffled, writers, tmp_id: str, fi: FileInfo,
                 bucket: str, object_name: str, write_quorum: int) -> int:
-        """2-phase commit in two quorum fan-outs — stage (close the
-        shard writer, write the staged journal), then rename; returns
-        how many drives MISSED the commit (offline slot, dropped
-        writer, or failed rename) — the MRF degraded-write signal. The
-        barrier between the two IS the two phases: below write quorum
-        at stage, no drive has been told to rename."""
-        metas = [fi.light_copy() for _ in range(len(shuffled))]
-
-        def stage(i, d):
-            w = writers[i]
-            if w is None:
-                raise serr.DiskNotFound(f"writer {i}")
-            w.close()  # flushes remaining frames (empty file for 0-byte)
-            m = metas[i]
-            m.erasure.index = i + 1
-            if not self.bitrot_algo.streaming:
-                # whole-file digests are per-drive (each shard differs)
-                for c in m.erasure.checksums:
-                    c.hash = w.digest()
-            # the drive is told what this process knows: the staging
-            # directory is this request's own, and (at rename) the
-            # version in it — it probes for and re-reads neither
-            d.write_metadata(MINIO_META_TMP_BUCKET, tmp_id, m, fresh=True)
-
+        """Commit in ONE quorum fan-out, rename: each drive is handed
+        its own version (no staged journal). Returns how many drives
+        MISSED the commit (offline slot, dropped writer, or failed
+        rename) — the MRF degraded-write signal. The barrier before it
+        is the last shard-write fan-out, whose tasks closed the
+        writers: below write quorum there, no drive was told to rename.
+        Where no group was known to be the last (0 bytes, or a stream
+        of unknown length that ended on a group), a fan-out of closes
+        is that barrier."""
         # the whole commit window rides the quorum-ack lane: a drive
-        # stalling at close/meta/rename must not hold the client ack
-        # once quorum is durable — it is counted into `lost` below and
-        # the object converges back through MRF
+        # stalling at close/rename must not hold the client ack once
+        # quorum is durable — it is counted into `lost` below and the
+        # object converges back through MRF
         stall = healthtrack.write_stall_s()
         commit_span = telemetry.current_span()
         _PUT_COMMITS.inc()
@@ -960,27 +982,46 @@ class ErasureObjects:
                     commit_span.attrs.get("fanouts", 0) + 1
             return errs
 
-        # shard fan-out is done (in tmp), no metadata exists yet —
-        # a crash here must leave the previous version untouched and
-        # only tmp garbage for fsck to reclaim
+        def live():
+            return [d if writers[i] is not None else None
+                    for i, d in enumerate(shuffled)]
+
+        if any(w is not None and not w.closed for w in writers):
+            _PUT_CLOSE_FANOUTS.inc()
+            # flushes remaining frames (an empty file for 0 bytes)
+            errs = fan_out("close", live(), lambda i, d: writers[i].close())
+            for i, e in enumerate(errs):
+                if e is not None:
+                    writers[i] = None
+            err = meta.reduce_write_quorum_errs(
+                errs, meta.OBJECT_OP_IGNORED_ERRS, write_quorum)
+            if err is not None:
+                raise err
+        # closed shards in tmp, no metadata anywhere new — a crash here
+        # must leave the previous version untouched and only tmp
+        # garbage for fsck to reclaim
         crashpoint.hit("put.shards.before_meta")
-        errs = fan_out("stage", shuffled, stage)
-        for i, e in enumerate(errs):
-            if e is not None:
-                writers[i] = None
-        err = meta.reduce_write_quorum_errs(
-            errs, meta.OBJECT_OP_IGNORED_ERRS, write_quorum)
-        if err is not None:
-            raise err
-        staged = meta.eval_disks(shuffled, errs)
-        # fully staged, uncommitted: the rename fan-out is the point
-        # of no return
+        metas = [fi.light_copy() for _ in range(len(shuffled))]
+        for i, w in enumerate(writers):
+            if w is None:
+                continue
+            metas[i].erasure.index = i + 1
+            if not self.bitrot_algo.streaming:
+                # whole-file digests are per-drive (each shard differs)
+                for c in metas[i].erasure.checksums:
+                    c.hash = w.digest()
+        staged = live()
+        # every version made, nothing committed: the rename fan-out is
+        # the point of no return
         crashpoint.hit("put.meta.before_rename")
 
         def rename(i, d):
             # one hit per drive: arm :<nth> to die with n-1 drives
             # committed (torn below/at write quorum)
             crashpoint.hit("put.rename.partial", disk=i)
+            # the drive is handed its version: the staging directory
+            # holds the data dir alone, and no journal is read or made
+            # there
             d.rename_data(MINIO_META_TMP_BUCKET, tmp_id, fi.data_dir,
                           bucket, object_name, fi=metas[i])
 

@@ -87,12 +87,8 @@ class StorageAPI(abc.ABC):
     # -- metadata ----------------------------------------------------------
 
     @abc.abstractmethod
-    def write_metadata(self, volume: str, path: str, fi: FileInfo,
-                       fresh: bool = False) -> None:
-        """Append fi into path's journal. `fresh`: path is a staging
-        directory the caller made for this write alone — nothing to
-        read or merge, the journal holds fi and no more."""
-        ...
+    def write_metadata(self, volume: str, path: str,
+                       fi: FileInfo) -> None: ...
 
     @abc.abstractmethod
     def read_version(self, volume: str, path: str,
@@ -129,8 +125,8 @@ class StorageAPI(abc.ABC):
         committed (empty = legacy latest-pick) — version-faithful
         replays stage versions whose mod time sorts behind the
         session placeholder, so "latest" is not "the one". `fi`: the
-        version itself, as staged with write_metadata(fresh=True) — the
-        drive then commits it without reading the staged journal back."""
+        version itself, for a staging directory that holds the data dir
+        alone — the drive commits it with no staged journal to read."""
         ...
 
     # -- files -------------------------------------------------------------

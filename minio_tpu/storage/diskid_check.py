@@ -120,9 +120,8 @@ class DiskIDCheck(StorageAPI):
     def delete_vol(self, volume, force=False):
         return self._call(self.inner.delete_vol, volume, force)
 
-    def write_metadata(self, volume, path, fi, fresh=False):
-        return self._call(self.inner.write_metadata, volume, path, fi,
-                          fresh)
+    def write_metadata(self, volume, path, fi):
+        return self._call(self.inner.write_metadata, volume, path, fi)
 
     def read_version(self, volume, path, version_id=""):
         return self._call(self.inner.read_version, volume, path,

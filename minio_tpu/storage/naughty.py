@@ -383,10 +383,9 @@ class NaughtyDisk(StorageAPI):
 
     # -- metadata ----------------------------------------------------------
 
-    def write_metadata(self, volume: str, path: str, fi: FileInfo,
-                       fresh: bool = False) -> None:
+    def write_metadata(self, volume: str, path: str, fi: FileInfo) -> None:
         self._begin("write_metadata")
-        self.inner.write_metadata(volume, path, fi, fresh)
+        self.inner.write_metadata(volume, path, fi)
 
     def read_version(self, volume: str, path: str,
                      version_id: str = "") -> FileInfo:

@@ -422,13 +422,10 @@ class XLStorage(StorageAPI):
     def write_all(self, volume: str, path: str, data: bytes) -> None:
         self._commit_file(self._file_path(volume, path), data)
 
-    def _commit_file(self, fp: str, data: bytes, made: bool = False,
-                     private: bool = False) -> None:
+    def _commit_file(self, fp: str, data: bytes, made: bool = False) -> None:
         """write_all's body. `made`: the caller has seen fp's directory
         (it made it, or read from it), so none is made unless the write
-        misses it. `private`: nothing reads fp before its directory is
-        committed or swept, so it is written in place — no temp
-        sibling, no rename; the fsync discipline is the same."""
+        misses it."""
         try:
             if not made:
                 os.makedirs(os.path.dirname(fp), exist_ok=True)
@@ -440,15 +437,13 @@ class XLStorage(StorageAPI):
                            data=data)
             # write-temp → (fsync) → rename → (dirsync): MINIO_TPU_FSYNC
             # turns the barriers on (pkg/safe analog + ALICE safe-rename)
-            write = atomicfile.write_in_place if private \
-                else atomicfile.write_atomic
             try:
-                write(fp, data)
+                atomicfile.write_atomic(fp, data)
             except FileNotFoundError:
                 if not made:
                     raise
                 os.makedirs(os.path.dirname(fp), exist_ok=True)
-                write(fp, data)
+                atomicfile.write_atomic(fp, data)
         except NotADirectoryError:
             raise errors.FileParentIsFile(fp) from None
         except OSError as e:
@@ -716,27 +711,16 @@ class XLStorage(StorageAPI):
             return meta
         return XLMetaV2.loads(buf)
 
-    def write_metadata(self, volume: str, path: str, fi: FileInfo,
-                       fresh: bool = False) -> None:
+    def write_metadata(self, volume: str, path: str, fi: FileInfo) -> None:
         """Append fi as a version into xl.meta (creating it if absent) —
-        reference WriteMetadata (cmd/xl-storage.go:1219). `fresh`: path
-        is a staging directory the caller made for this write alone, so
-        there is no journal to merge and no legacy file to probe for;
-        the journal is written in place beside the shards the caller
-        has just put there (a staging directory no writer has made yet
-        is made when the write misses it)."""
-        if fresh:
+        reference WriteMetadata (cmd/xl-storage.go:1219)."""
+        try:
+            meta = self._read_xl_meta(volume, path)
+        except errors.FileNotFound:
             meta = XLMetaV2()
-        else:
-            try:
-                meta = self._read_xl_meta(volume, path)
-            except errors.FileNotFound:
-                meta = XLMetaV2()
         meta.add_version(fi)
-        self._commit_file(
-            self._file_path(volume,
-                            os.path.join(path, XL_STORAGE_FORMAT_FILE)),
-            meta.dumps(), made=fresh, private=fresh)
+        self.write_all(volume, os.path.join(path, XL_STORAGE_FORMAT_FILE),
+                       meta.dumps())
 
     def read_version(self, volume: str, path: str,
                      version_id: str = "") -> FileInfo:
@@ -780,9 +764,8 @@ class XLStorage(StorageAPI):
         2-phase-commit finish). `version_id` names the version being
         committed; without it the latest entry is assumed (correct
         only when the staged meta holds one version). `fi` is the
-        version the caller staged with write_metadata(fresh=True): with
-        it the staged xl.meta is not read back, and src is taken for a
-        staging directory that holds that file and the data dir only."""
+        version itself: with it src is taken for a staging directory
+        that holds the data dir alone — no staged xl.meta is read."""
         with telemetry.span("disk.rename_data") as sp:
             dst = self._rename_data(src_volume, src_path, data_dir,
                                     dst_volume, dst_path, version_id, fi)
@@ -793,10 +776,11 @@ class XLStorage(StorageAPI):
                      ) -> tuple[XLMetaV2, str, bool]:
         """The journal a commit into volume/path merges into, what was
         found there — `journal` (an xl.meta), `legacy` (an xl.json,
-        migrated), `fresh` (neither) — and whether the object
-        directory was made here, empty. A fresh key costs one failed
-        open and the mkdir it needs anyway; only a directory that is
-        there without an xl.meta pays for the legacy probe."""
+        migrated), `corrupt` (an xl.meta that does not parse, dropped),
+        `fresh` (none) — and whether the object directory was made
+        here, empty. A fresh key costs one failed open and the mkdir it
+        needs anyway; only a directory that is there without an
+        xl.meta pays for the legacy probe."""
         obj = self._file_path(volume, path)
         try:
             with open(os.path.join(obj, XL_STORAGE_FORMAT_FILE), "rb") as f:
@@ -808,7 +792,14 @@ class XLStorage(StorageAPI):
         except OSError:
             found = "journal"          # read_all names the error
         else:
-            return XLMetaV2.loads(buf), "journal", False
+            try:
+                return XLMetaV2.loads(buf), "journal", False
+            except errors.FileCorrupt:
+                # a journal torn by a crash inside its write (the fsync
+                # discipline off) is dropped, as upstream RenameData
+                # drops one ("Data appears corrupt"): the versions it
+                # held are on the other drives, and heal brings them
+                return XLMetaV2(), "corrupt", False
         try:
             return self._read_xl_meta(volume, path), found, False
         except errors.FileNotFound:
@@ -886,14 +877,9 @@ class XLStorage(StorageAPI):
 
     def _drop_staging(self, volume: str, path: str) -> bool:
         """Remove a staging directory that, its data dir renamed away,
-        holds its xl.meta and nothing else: an unlink and an rmdir.
-        False when it holds more (or is gone): the caller's recursive
-        delete decides."""
+        is empty: an rmdir. False when it holds more (or is gone): the
+        caller's recursive delete decides."""
         fp = self._file_path(volume, path)
-        try:
-            os.unlink(os.path.join(fp, XL_STORAGE_FORMAT_FILE))
-        except OSError:
-            pass
         try:
             os.rmdir(fp)
         except OSError:

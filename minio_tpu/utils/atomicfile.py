@@ -33,7 +33,7 @@ from typing import Optional
 from . import knobs
 
 __all__ = ["fsync_enabled", "fsync_file", "fsync_dir", "write_atomic",
-           "write_in_place", "load_json_doc"]
+           "load_json_doc"]
 
 
 def fsync_enabled() -> bool:
@@ -99,16 +99,6 @@ def write_atomic(path: str, data: bytes) -> None:
         except OSError:
             pass
         raise
-    fsync_dir(os.path.dirname(path) or ".")
-
-
-def write_in_place(path: str, data: bytes) -> None:
-    """write → (fsync) → (dirsync) under the final name, for a file in
-    a private staging directory: nothing reads it before the directory
-    itself is committed or swept, so a crash leaves a torn copy only
-    where no reader looks, and the temp sibling and its rename buy
-    nothing. The barriers are write_atomic's."""
-    _write_file(path, data)
     fsync_dir(os.path.dirname(path) or ".")
 
 

@@ -96,13 +96,14 @@ def names() -> List[str]:
 
 _W = "PUT commit (engine._commit)"
 define("put.shards.before_meta",
-       "after the shard fan-out completes, before the stage fan-out "
-       "(close + staged xl.meta) — shards exist in tmp, no metadata "
-       "anywhere", _W)
+       "after the last shard-write fan-out (the writers closed in it) "
+       "or the fallback close fan-out, before the rename fan-out — "
+       "closed shards in tmp, no metadata anywhere", _W)
 define("put.meta.before_rename",
-       "after the stage fan-out lands at write quorum in tmp, before "
-       "the rename_data fan-out — a fully staged but uncommitted "
-       "write", _W)
+       "after the last shard-write fan-out (the writers closed in it) "
+       "or the fallback close fan-out, and after the per-drive "
+       "versions are made in memory, before the rename fan-out — "
+       "closed shards in tmp, no metadata anywhere", _W)
 define("put.rename.partial",
        "inside the per-disk rename fan-out (one hit per disk; arm "
        ":<nth> to die after n-1 disks committed) — a torn commit "

@@ -339,10 +339,11 @@ def test_torn_write_injection(tmp_path):
         == json.loads(doc)
 
 
-def test_torn_staged_meta_on_one_drive_converges(zz):
-    """Tear ONE drive's staged xl.meta mid-PUT: quorum still commits,
-    the object reads back complete, and fsck reclaims the leaked tmp
-    staging the torn drive left behind."""
+def test_torn_meta_on_one_drive_converges(zz):
+    """Tear ONE drive's xl.meta mid-PUT (the first raw-file commit of a
+    PUT is a drive's committed journal): quorum still commits, the
+    object reads back complete, and fsck's heal drops the torn journal
+    for a whole one and reclaims the staging the torn drive left."""
     crashpoint.arm("storage.write_all.commit",
                    action=crashpoint.torn_write_action(0.3))
     zz.put_object("b", "torn", b"T" * 2500)

@@ -294,7 +294,7 @@ PUT_SPANS = {"s3.auth", "s3.body_hash", "s3.respond", "engine.put_object",
              "pipeline.encode", "sched.dispatch", "sched.queue",
              "sched.collect", "sched.slot", "sched.transfer", "sched.h2d",
              "sched.compute", "sched.fetch", "pipeline.shard_write",
-             "disk.shard_write", "put.commit", "put.stage", "put.rename",
+             "disk.shard_write", "put.commit", "put.rename",
              "disk.rename_data"}
 GET_SPANS = {"s3.auth", "s3.respond", "get.open", "engine.get_object",
              "get.read_shards", "disk.shard_read",
@@ -365,14 +365,19 @@ def test_put_and_degraded_get_give_one_whole_tree_each(node, tmp_path):
     assert by["s3.body_hash"][0]["attrs"]["bytes"] == len(body)
     assert by["s3.body_hash"][0]["parent_id"] == put["span_id"]
     assert len(by["pipeline.encode"]) == len(by["pipeline.shard_write"])
+    # each drive's writer closes in the last group's write task, and in
+    # no other
+    assert sorted(sp["attrs"]["closed"] for sp in by["disk.shard_write"]) \
+        == [0] * 12 + [1] * 6
+    assert "put.stage" not in by and "put.close" not in by
     assert "cpu_ns" in put                           # recorded: thread CPU
 
 
 @pytest.mark.parametrize("key", ["fresh", "overwrite"])
 def test_commit_spans_say_what_each_drive_was_handed(node, key):
-    """put.commit counts its quorum fan-outs; every disk.rename_data
-    says whether it read the staged journal back (never, for a PUT)
-    and what it found at the destination."""
+    """put.commit counts its quorum fan-outs (one: rename); every
+    disk.rename_data says whether it read a staged journal back (never,
+    for a PUT) and what it found at the destination."""
     from tests.test_telemetry import Client
     c = Client(node.s3.port, CREDS)
     assert c.request("PUT", "/cmtb")[0] == 200
@@ -381,6 +386,7 @@ def test_commit_spans_say_what_each_drive_was_handed(node, key):
     counters = [telemetry.REGISTRY.counter(name, "") for name in (
         "minio_tpu_put_commits_total",
         "minio_tpu_put_commit_fanouts_total",
+        "minio_tpu_put_close_fanouts_total",
         "minio_tpu_rename_data_src_reads_total")]
     before = [ctr.value() for ctr in counters]
     telemetry.SPANS.record_begin()
@@ -392,17 +398,20 @@ def test_commit_spans_say_what_each_drive_was_handed(node, key):
     finally:
         spans = telemetry.SPANS.record_end()["spans"]
     commits = [sp for sp in spans if sp["name"] == "put.commit"]
-    assert [sp["attrs"]["fanouts"] for sp in commits] == [2]
-    assert not {"put.close_writers", "put.write_meta"} \
-        & {sp["name"] for sp in spans}
+    assert [sp["attrs"]["fanouts"] for sp in commits] == [1]
+    assert not {"put.close_writers", "put.write_meta", "put.stage",
+                "put.close"} & {sp["name"] for sp in spans}
     renames = [sp["attrs"] for sp in spans
                if sp["name"] == "disk.rename_data"]
     assert len(renames) == 6
     dst = "fresh" if key == "fresh" else "journal"
     assert all(a == {"src_read": 0, "dst": dst} for a in renames), renames
-    # /metrics: fan-outs / commits = 2, no staged journal read back
+    # /metrics: fan-outs / commits = 1, no fallback close, no staged
+    # journal read back
     after = [ctr.value() for ctr in counters]
-    assert [a - b for a, b in zip(after, before)] == [1, 2, 0]
+    assert [a - b for a, b in zip(after, before)] == [1, 1, 0, 0]
+    text = telemetry.REGISTRY.render()
+    assert "# TYPE minio_tpu_put_close_fanouts_total counter" in text
 
 
 def test_admin_spans_record_then_fetch(node):

@@ -281,14 +281,13 @@ def _tree(root):
 
 
 def _stage_and_commit(drive, fi, key, handed, tmp_id=None, body=b"shard"):
-    """One drive's share of a PUT commit: shard in tmp, staged journal,
-    rename_data — `handed`: the way engine._commit does it (fresh
-    staging, the FileInfo passed on); else the way multipart complete
-    and heal do (journal read back)."""
+    """One drive's share of a PUT commit: shard in tmp, then
+    rename_data — `handed`: the way engine._commit does it (shards
+    only, no staged journal, the FileInfo passed on); else the way
+    multipart complete and heal do (a staged journal, read back)."""
     tmp_id = tmp_id or str(uuid.uuid4())
     drive.write_all(TMP_VOL, f"{tmp_id}/{fi.data_dir}/part.1", body)
     if handed:
-        drive.write_metadata(TMP_VOL, tmp_id, fi, fresh=True)
         drive.rename_data(TMP_VOL, tmp_id, fi.data_dir, "b", key, fi=fi)
     else:
         drive.write_metadata(TMP_VOL, tmp_id, fi)
@@ -381,26 +380,10 @@ def test_rename_data_handed_fi_never_opens_the_staged_journal(
     assert (TMP_VOL, "stg2/xl.meta") in reads       # the spy does see
 
 
-def test_write_metadata_fresh_writes_what_write_metadata_writes(drive):
-    drive.make_vol_bulk(TMP_VOL)
-    fi = _sample_fi(mod_time=AWKWARD_MTIME, n_parts=2)
-    os.makedirs(os.path.join(drive.root, TMP_VOL, "made"))
-    drive.write_metadata(TMP_VOL, "made", fi, fresh=True)
-    drive.write_metadata(TMP_VOL, "plain", fi)
-    # no writer has made this staging directory (a 0-byte object
-    # through a writer that makes none): the write makes it
-    drive.write_metadata(TMP_VOL, "unmade/deeper", fi, fresh=True)
-    want = drive.read_all(TMP_VOL, "plain/xl.meta")
-    assert drive.read_all(TMP_VOL, "made/xl.meta") == want
-    assert drive.read_all(TMP_VOL, "unmade/deeper/xl.meta") == want
-    # written in place: no temp sibling was renamed over it or left
-    assert drive.list_dir(TMP_VOL, "made") == ["xl.meta"]
-
-
 @pytest.mark.parametrize("stray", [False, True])
 def test_rename_data_drops_the_staging_directory(drive, stray):
-    """An unlink and an rmdir where the staging directory holds its
-    journal and no more; the recursive delete where it holds more."""
+    """An rmdir where the staging directory, its data dir renamed away,
+    is empty; the recursive delete where it holds more."""
     drive.make_vol_bulk(TMP_VOL, "b")
     fi = _sample_fi()
     if stray:
@@ -426,6 +409,35 @@ def test_rename_data_replayed_leaves_the_committed_data_dir(drive):
         assert drive.read_version("b", "obj").data_dir == fi.data_dir
 
 
+@pytest.mark.parametrize("handed", [True, False])
+def test_rename_data_over_a_torn_journal_commits_a_whole_one(drive, handed):
+    """A destination xl.meta torn by a crash inside its write is
+    dropped, and the commit writes a whole journal of the one version
+    (what heal needs to bring the drive back)."""
+    drive.make_vol_bulk(TMP_VOL, "b")
+    _stage_and_commit(drive, _sample_fi(mod_time=1000.25), "obj",
+                      handed=True)
+    fp = os.path.join(drive.root, "b", "obj", "xl.meta")
+    with open(fp, "rb") as f:
+        buf = f.read()
+    with open(fp, "wb") as f:
+        f.write(buf[:len(buf) // 3])
+    with pytest.raises(errors.FileCorrupt):
+        drive.read_version("b", "obj")
+    second = _sample_fi(mod_time=AWKWARD_MTIME)
+    telemetry.SPANS.record_begin()
+    try:
+        with telemetry.trace("t"):
+            _stage_and_commit(drive, second, "obj", handed)
+    finally:
+        spans = telemetry.SPANS.record_end()["spans"]
+    assert [sp["attrs"]["dst"] for sp in spans
+            if sp["name"] == "disk.rename_data"] == ["corrupt"]
+    fis = drive.read_versions("b", "obj")
+    assert [f.data_dir for f in fis] == [second.data_dir]
+    assert drive.list_dir(TMP_VOL, "") == []
+
+
 def test_rename_data_into_a_missing_volume_is_volume_not_found(drive):
     drive.make_vol_bulk(TMP_VOL)
     fi = _sample_fi()
@@ -436,16 +448,16 @@ def test_rename_data_into_a_missing_volume_is_volume_not_found(drive):
 
 
 @pytest.mark.parametrize("wrapper", ["naughty", "diskid"])
-def test_wrappers_pass_fresh_and_fi_through(drive, wrapper):
+def test_wrappers_pass_fi_through(drive, wrapper):
     from minio_tpu.storage.diskid_check import DiskIDCheck
     from minio_tpu.storage.naughty import NaughtyDisk
     drive.make_vol_bulk(TMP_VOL, "b")
-    seen = {}
+    seen = {"wm": 0}
     real_wm, real_rd = drive.write_metadata, drive.rename_data
 
-    def write_metadata(volume, path, fi, fresh=False):
-        seen["fresh"] = fresh
-        return real_wm(volume, path, fi, fresh)
+    def write_metadata(volume, path, fi):
+        seen["wm"] += 1
+        return real_wm(volume, path, fi)
 
     def rename_data(sv, sp, dd, dv, dp, version_id="", fi=None):
         seen["fi"] = fi
@@ -456,9 +468,9 @@ def test_wrappers_pass_fresh_and_fi_through(drive, wrapper):
         else DiskIDCheck(drive, drive.get_disk_id())
     fi = _sample_fi()
     _stage_and_commit(w, fi, "obj", handed=True)
-    assert seen == {"fresh": True, "fi": fi}
+    assert seen == {"wm": 0, "fi": fi}
     _stage_and_commit(w, _sample_fi(mod_time=2000.0), "obj", handed=False)
-    assert seen == {"fresh": False, "fi": None}
+    assert seen == {"wm": 1, "fi": None}
     assert w.read_version("b", "obj").mod_time == 2000.0
 
 

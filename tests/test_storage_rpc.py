@@ -6,6 +6,8 @@ from __future__ import annotations
 
 import hashlib
 import io
+import json
+import uuid
 
 import pytest
 
@@ -104,26 +106,7 @@ def test_metadata_verbs(cluster):
         r.read_version("v", "obj")
 
 
-def test_commit_verbs_round_trip_fresh_and_fi(cluster):
-    """write_metadata's `fresh` and rename_data's `fi` cross the wire:
-    the remote drive runs the drive code it was handed them for, and a
-    call without them runs the read-back as before."""
-    locals_, remotes = cluster
-    r, local = remotes[3], locals_["/d3"]
-    r.make_vol("v")
-    local.make_vol_bulk(".minio.sys/tmp")
-    seen = []
-    real_wm, real_rd = local.write_metadata, local.rename_data
-
-    def write_metadata(volume, path, fi, fresh=False):
-        seen.append(("wm", fresh))
-        return real_wm(volume, path, fi, fresh)
-
-    def rename_data(sv, sp, dd, dv, dp, version_id="", fi=None):
-        seen.append(("rd", fi))
-        return real_rd(sv, sp, dd, dv, dp, version_id, fi)
-
-    local.write_metadata, local.rename_data = write_metadata, rename_data
+def _commit_fi():
     fi = new_file_info("v/obj", 4, 2)
     fi.volume, fi.name, fi.size = "v", "obj", 5
     fi.mod_time = 1234567890.5
@@ -132,12 +115,35 @@ def test_commit_verbs_round_trip_fresh_and_fi(cluster):
     fi.add_object_part(1, "deadbeef", 5, 5)
     fi.erasure.index = 4
     fi.erasure.checksums = [ChecksumInfo(1, "highwayhash256S", b"")]
+    return fi
+
+
+def test_commit_verbs_round_trip_fi(cluster):
+    """rename_data's `fi` crosses the wire: the remote drive commits a
+    staging directory that holds the shards alone, as a PUT stages it,
+    and a call without it reads the staged journal back as before."""
+    locals_, remotes = cluster
+    r, local = remotes[3], locals_["/d3"]
+    r.make_vol("v")
+    local.make_vol_bulk(".minio.sys/tmp")
+    seen = []
+    real_wm, real_rd = local.write_metadata, local.rename_data
+
+    def write_metadata(volume, path, fi):
+        seen.append(("wm",))
+        return real_wm(volume, path, fi)
+
+    def rename_data(sv, sp, dd, dv, dp, version_id="", fi=None):
+        seen.append(("rd", fi))
+        return real_rd(sv, sp, dd, dv, dp, version_id, fi)
+
+    local.write_metadata, local.rename_data = write_metadata, rename_data
+    fi = _commit_fi()
     tmp = ".minio.sys/tmp"
     r.append_file(tmp, f"stg/{fi.data_dir}/part.1", b"shard")
-    r.write_metadata(tmp, "stg", fi, fresh=True)
     r.rename_data(tmp, "stg", fi.data_dir, "v", "obj", fi=fi)
-    assert seen[0] == ("wm", True)
-    assert seen[1][0] == "rd" and fi_to_dict(seen[1][1]) == fi_to_dict(fi)
+    assert len(seen) == 1 and seen[0][0] == "rd"
+    assert fi_to_dict(seen[0][1]) == fi_to_dict(fi)
     got = r.read_version("v", "obj")
     assert got.erasure.index == 4 and got.data_dir == fi.data_dir
     assert r.read_all("v", f"obj/{fi.data_dir}/part.1") == b"shard"
@@ -149,8 +155,28 @@ def test_commit_verbs_round_trip_fresh_and_fi(cluster):
     r.append_file(tmp, f"stg/{fi.data_dir}/part.1", b"shard")
     r.write_metadata(tmp, "stg", fi)
     r.rename_data(tmp, "stg", fi.data_dir, "v", "obj")
-    assert seen == [("wm", False), ("rd", None)]
+    assert seen == [("wm",), ("rd", None)]
     assert local.read_all("v", "obj/xl.meta") == committed
+
+
+def test_writemetadata_from_a_peer_that_sends_fresh_merges(cluster):
+    """A peer that still sends writemetadata's old `fresh` argument is
+    heard as any other: the journal already there is merged, not
+    replaced."""
+    locals_, remotes = cluster
+    r, local = remotes[3], locals_["/d3"]
+    local.make_vol_bulk(".minio.sys/tmp")
+    tmp = ".minio.sys/tmp"
+    first, second = _commit_fi(), _commit_fi()
+    first.version_id, second.version_id = str(uuid.uuid4()), \
+        str(uuid.uuid4())
+    second.mod_time += 1
+    r.write_metadata(tmp, "stg", first)
+    r._call("writemetadata", {"volume": tmp, "path": "stg",
+                              "fresh": "true"},
+            json.dumps(fi_to_dict(second)).encode())
+    assert {v.version_id for v in local.read_versions(tmp, "stg")} \
+        == {first.version_id, second.version_id}
 
 
 def test_fi_codec_roundtrip():
