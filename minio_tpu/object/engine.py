@@ -98,16 +98,11 @@ class GetOptions:
         self.version_id = version_id
 
 
-# fan-outs / commits says how many quorum fan-outs over the drive-io
-# pool one PUT's commit costs (1: rename); 1 - close fan-outs / commits
-# is the share of PUTs whose writers closed in their last shard write
+# 1 - close fan-outs / commits is the share of PUTs whose writers
+# closed in their last shard write
 _PUT_COMMITS = telemetry.REGISTRY.counter(
     "minio_tpu_put_commits_total",
     "Single-part PUT commits begun (shards written, under the lock)")
-_PUT_COMMIT_FANOUTS = telemetry.REGISTRY.counter(
-    "minio_tpu_put_commit_fanouts_total",
-    "Quorum fan-outs over the drive set issued by single-part PUT "
-    "commits (rename, and the fallback close)")
 _PUT_CLOSE_FANOUTS = telemetry.REGISTRY.counter(
     "minio_tpu_put_close_fanouts_total",
     "Single-part PUT commits that closed their shard writers in a "
@@ -229,7 +224,7 @@ class ErasureObjects:
 
     def get_bucket_info(self, bucket: str):
         results, errs = meta.for_each_disk(
-            self.disks, lambda i, d: d.stat_vol(bucket))
+            self.disks, lambda i, d: d.stat_vol(bucket), stage="stat_vol")
         read_quorum = len(self.disks) // 2
         err = meta.reduce_read_quorum_errs(
             errs, meta.OBJECT_OP_IGNORED_ERRS, read_quorum)
@@ -976,7 +971,6 @@ class ErasureObjects:
                 _, errs = meta.for_each_disk_quorum(
                     disks, fn, write_quorum, stall_s=stall, stage=phase,
                     **kw)
-            _PUT_COMMIT_FANOUTS.inc()
             if commit_span is not None:
                 commit_span.attrs["fanouts"] = \
                     commit_span.attrs.get("fanouts", 0) + 1
@@ -1504,8 +1498,9 @@ class ErasureObjects:
                 in staged:
             if fut is not None:
                 try:
-                    # check: allow(deadline) device dispatch; scheduler close() flushes waiters
-                    fused = fut.result()
+                    with telemetry.span("get.decode_wait"):
+                        # check: allow(deadline) device dispatch; scheduler close() flushes waiters
+                        fused = fut.result()
                 except Exception:  # noqa: BLE001 — a shared-dispatch
                     # failure must not kill a GET the host can still
                     # serve: fall back to the local decode + step-2
@@ -1554,16 +1549,21 @@ class ErasureObjects:
             for i in range(n):
                 if digests[i] is not None and shards[i] is not None:
                     pend.setdefault(len(shards[i]), []).append((gi, i))
-        for _sl, items in pend.items():
-            stacked = np.stack([group[gi][4][i] for gi, i in items])
-            got = bitrot_mod.hash_shards_batch(stacked, algo)
-            for row, (gi, i) in enumerate(items):
-                if got[row].tobytes() != group[gi][5][i]:
-                    group[gi][4][i] = None
-                    drop_reader(i)
-                    corrupt.add(gi)
-                else:
-                    group[gi][5][i] = None
+        if pend:
+            with telemetry.span(
+                    "get.host_verify",
+                    shards=sum(len(it) for it in pend.values()),
+                    bytes=sum(sl * len(it) for sl, it in pend.items())):
+                for _sl, items in pend.items():
+                    stacked = np.stack([group[gi][4][i] for gi, i in items])
+                    got = bitrot_mod.hash_shards_batch(stacked, algo)
+                    for row, (gi, i) in enumerate(items):
+                        if got[row].tobytes() != group[gi][5][i]:
+                            group[gi][4][i] = None
+                            drop_reader(i)
+                            corrupt.add(gi)
+                        else:
+                            group[gi][5][i] = None
 
         # 3) corrupt blocks (bitrot found after deferral): re-read with
         #    inline verification and host reconstruct — the corrupt
@@ -1641,7 +1641,8 @@ class ErasureObjects:
         inflight: dict = {}
 
         def launch(i: int) -> None:
-            inflight[meta.submit_disk_task(read_one, i, readers[i])] = i
+            inflight[meta.submit_disk_task(read_one, i, readers[i],
+                                           stage="shard_read")] = i
 
         for i in candidates[:k]:
             launch(i)
@@ -2352,11 +2353,7 @@ class _PartReadPlan:
         """Queue one group's reads on the prefetch pool, carrying the
         caller's span context so the reads attach to the request tree."""
         from ..parallel import pipeline as pl
-        cctx = telemetry.propagating_context()
-        if cctx is not None:
-            return pl.PREFETCH_POOL.submit(cctx.run, self.read_group,
-                                           *spec)
-        return pl.PREFETCH_POOL.submit(self.read_group, *spec)
+        return pl.prefetch(self.read_group, *spec)
 
     def prime(self) -> None:
         """Issue this part's FIRST group read on the prefetch pool —
@@ -2380,7 +2377,7 @@ class _PartReadPlan:
             with telemetry.span("get.read_shards"):
                 lookahead = self._pending
                 self._pending = None
-                if lookahead is not None and lookahead.cancel():
+                if lookahead is not None and pl.cancel_prefetch(lookahead):
                     # still queued behind other streams' prefetch
                     # tasks: reading inline is strictly faster than
                     # waiting for a task that hasn't started
@@ -2449,7 +2446,9 @@ class _PartReadPlan:
         """Settle any in-flight lookahead, then close the readers (an
         abandoned generator must not leave a pool thread racing closed
         streams)."""
-        if self._pending is not None and not self._pending.cancel():
+        from ..parallel import pipeline as pl
+        if self._pending is not None \
+                and not pl.cancel_prefetch(self._pending):
             try:
                 # check: allow(deadline) task body IS the hedged reader
                 self._pending.result()

@@ -13,12 +13,12 @@ writeQuorum = dataBlocks (+1 when data == parity)
 
 from __future__ import annotations
 
-from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Optional, Sequence
 
 from ..storage import errors as serr
 from ..storage.api import StorageAPI
 from ..storage.datatypes import FileInfo
+from ..utils import telemetry
 from . import api_errors
 
 # Per-drive errors ignored during object ops (reference objectOpIgnoredErrs:
@@ -73,39 +73,45 @@ def reduce_write_quorum_errs(errs, ignored, write_quorum: int
 # Parallel per-drive fan-out (the reference's errgroup-per-disk pattern)
 # ---------------------------------------------------------------------------
 
-_POOL = ThreadPoolExecutor(max_workers=64, thread_name_prefix="drive-io")
+# every task goes through `submit_disk_task`, which reads the pool at
+# call time (tests swap it) and records each task's wait for a thread
+_POOL = telemetry.host_pool("drive_pool", 64, "drive-io")
+
+
+def submit_disk_task(fn, *args, stage: str = ""):
+    """One task on the shared drive-io pool, carrying the caller's
+    span context and recording its wait for a thread under `stage`
+    (`telemetry.submit`) — the fan-outs below, and the hedged-read
+    state machine, which launches per-reader tasks through this so it
+    can wait on them with a deadline instead of joining a whole
+    fan-out."""
+    return telemetry.submit(_POOL, "drive_pool", fn, *args, stage=stage)
 
 
 def for_each_disk(disks: Sequence[Optional[StorageAPI]],
-                  fn: Callable[[int, StorageAPI], object]
+                  fn: Callable[[int, StorageAPI], object],
+                  stage: str = ""
                   ) -> tuple[list, list[Optional[Exception]]]:
     """Run fn(index, disk) on every non-None drive concurrently.
 
     Returns (results, errors) — per index; a None disk yields
-    DiskNotFound (same shape as the reference's errgroup pattern)."""
+    DiskNotFound (same shape as the reference's errgroup pattern).
+    `stage` names the fan-out in the drive pool's wait records."""
     results: list = [None] * len(disks)
     errs: list[Optional[Exception]] = [None] * len(disks)
 
-    def run(i: int):
-        d = disks[i]
-        if d is None:
-            errs[i] = serr.DiskNotFound(f"drive {i}")
-            return
+    def run(i: int, d: StorageAPI):
         try:
             results[i] = fn(i, d)
         except Exception as e:  # noqa: BLE001 — per-drive fault isolation
             errs[i] = e
 
-    from ..utils import telemetry
-    if telemetry.current_span() is not None:
-        # carry the caller's span into the pool workers so per-drive
-        # I/O attaches to the request tree; one Context copy per task
-        # (a Context must never run in two threads at once)
-        import contextvars
-        futures = [_POOL.submit(contextvars.copy_context().run, run, i)
-                   for i in range(len(disks))]
-    else:
-        futures = [_POOL.submit(run, i) for i in range(len(disks))]
+    futures = []
+    for i, d in enumerate(disks):
+        if d is None:
+            errs[i] = serr.DiskNotFound(f"drive {i}")
+        else:
+            futures.append(submit_disk_task(run, i, d, stage=stage))
     for f in futures:
         # each task is one drive verb, bounded by the drive/RPC
         # deadline; fan-outs that must not wait for stragglers ride
@@ -113,18 +119,6 @@ def for_each_disk(disks: Sequence[Optional[StorageAPI]],
         # check: allow(deadline) per-drive verb bounded by drive/RPC deadline
         f.result()
     return results, errs
-
-
-def submit_disk_task(fn, *args):
-    """One task on the shared drive-io pool, carrying the caller's
-    span context (the for_each_disk discipline) — the hedged-read
-    state machine launches per-reader tasks through this so it can
-    wait on them with a deadline instead of joining a whole fan-out."""
-    from ..utils import telemetry
-    if telemetry.current_span() is not None:
-        import contextvars
-        return _POOL.submit(contextvars.copy_context().run, fn, *args)
-    return _POOL.submit(fn, *args)
 
 
 def for_each_disk_quorum(disks: Sequence[Optional[StorageAPI]],
@@ -158,11 +152,11 @@ def for_each_disk_quorum(disks: Sequence[Optional[StorageAPI]],
 
     stall_s=None (quorum-ack off) degrades to exactly for_each_disk."""
     if stall_s is None:
-        return for_each_disk(disks, fn)
+        return for_each_disk(disks, fn, stage=stage)
     import time as _time
     from concurrent.futures import FIRST_COMPLETED
     from concurrent.futures import wait as _fwait
-    from ..utils import healthtrack, knobs, telemetry
+    from ..utils import healthtrack, knobs
 
     results: list = [None] * len(disks)
     errs: list[Optional[Exception]] = [None] * len(disks)
@@ -171,9 +165,6 @@ def for_each_disk_quorum(disks: Sequence[Optional[StorageAPI]],
     ran: list[Optional[float]] = [None] * len(disks)
     k_peers = knobs.get_float("MINIO_TPU_WRITE_STALL_K")
     futs: dict = {}
-    traced = telemetry.current_span() is not None
-    if traced:
-        import contextvars
     for i in range(len(disks)):
         if disks[i] is None:
             errs[i] = serr.DiskNotFound(f"drive {i}")
@@ -187,9 +178,7 @@ def for_each_disk_quorum(disks: Sequence[Optional[StorageAPI]],
             finally:
                 ran[i] = _time.monotonic() - started[i]
 
-        fut = _POOL.submit(contextvars.copy_context().run, run) \
-            if traced else _POOL.submit(run)
-        futs[fut] = i
+        futs[submit_disk_task(run, stage=stage)] = i
     while futs:
         ok = sum(1 for i in range(len(disks))
                  if settled[i] and errs[i] is None)
@@ -240,7 +229,8 @@ def read_all_file_info(disks: Sequence[Optional[StorageAPI]], bucket: str,
     """Read xl.meta from every drive (reference readAllFileInfo,
     cmd/erasure-metadata-utils.go:118)."""
     results, errs = for_each_disk(
-        disks, lambda i, d: d.read_version(bucket, object_path, version_id))
+        disks, lambda i, d: d.read_version(bucket, object_path, version_id),
+        stage="read_version")
     return results, errs
 
 

@@ -35,7 +35,6 @@ import contextvars
 import os
 import queue
 import threading
-from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Optional, Sequence
 
 from ..utils import knobs, telemetry
@@ -74,9 +73,21 @@ def configure_pool_buffers(requests_budget: int) -> int:
 # with the host's concurrency (the tasks are I/O-bound waiters); when a
 # lookahead is still queued behind other streams at collection time the
 # GET cancels it and reads inline, so prefetch stays a strict win.
-PREFETCH_POOL = ThreadPoolExecutor(
-    max_workers=max(16, 4 * (os.cpu_count() or 4)),
-    thread_name_prefix="get-prefetch")
+PREFETCH_POOL = telemetry.host_pool(
+    "prefetch_pool", max(16, 4 * (os.cpu_count() or 4)), "get-prefetch")
+
+
+def prefetch(fn, *args):
+    """One lookahead task on PREFETCH_POOL, its wait for a thread
+    recorded (`telemetry.submit`)."""
+    return telemetry.submit(PREFETCH_POOL, "prefetch_pool", fn, *args,
+                            stage="lookahead")
+
+
+def cancel_prefetch(fut) -> bool:
+    """Take back a lookahead that has not started; True when it had
+    not."""
+    return telemetry.cancel(fut, "prefetch_pool")
 
 _EOT = object()          # end-of-stream sentinel on the stage queues
 

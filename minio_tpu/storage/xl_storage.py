@@ -33,13 +33,6 @@ from .datatypes import DiskInfo, FileInfo, VolInfo
 from .format import FORMAT_CONFIG_FILE, MINIO_META_BUCKET, FormatErasureV3
 from .xl_meta import XLMetaV2
 
-# reads / rename_data calls says how many commits were handed their
-# version: 0 for single-part PUTs, 1 for multipart complete and heal
-_SRC_READS = telemetry.REGISTRY.counter(
-    "minio_tpu_rename_data_src_reads_total",
-    "rename_data calls that read the staged xl.meta back from the "
-    "drive (the caller handed no FileInfo)")
-
 XL_STORAGE_FORMAT_FILE = "xl.meta"
 XL_LEGACY_FORMAT_FILE = "xl.json"   # format v1 (migrated on access)
 MINIO_META_TMP_BUCKET = MINIO_META_BUCKET + "/tmp"
@@ -829,7 +822,6 @@ class XLStorage(StorageAPI):
                      fi: Optional[FileInfo] = None) -> str:
         staged = fi is not None
         if not staged:
-            _SRC_READS.inc()
             # the staged multipart session meta holds the session
             # placeholder AND the final version — "latest by mod time"
             # is wrong for version-faithful replays (preserved mod

@@ -295,12 +295,13 @@ PUT_SPANS = {"s3.auth", "s3.body_hash", "s3.respond", "engine.put_object",
              "sched.collect", "sched.slot", "sched.transfer", "sched.h2d",
              "sched.compute", "sched.fetch", "pipeline.shard_write",
              "disk.shard_write", "put.commit", "put.rename",
-             "disk.rename_data"}
+             "disk.rename_data", "drive_pool.wait"}
 GET_SPANS = {"s3.auth", "s3.respond", "get.open", "engine.get_object",
              "get.read_shards", "disk.shard_read",
              "pipeline.verify_decode", "sched.dispatch", "sched.queue",
              "sched.collect", "sched.slot", "sched.transfer", "sched.h2d",
-             "sched.compute", "sched.fetch", "get.join"}
+             "sched.compute", "sched.fetch", "get.join", "drive_pool.wait",
+             "prefetch_pool.wait"}
 
 
 @pytest.fixture()
@@ -373,6 +374,82 @@ def test_put_and_degraded_get_give_one_whole_tree_each(node, tmp_path):
     assert "cpu_ns" in put                           # recorded: thread CPU
 
 
+def _split_verify_decode(spans: list) -> list:
+    """Each `pipeline.verify_decode` of the window with the names of its
+    `get.decode_wait` / `get.host_verify` children, after checking they
+    lie inside it and do not overlap."""
+    kids = spanview.children_of(spans)
+    out = []
+    for vd in (sp for sp in spans if sp["name"] == "pipeline.verify_decode"):
+        parts = sorted((c for c in kids.get(vd["span_id"], ())
+                        if c["name"] in ("get.decode_wait",
+                                         "get.host_verify")),
+                       key=lambda c: c["t0_ns"])
+        for c in parts:
+            assert vd["t0_ns"] <= c["t0_ns"] <= c["t1_ns"] <= vd["t1_ns"]
+        for a, b in zip(parts, parts[1:]):
+            assert a["t1_ns"] <= b["t0_ns"], (a, b)
+        for c in parts:
+            if c["name"] == "get.host_verify":
+                assert c["attrs"]["shards"] > 0
+                assert c["attrs"]["bytes"] >= c["attrs"]["shards"]
+        out.append([c["name"] for c in parts])
+    return out
+
+
+@pytest.mark.parametrize("decode", ["device", "fails"])
+def test_degraded_get_splits_its_verify_decode(node, monkeypatch, decode):
+    """A degraded GET's verify+decode names its two waits: the stream's
+    wait on the former's future (`get.decode_wait`) and the host's batch
+    verify of the shards no launch covered (`get.host_verify`). An
+    object that lost a data shard waits on a decode; one that lost
+    parity only verifies on the host; when the shared dispatch fails,
+    the group falls back to the host and one verify_decode holds
+    both."""
+    from concurrent.futures import Future
+    from tests.test_telemetry import Client
+    from minio_tpu.object import metadata as meta
+    c = Client(node.s3.port, CREDS)
+    assert c.request("PUT", "/vdb")[0] == 200
+    body = os.urandom(9 * (1 << 16) + 5)
+    eng = node.sets.sets[0]
+    lost: dict = {}                  # what drive 0 holds: "data" / "parity"
+    for i in range(16):
+        assert c.request("PUT", f"/vdb/o{i}", body=body)[0] == 200
+        fi = next(f for f in meta.read_all_file_info(
+            eng.disks, "vdb", f"o{i}")[0] if f is not None)
+        kind = "data" if fi.erasure.distribution[0] <= 4 else "parity"
+        lost.setdefault(kind, f"/vdb/o{i}")
+    keys = [lost["data"]] if decode == "fails" \
+        else [lost["data"], lost["parity"]]
+    path = getattr(eng.disks[0], "inner", eng.disks[0]).root
+    eng.disks[0] = None
+    shutil.rmtree(path)
+    open(path, "w").close()
+    for key in keys:                            # the decode programs
+        assert c.request("GET", key)[1] == body
+    if decode == "fails":
+        def failed(*_a, **_kw):
+            fut = Future()
+            fut.set_exception(RuntimeError("dispatch lost"))
+            return fut
+        monkeypatch.setattr(eng.scheduler, "submit_decode", failed)
+    telemetry.SPANS.record_begin()
+    try:
+        for key in keys:
+            assert c.request("GET", key)[1] == body
+        assert c.request("GET", "/vdb")[0] == 200     # the last root is in
+    finally:
+        win = telemetry.SPANS.record_end()
+    parts = _split_verify_decode(win["spans"])
+    assert parts
+    if decode == "device":
+        assert ["get.decode_wait"] in parts         # lost a data shard
+        assert ["get.host_verify"] in parts         # lost parity only
+    else:
+        assert ["get.decode_wait", "get.host_verify"] in parts
+
+
 @pytest.mark.parametrize("key", ["fresh", "overwrite"])
 def test_commit_spans_say_what_each_drive_was_handed(node, key):
     """put.commit counts its quorum fan-outs (one: rename); every
@@ -385,9 +462,7 @@ def test_commit_spans_say_what_each_drive_was_handed(node, key):
         assert c.request("PUT", "/cmtb/k", body=b"a" * 3000)[0] == 200
     counters = [telemetry.REGISTRY.counter(name, "") for name in (
         "minio_tpu_put_commits_total",
-        "minio_tpu_put_commit_fanouts_total",
-        "minio_tpu_put_close_fanouts_total",
-        "minio_tpu_rename_data_src_reads_total")]
+        "minio_tpu_put_close_fanouts_total")]
     before = [ctr.value() for ctr in counters]
     telemetry.SPANS.record_begin()
     try:
@@ -406,10 +481,9 @@ def test_commit_spans_say_what_each_drive_was_handed(node, key):
     assert len(renames) == 6
     dst = "fresh" if key == "fresh" else "journal"
     assert all(a == {"src_read": 0, "dst": dst} for a in renames), renames
-    # /metrics: fan-outs / commits = 1, no fallback close, no staged
-    # journal read back
+    # /metrics: one commit, no fallback close
     after = [ctr.value() for ctr in counters]
-    assert [a - b for a, b in zip(after, before)] == [1, 1, 0, 0]
+    assert [a - b for a, b in zip(after, before)] == [1, 0]
     text = telemetry.REGISTRY.render()
     assert "# TYPE minio_tpu_put_close_fanouts_total counter" in text
 
