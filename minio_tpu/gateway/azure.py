@@ -156,6 +156,7 @@ class AzureGatewayObjects:
     def put_object(self, bucket: str, key: str, reader, size: int = -1,
                    opts: Optional[PutOptions] = None) -> ObjectInfo:
         opts = opts or PutOptions()
+        self.get_bucket_info(bucket)    # the S3 handler does not check
         if not isinstance(reader, (bytes, bytearray)) and \
                 (size < 0 or size > self.STREAM_THRESHOLD):
             return self._put_object_streamed(bucket, key, reader, size,

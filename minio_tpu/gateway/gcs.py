@@ -432,6 +432,7 @@ class GCSJsonGatewayObjects:
     def put_object(self, bucket: str, key: str, reader, size: int = -1,
                    opts: Optional[PutOptions] = None) -> ObjectInfo:
         opts = opts or PutOptions()
+        self.get_bucket_info(bucket)    # the S3 handler does not check
         if isinstance(reader, (bytes, bytearray)):
             body = bytes(reader)
         else:

@@ -290,6 +290,12 @@ class ErasureObjects:
     def put_object(self, bucket: str, object_name: str, reader,
                    size: int = -1, opts: Optional[PutOptions] = None
                    ) -> ObjectInfo:
+        """Write one object at write quorum. Raises `BucketNotFound`
+        when the bucket is missing: nothing stats it first — each
+        drive's `rename_data` refuses a volume it does not hold, and the
+        commit's rename fan-out reduces those refusals at write quorum
+        (the body has then been read and encoded, and the staging
+        directories are removed)."""
         with telemetry.span("engine.put_object", bucket=bucket,
                             object=object_name, size=size):
             return self._put_object(bucket, object_name, reader, size,

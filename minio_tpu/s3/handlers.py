@@ -46,6 +46,11 @@ MIN_PART_SIZE = 5 * (1 << 20)            # 5 MiB
 MAX_PARTS = 10000
 _BUCKET_RE = re.compile(r"^[a-z0-9][a-z0-9.\-]{1,61}[a-z0-9]$")
 
+_PUT_BUCKET_MISSING = telemetry.REGISTRY.counter(
+    "minio_tpu_put_bucket_missing_total",
+    "PUTs whose body was read and refused NoSuchBucket by the object "
+    "layer")
+
 
 @dataclasses.dataclass
 class HTTPResponse:
@@ -1307,12 +1312,16 @@ class S3ApiHandlers:
 
     def put_object(self, ctx, bucket, key) -> HTTPResponse:
         self.authenticate(ctx, "s3:PutObject", bucket, key)
-        self.obj.get_bucket_info(bucket)
         if ctx.header("x-minio-tpu-repl-spec"):
             # internal replication apply (the reference's
             # x-minio-source-* peer headers): a version-faithful write
             # carrying explicit identity — owner credential only
+            self.obj.get_bucket_info(bucket)
             return self._repl_apply(ctx, bucket, key)
+        # no bucket check before the body: the object layer answers a
+        # missing bucket itself (the erasure engine at its commit's
+        # rename fan-out), so a PUT into a bucket that is there pays no
+        # stat of its volume on every drive
         # _put_reader resolves the true payload size (including
         # x-amz-decoded-content-length for aws-chunked streams, where
         # Content-Length covers the chunk framing) — quota must gate on
@@ -1332,12 +1341,19 @@ class S3ApiHandlers:
             olock.DefaultRetention.from_config_xml(lock_cfg).apply_to(
                 metadata)
         versioned = self.bucket_meta.versioning_enabled(bucket)
-        info = self.obj.put_object(
-            bucket, key, reader, size,
-            PutOptions(metadata=metadata, versioned=versioned,
-                       parity=self._parity_for(
-                           ctx.header("x-amz-storage-class")),
-                       sse_spec=sse_spec))
+        try:
+            info = self.obj.put_object(
+                bucket, key, reader, size,
+                PutOptions(metadata=metadata, versioned=versioned,
+                           parity=self._parity_for(
+                               ctx.header("x-amz-storage-class")),
+                           sse_spec=sse_spec))
+        except oerr.BucketNotFound:
+            # the defaults bucket_meta cached above describe a bucket
+            # that is not there: one made later must be read fresh
+            _PUT_BUCKET_MISSING.inc()
+            self.bucket_meta.reload(bucket)
+            raise
         # Count the client bytes actually received: `size` is the
         # resolved payload length (decoded length for aws-chunked
         # streams), unlike Content-Length (framing included) or

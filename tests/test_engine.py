@@ -566,3 +566,73 @@ def test_object_ending_in_a_short_block_matches_the_reference(
         assert (st["short_blocks"], st["short_shard_bytes"]) \
             == (short, short * s_t)
         assert st["ragged_batches"] == short
+
+
+# ---------------------------------------------------------------------------
+# PUT into a missing bucket: the commit's rename fan-out answers
+# ---------------------------------------------------------------------------
+
+def _tmp_left(tmp_path, n=NDISKS) -> list[str]:
+    return [os.path.join(dp, x)
+            for j in range(n)
+            for dp, dn, fn in os.walk(tmp_path / f"d{j}" / ".minio.sys"
+                                      / "tmp")
+            for x in dn + fn]
+
+
+@pytest.mark.parametrize("size", [0, 100, 3 * BLOCK + 777])
+def test_put_into_missing_bucket_raises_bucket_not_found(tmp_path, size):
+    e = make_engine(tmp_path)
+    with pytest.raises(api_errors.BucketNotFound):
+        e.put_object("ghost", "k", payload(size))
+    assert _tmp_left(tmp_path) == []
+    assert not os.path.exists(tmp_path / "d0" / "ghost")
+
+
+def test_put_into_existing_bucket_stats_no_volume(tmp_path):
+    e = make_engine(tmp_path, naughty=True)
+    e.make_bucket("bucket")
+    data = payload(2 * BLOCK + 9)
+    e.put_object("bucket", "o", data)
+    assert [d.stats.calls.get("stat_vol", 0) for d in e.disks] \
+        == [0] * NDISKS
+    assert all(d.stats.calls.get("rename_data", 0) == 1 for d in e.disks)
+    assert b"".join(e.get_object("bucket", "o")[1]) == data
+
+
+@pytest.mark.parametrize("removed", [1, M])
+def test_put_commits_degraded_when_a_minority_lost_the_volume(
+        tmp_path, removed):
+    """The volume gone from fewer drives than write quorum allows: the
+    PUT commits on the rest and queues the object for MRF."""
+    import shutil
+    e = make_engine(tmp_path)
+    e.make_bucket("bucket")
+    degraded = []
+    e.on_degraded_write = lambda b, o, v: degraded.append((b, o))
+    for j in range(removed):
+        shutil.rmtree(tmp_path / f"d{j}" / "bucket")
+    data = payload(BLOCK + 5)
+    e.put_object("bucket", "o", data)
+    assert degraded == [("bucket", "o")]
+    assert b"".join(e.get_object("bucket", "o")[1]) == data
+
+
+@pytest.mark.parametrize("removed", [M + 1, K, NDISKS])
+def test_put_refused_when_too_many_drives_lost_the_volume(
+        tmp_path, removed):
+    """The volume gone from enough drives to break write quorum: the
+    PUT is refused (BucketNotFound once the missing volumes are a
+    quorum of their own) and no object can be read."""
+    import shutil
+    e = make_engine(tmp_path)
+    e.make_bucket("bucket")
+    for j in range(removed):
+        shutil.rmtree(tmp_path / f"d{j}" / "bucket")
+    want = (api_errors.BucketNotFound if removed >= K
+            else api_errors.InsufficientWriteQuorum)
+    with pytest.raises(want):
+        e.put_object("bucket", "o", payload(BLOCK + 5))
+    with pytest.raises(api_errors.ObjectApiError):
+        e.get_object_info("bucket", "o")
+    assert _tmp_left(tmp_path) == []

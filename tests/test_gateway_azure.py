@@ -435,3 +435,12 @@ def test_azure_streamed_put_constant_memory(gw, monkeypatch):
     _i, stream = gw.get_object("cont", "streamed")
     assert b"".join(stream) == payload
     assert gw.get_object_info("cont", "streamed").etag == info.etag
+
+
+@pytest.mark.parametrize("size", [0, 1000])
+def test_azure_put_into_missing_container_is_bucket_not_found(gw, size):
+    """The S3 handler does not check the bucket before a PUT: the
+    gateway answers a missing container itself, and writes nothing."""
+    with pytest.raises(api_errors.BucketNotFound):
+        gw.put_object("ghost", "k", b"z" * size)
+    assert not gw.bucket_exists("ghost")

@@ -484,3 +484,12 @@ def test_multipart_part_too_small_and_abort(gw, monkeypatch):
     assert FakeGCS.buckets["sb"] == {}
     with pytest.raises(api_errors.InvalidUploadID):
         gw.abort_multipart_upload("sb", "o", uid)
+
+
+@pytest.mark.parametrize("size", [0, 1000])
+def test_put_into_missing_bucket_is_bucket_not_found(gw, size):
+    """The S3 handler does not check the bucket before a PUT: the
+    gateway answers a missing bucket itself, and writes nothing."""
+    with pytest.raises(api_errors.BucketNotFound):
+        gw.put_object("ghost", "k", b"z" * size)
+    assert not gw.bucket_exists("ghost")

@@ -655,3 +655,103 @@ def test_put_bucket_notification_validated_by_plane(client, server):
         assert arn.encode() in body
     finally:
         server.api.notify = None
+
+
+# ---------------------------------------------------------------------------
+# PutObject into a missing bucket: the object layer answers, not a stat
+# ---------------------------------------------------------------------------
+
+def _drive_roots(server) -> list[str]:
+    return [d.root for s in server.api.obj.sets for d in s.disks]
+
+
+def _tmp_entries(server) -> list[str]:
+    """Everything left under `.minio.sys/tmp` on any drive of the node."""
+    import os
+    return [os.path.join(dp, n)
+            for root in _drive_roots(server)
+            for dp, dn, fn in os.walk(os.path.join(root, ".minio.sys",
+                                                   "tmp"))
+            for n in dn + fn]
+
+
+def _bucket_missing_total() -> float:
+    from minio_tpu.utils import telemetry
+    return telemetry.REGISTRY.counter(
+        "minio_tpu_put_bucket_missing_total", "").value()
+
+
+@pytest.mark.parametrize("size", [0, 1000, 3 * (1 << 18) + 4321],
+                         ids=["empty", "small", "multi-block"])
+def test_put_into_missing_bucket_is_no_such_bucket(server, client, size):
+    """The body is read and encoded, the commit's rename fan-out finds
+    no volume on any drive: 404 NoSuchBucket, the staging directories
+    gone, and the counter says the body was read for nothing."""
+    before = _bucket_missing_total()
+    status, _, body = client.request(
+        "PUT", f"/ghost-bucket-{size}/k", body=b"\x5a" * size)
+    assert status == 404
+    assert b"NoSuchBucket" in body
+    assert _tmp_entries(server) == []
+    assert _bucket_missing_total() - before == 1
+    status, _, _ = client.request("HEAD", f"/ghost-bucket-{size}")
+    assert status == 404
+
+
+def test_put_into_existing_bucket_stats_no_drive(client, bucket,
+                                                 monkeypatch):
+    """A PUT into a bucket that is there asks no drive whether it
+    exists: not one `stat_vol` of its volume."""
+    from minio_tpu.storage.xl_storage import XLStorage
+    stats = []
+    real = XLStorage.stat_vol
+
+    def counting(self, volume):
+        if volume == bucket:
+            stats.append(volume)
+        return real(self, volume)
+
+    monkeypatch.setattr(XLStorage, "stat_vol", counting)
+    before = _bucket_missing_total()
+    data = b"no stat before the body" * 100
+    status, headers, _ = client.request("PUT", f"/{bucket}/nostat",
+                                        body=data)
+    assert status == 200
+    assert headers["etag"].strip('"') == hashlib.md5(data).hexdigest()
+    assert stats == []
+    assert _bucket_missing_total() == before
+    status, _, got = client.request("GET", f"/{bucket}/nostat")
+    assert status == 200 and got == data
+
+
+def test_refused_put_leaves_no_stale_bucket_metadata(server, client):
+    """A refused PUT drops the defaults the handler cached for the
+    missing bucket: once the bucket is made and versioning is enabled
+    elsewhere (a peer's write, no notice to this node), the next PUT is
+    versioned."""
+    from minio_tpu.object.bucket_metadata import BucketMetadataSys
+    status, _, _ = client.request("PUT", "/late-bucket/k", body=b"one")
+    assert status == 404
+    status, _, _ = client.request("PUT", "/late-bucket")
+    assert status == 200
+    BucketMetadataSys(server.api.obj).update("late-bucket",
+                                             versioning="Enabled")
+    status, headers, _ = client.request("PUT", "/late-bucket/k",
+                                        body=b"two")
+    assert status == 200
+    assert headers.get("x-amz-version-id", "") not in ("", "null")
+
+
+def test_replication_apply_still_checks_the_bucket(client, bucket):
+    """The replication-apply branch checks the bucket before it parses
+    the spec header: a missing bucket is NoSuchBucket, an existing one
+    reaches the spec (here malformed)."""
+    hdr = {"x-minio-tpu-repl-spec": "not-a-spec"}
+    status, _, body = client.request("PUT", "/ghost-repl-bucket/k",
+                                     body=b"x", headers=hdr)
+    assert status == 404
+    assert b"NoSuchBucket" in body
+    status, _, body = client.request("PUT", f"/{bucket}/repl-k",
+                                     body=b"x", headers=hdr)
+    assert status == 400
+    assert b"InvalidArgument" in body
