@@ -28,6 +28,15 @@ from ..utils import device, eventlog, knobs, native
 # Batches at least this large go to the device (dispatch+transfer amortized).
 DEVICE_MIN_BYTES = knobs.get_int("MINIO_TPU_DEVICE_MIN_BYTES")
 
+# Objects under one block are laid at their S rung (parallel/ladder.
+# s_rungs). Up to this many columns the host encodes and hashes them
+# faster than a launch at the rung (a lone block: 0.25-6.0 ms against
+# 2.4-8.2 ms at S rungs 16384-262144 at 12+4 on a v5e; at the full S
+# 12.0 against 10.2 ms — tools/subblock_crossover.py, PERF.md §6): a
+# rung up to it goes to the host at submit, one above it to the device,
+# whatever the launch's block count.
+SUBBLOCK_HOST_MAX_S = 262144
+
 
 def _device_is_tpu() -> bool:
     """The routing predicate every device gate reads (scheduler, SSE,
@@ -62,6 +71,14 @@ def data_path_line() -> str:
     kernel = "pallas" if mesh is None else "xla matmul + all_to_all"
     return (f"data path: {dp.platform} ({dp.device_kind}) x{used}{of}, "
             f"{kernel}")
+
+
+def subblock_on_device(s: int) -> bool:
+    """Whether a launch of objects under one block at S rung `s`
+    (parallel/ladder.s_rungs) is the device's: the measured crossover,
+    a rung at a time — never the launch's block count, which is how
+    many happened to coalesce in the grace window."""
+    return s > SUBBLOCK_HOST_MAX_S
 
 
 class _Fused(NamedTuple):
@@ -391,7 +408,7 @@ class Codec:
     # whose keyword arguments (force, stage_cb, blocks) they pass on.
 
     def encode_and_hash_batch(self, data: np.ndarray, algo, lengths=None,
-                              **launch):
+                              subblock: bool = False, **launch):
         """Fused device path for the PUT hot loop: one program computes
         parity AND every shard's HighwayHash256 digest (the reference's
         Erasure.Encode + streaming-bitrot work, cmd/erasure-encode.go:75 +
@@ -411,42 +428,66 @@ class Codec:
         had not been given; one with a short block runs the ragged row
         at the same rung, is routed by its real bytes, and — when the
         bitrot algorithm has no ragged kernel — goes to the host whole.
+
+        subblock: every block of the launch is an object under one
+        block, laid at its S rung (`data.shape[2]`, parallel/ladder.
+        s_rungs): the launch runs the ragged row at that S, on that
+        rung's B ladder, and is routed by the rung
+        (`subblock_on_device`), not by its bytes.
         """
         if self.m == 0:
             return None
         if lengths is not None:
             from ..parallel import ladder
-            n, to = ladder.launch_size("encode", data.shape[0],
-                                       launch.get("blocks"))
+            n, to = ladder.launch_size(
+                "encode", data.shape[0], launch.get("blocks"),
+                subblock and data.shape[2] < self.shard_size)
             lengths = np.asarray(lengths, np.int32)[:n]
-            if (lengths < data.shape[2]).any():
+            if subblock or (lengths < data.shape[2]).any():
                 return self._encode_ragged(data, algo, lengths, to,
-                                           **launch)
+                                           subblock, **launch)
         return self._launch(FUSED["encode_and_hash_batch"], data, (), (),
                             algo, **launch)
 
     def _encode_ragged(self, data: np.ndarray, algo, lengths: np.ndarray,
-                       to: int, **launch):
+                       to: int, subblock: bool = False, **launch):
         """`encode_and_hash_batch` for a launch with a short block:
-        `lengths` of its real blocks, `to` the rung it runs at."""
+        `lengths` of its real blocks, `to` the rung it runs at;
+        `subblock`: of objects under one block, at their S rung."""
         from ..parallel import ladder
         if self._device_hash_kernel(algo) != "highwayhash":
             eventlog.emit_once("device.decline", stage="encode",
                                reason="no-ragged-kernel")
             return None
+        s = data.shape[2]
         # pad blocks at the full length, as the static program has them
-        at_rung = np.full(to, data.shape[2], np.int32)
+        at_rung = np.full(to, s, np.int32)
         at_rung[:len(lengths)] = lengths
         real = int(lengths.sum()) * self.k
-        if not launch.get("force") and self._route(real) == "device":
+        force = launch.pop("force", "")
+        if subblock:
+            route = "device" if _device_is_tpu() \
+                and subblock_on_device(s) else "host"
+        else:
+            route = self._route(real)
+        if not force:
+            if route != "device":
+                return None
             # the first launch with a short block that the device takes
-            # starts the load of the row's rungs (a node's own small
-            # objects are short blocks too, and stay on the host: boot
-            # loads none of this), and every launch waits for its own
-            ladder.load_encode_ragged(self, algo, want=to)
-            ladder.await_ragged(self, algo, to)
+            # at an S starts the load of the row's rungs there (a node's
+            # own small objects are short blocks too, and stay on the
+            # host: boot loads none of this), and every launch waits for
+            # its own
+            ladder.load_encode_ragged(self, algo, want=to, s=s)
+            ladder.await_ragged(self, algo, to, s=s)
+        if launch.get("blocks") is None:
+            # the direct route: brought up to the rung here, so that a
+            # launch at an S rung pads on its own ladder
+            data = ladder.pad_blocks(data, to)
+            launch["blocks"] = len(lengths)
         return self._launch(FUSED["encode_and_hash_batch.ragged"], data,
-                            (at_rung,), (), algo, nbytes=real, **launch)
+                            (at_rung,), (), algo, force=force or "device",
+                            nbytes=real, **launch)
 
     def encrypt_encode_and_hash_batch(self, data: np.ndarray, keys,
                                       nonces, pkg_bytes: int, algo,

@@ -572,6 +572,7 @@ class ErasureObjects:
         enc_off = 0       # plaintext stream offset of the next sse batch
         tail_pt = b""     # short last block (plaintext) under sse
         lengths = None    # of the last group, when it ends short
+        sub = None        # the one block of a body under one block
         # pulling the body through the hash reader into the ring: one
         # span per group of blocks (busy time + call count), not one
         # per block
@@ -609,17 +610,26 @@ class ErasureObjects:
                         # after them in stage FIFO order
                         tail_pt = bytes(arr[nb][:n])
                         break
+                    if total == n:
+                        # the whole body is under one block: one block
+                        # at its S rung, encoded and written below
+                        sub, lengths = _lay_subblock(arr[0], n, k, s_len)
+                        break
                     # short last block: it is the last row of the
                     # group of the whole blocks before it, and goes
                     # with them below
                     lengths = _lay_short_block(arr, nb, n, k, s_len)
                     nb += 1
                     break
-            reads.flush(blocks=nb)
+            reads.flush(blocks=nb or int(sub is not None))
             # the read loop has ended (EOF, or a short block): without
             # SSE the group below is the stream's last; under SSE the
             # last of the finish batches is
-            if nb:
+            if sub is not None:
+                self._encode_write(codec, sub, writers, write_quorum,
+                                   lengths=lengths, last=True,
+                                   subblock=True)
+            elif nb:
                 if pipe is None:
                     # a stream that fit one batch (an unknown-length
                     # one too): encode+write inline, no stage threads
@@ -715,6 +725,15 @@ class ErasureObjects:
                     # in the finish batches (after flush_full below)
                     tail_pt = bytes(row[:n])
                     break
+                if total == n:
+                    # the whole body is under one block: ONE block at
+                    # its S rung, never a row of a full-S group
+                    reads.flush(blocks=1)
+                    data, lengths = _lay_subblock(row, n, k, s_len)
+                    self._encode_write(codec, data, writers, write_quorum,
+                                       lengths=lengths, last=True,
+                                       subblock=True)
+                    return total
                 # short last block: the last row of the group of the
                 # whole blocks before it — one encode, one fan-out
                 lengths = _lay_short_block(buf, nb, n, k, s_len)
@@ -729,11 +748,12 @@ class ErasureObjects:
         return total
 
     def _fused_encode(self, codec: Codec, data: np.ndarray, fut=None,
-                      lengths=None):
+                      lengths=None, subblock: bool = False):
         """(parity, digests) of one plain batch off the device — from
         its future, the shared batch former, or the codec itself when
         the engine runs without a former — or None (local CPU path).
-        `lengths`: of a group that ends in a short block."""
+        `lengths`: of a group that ends in a short block; `subblock`:
+        the one block of a body under one block, at its S rung."""
         if fut is not None:
             # check: allow(deadline) device dispatch; scheduler close() flushes waiters
             return fut.result()
@@ -741,9 +761,11 @@ class ErasureObjects:
             # the cross-request scheduler coalesces concurrent PUT
             # streams into shared dispatches
             return self.scheduler.encode_and_hash(
-                codec, data, self.bitrot_algo, lengths=lengths)
+                codec, data, self.bitrot_algo, lengths=lengths,
+                subblock=subblock)
         return codec.encode_and_hash_batch(data, self.bitrot_algo,
-                                           lengths=lengths)
+                                           lengths=lengths,
+                                           subblock=subblock)
 
     def _unpack_fused(self, codec: Codec, data: np.ndarray, fused,
                       ciphertext: bool = False, lengths=None
@@ -802,15 +824,20 @@ class ErasureObjects:
 
     def _encode_write(self, codec: Codec, data: np.ndarray, writers,
                       write_quorum: int, sse=None, sse_off: int = 0,
-                      lengths=None, last: bool = False) -> None:
+                      lengths=None, last: bool = False,
+                      subblock: bool = False) -> None:
         """Encode+digest one (B, k, S) batch and fan the framed shard
         writes out — data rows go to the writers as views of `data`.
         With `sse`, the batch rows are PLAINTEXT full blocks starting
         at stream offset `sse_off` and the cipher fuses in (or falls
         back to the in-place CPU cipher). `lengths`: of a plain group
-        that ends in a short block (`_lay_short_block`). `last`: the
-        stream's last group (`_write_shards_batch`)."""
-        with telemetry.span("pipeline.encode", blocks=data.shape[0]):
+        that ends in a short block (`_lay_short_block`), or of the one
+        block of a body under one block (`subblock`, laid at its S
+        rung by `_lay_subblock`). `last`: the stream's last group
+        (`_write_shards_batch`)."""
+        attrs = {"subblock": 1, "S": data.shape[2]} if subblock else {}
+        with telemetry.span("pipeline.encode", blocks=data.shape[0],
+                            **attrs):
             if sse is not None:
                 item = {"sse_kn": sse.batch_params(
                     sse_off, data.shape[0], self.block_size),
@@ -826,7 +853,8 @@ class ErasureObjects:
                 # program, one round-trip)
                 data_rows, parity, dd, dp = self._unpack_fused(
                     codec, data,
-                    self._fused_encode(codec, data, lengths=lengths),
+                    self._fused_encode(codec, data, lengths=lengths,
+                                       subblock=subblock),
                     lengths=lengths)
         with telemetry.span("pipeline.shard_write"):
             self._write_shards_batch(data_rows, parity, dd, dp, writers,
@@ -2479,26 +2507,45 @@ def _read_full(reader, n: int) -> bytes:
     return buf
 
 
+def _split_into(shards: np.ndarray, block: np.ndarray, s_t: int) -> None:
+    """`block` laid into the zeroed (k, S) `shards` as `Codec.split`
+    lays it: shard i = bytes [i*S_t, (i+1)*S_t) of the block in the
+    first S_t columns of row i, zero everywhere else."""
+    whole, rest = divmod(len(block), s_t)
+    shards[:whole, :s_t] = block[:whole * s_t].reshape(whole, s_t)
+    if rest:
+        shards[whole, :rest] = block[whole * s_t:]
+
+
 def _lay_short_block(buf: np.ndarray, row: int, n: int, k: int,
                      s_len: int) -> np.ndarray:
     """The short last block of a stream, read into the first n bytes of
     buf[row] (a (k * s_len,) row of a staging buffer), laid out in
-    place as `Codec.split` lays it — shard i = bytes [i*S_t, (i+1)*S_t)
-    of the block, S_t = ceil(n / k), in the first S_t columns of row
-    i of the (k, s_len) view, zero everywhere else — so that it is one
-    more block of its group at the full shard length. -> the group's
-    (row + 1,) shard lengths: s_len for the whole blocks, S_t last."""
+    place (`_split_into`, S_t = ceil(n / k)) in the (k, s_len) view of
+    the row, so that it is one more block of its group at the full
+    shard length. -> the group's (row + 1,) shard lengths: s_len for
+    the whole blocks, S_t last."""
     s_t = -(-n // k)
     block = buf[row, :n].copy()
     buf[row] = 0
-    shards = buf[row].reshape(k, s_len)
-    whole, rest = divmod(n, s_t)
-    shards[:whole, :s_t] = block[:whole * s_t].reshape(whole, s_t)
-    if rest:
-        shards[whole, :rest] = block[whole * s_t:]
+    _split_into(buf[row].reshape(k, s_len), block, s_t)
     lengths = np.full(row + 1, s_len, np.int32)
     lengths[row] = s_t
     return lengths
+
+
+def _lay_subblock(row: np.ndarray, n: int, k: int,
+                  s_len: int) -> tuple[np.ndarray, np.ndarray]:
+    """A body of n bytes, under one block, read into row[:n]: its one
+    block laid (`_split_into`, S_t = ceil(n / k)) at its S rung S_r
+    (parallel/ladder.s_rung) instead of the full `s_len`. -> (data
+    (1, k, S_r), lengths [S_t]). The frames written are those of the
+    full-S layout: 32 + S_t bytes a shard."""
+    from ..parallel import ladder
+    s_t = -(-n // k)
+    data = np.zeros((1, k, ladder.s_rung(s_len, s_t)), np.uint8)
+    _split_into(data[0], row[:n], s_t)
+    return data, np.array([s_t], np.int32)
 
 
 def _read_full_into(reader, view: np.ndarray) -> int:

@@ -26,6 +26,16 @@ former's cap (`scheduler.max_batch`) — never written down beside them:
     it unpadded).
 
 Defaults (group 8, cap 32): 1 2 4 6 8 12 16 20 24 32.
+
+An object whose whole body is under one block is one short block, and
+launches at its S RUNG (`s_rungs`: a few shard lengths below the full
+block's, derived from the GF kernel's tile), never in a launch of
+full-block S: a 40 KiB body rides a (B, 12, 16384) launch, not a
+(B, 12, 349526) one. Below the full S a pad block costs at most a
+quarter of a full-S one, so such a launch's B ladder is coarser
+(`subblock_rungs`: every power of two up to the cap). Those programs
+load on demand, behind the first such launch the device takes
+(`load_encode_ragged`), never at boot.
 """
 
 from __future__ import annotations
@@ -69,6 +79,29 @@ def rungs(group: int, cap: int) -> tuple[int, ...]:
     return tuple(sorted(out))
 
 
+@functools.lru_cache(maxsize=None)
+def s_rungs(shard_size: int) -> tuple[int, ...]:
+    """The shard lengths a launch of objects under one block runs at
+    (its S rungs): the GF kernel's lane tile (`rs_pallas._TS`, which
+    every S is padded up to on the device anyway) times each power of
+    four below the geometry's full-block `shard_size`, then that S —
+    at most 4x the columns of the smallest rung a block fits. Every
+    rung is a multiple of the hash's 32-byte packet and of the link
+    form's word."""
+    from ..ops import rs_pallas
+    out, s = [], rs_pallas._TS
+    while s < shard_size:
+        out.append(s)
+        s *= 4
+    return (*out, shard_size)
+
+
+def s_rung(shard_size: int, s_t: int) -> int:
+    """The S rung of a block of shard length `s_t`: the smallest one
+    that holds it."""
+    return next(r for r in s_rungs(shard_size) if r >= s_t)
+
+
 def group_of(verb: str) -> int:
     """Blocks a stream submits at a time, by verb."""
     from ..object import engine, healing
@@ -77,30 +110,45 @@ def group_of(verb: str) -> int:
             "recover": healing.HEAL_BATCH_BLOCKS}[verb]
 
 
-def rungs_of(verb: str, cap: int = 0) -> tuple[int, ...]:
+@functools.lru_cache(maxsize=None)
+def subblock_rungs(cap: int) -> tuple[int, ...]:
+    """The launch sizes of objects under one block at an S rung below
+    the full S: 1 and every power of two up to the cap, and the cap."""
+    cap = max(cap, 1)
+    return tuple(sorted({cap} | {1 << i for i in range(cap.bit_length())
+                                 if 1 << i <= cap}))
+
+
+def rungs_of(verb: str, cap: int = 0,
+             subblock: bool = False) -> tuple[int, ...]:
+    """A verb's launch sizes; `subblock`: of launches at an S rung
+    below the full S."""
     if not cap:
         from . import scheduler
         cap = scheduler.MAX_BATCH_BLOCKS
-    return rungs(group_of(verb), cap)
+    return subblock_rungs(cap) if subblock else rungs(group_of(verb), cap)
 
 
-def rung(verb: str, blocks: int, cap: int = 0) -> int:
+def rung(verb: str, blocks: int, cap: int = 0,
+         subblock: bool = False) -> int:
     """The B a launch of `blocks` blocks runs at. A lone group larger
     than the cap (the former splits between groups, never inside one)
     rounds up to a multiple of the verb's group."""
-    ladder = rungs_of(verb, cap)
+    ladder = rungs_of(verb, cap, subblock)
     if blocks > ladder[-1]:
         g = group_of(verb)
         return -(-blocks // g) * g
     return next(r for r in ladder if r >= blocks)
 
 
-def launch_size(verb: str, rows: int, blocks=None) -> tuple[int, int]:
+def launch_size(verb: str, rows: int, blocks=None,
+                subblock: bool = False) -> tuple[int, int]:
     """(real blocks, the B the step runs at) of a launch over an array
     of `rows` blocks: the array is padded to its rung already when the
     caller says how many of its rows are real (`blocks`: the batch
     former's staging buffer), and is brought up to it otherwise."""
-    return (rows, rung(verb, rows)) if blocks is None else (blocks, rows)
+    return (rows, rung(verb, rows, 0, subblock)) if blocks is None \
+        else (blocks, rows)
 
 
 def pad_blocks(arr: np.ndarray, to: int) -> np.ndarray:
@@ -143,8 +191,8 @@ def _listen() -> None:
 def _load_one(parent, codec, blocks: int, algo,
               ragged: bool = False) -> dict:
     """One encode rung's program, through the codec's own jitted
-    entry point: the step at B = `blocks` (`ragged`: the step of a
-    launch that carries short blocks)."""
+    entry point: the step at B = `blocks` and the codec's S (`ragged`:
+    the step of a launch that carries short blocks)."""
     verb = "encode"
     with telemetry.span("boot.load_program", parent=parent, verb=verb,
                         B=blocks, S=codec.shard_size) as sp:
@@ -171,7 +219,8 @@ def _load_one(parent, codec, blocks: int, algo,
 
 def load_encode(codec, algo, cap: int = 0,
                 workers: int = LOAD_WORKERS, ragged: bool = False,
-                trigger: str = "boot", demand=None) -> list[dict]:
+                trigger: str = "boot", demand=None,
+                s: int = 0) -> list[dict]:
     """Lower and compile — from the persistent compile cache when it
     is warm — every encode rung of `codec`'s geometry at its
     full-block S, `workers` at a time, largest first. -> one record a
@@ -179,17 +228,24 @@ def load_encode(codec, algo, cap: int = 0,
     programs are not these) nothing is loaded. `ragged`: the rungs of
     the row a launch with a short block runs (`load_encode_ragged`
     asks), with a `demand`: the rungs launches are waiting for go
-    first, and each is told as its program is in."""
+    first, and each is told as its program is in; `s`: at that S rung
+    instead (objects under one block: `subblock_rungs`)."""
     from ..object.codec import _device_is_tpu, _mesh_active
     if not _device_is_tpu() or _mesh_active() is not None \
             or codec.m == 0 or codec._device_hash_kernel(algo) is None:
         return []
     _listen()
-    ladder = rungs_of("encode", cap)
+    s = s or codec.shard_size
+    ladder = rungs_of("encode", cap, s < codec.shard_size)
+    if s != codec.shard_size:
+        # the programs at an S rung: those of the geometry's codec at
+        # that shard length
+        from ..object.codec import Codec
+        codec = Codec(codec.k, codec.m, s * codec.k)
     row = "encode_and_hash_batch" + (".ragged" if ragged else "")
     with telemetry.span("boot.load_programs", verb="encode", row=row,
                         trigger=trigger, k=codec.k, m=codec.m,
-                        S=codec.shard_size, programs=len(ladder),
+                        S=s, programs=len(ladder),
                         workers=workers) as sp:
         todo = sorted(ladder, reverse=True)
         mu = threading.Lock()
@@ -237,26 +293,32 @@ class _Demand:
             self.events[blocks].wait()
 
 
-# geometry + algorithm -> its ragged rungs' load: made by the first
-# launch with a short block that routes to the device, never at boot
+# geometry + S + algorithm -> the load of its ragged rungs: made by the
+# first launch with a short block at that S that routes to the device,
+# never at boot
 _RAGGED: dict[tuple, _Demand] = {}
 _RAGGED_MU = threading.Lock()
 
 
-def load_encode_ragged(codec, algo, cap: int = 0, want: int = 0) -> bool:
-    """Start, ONCE a geometry and a process, the load of the rungs of
+def load_encode_ragged(codec, algo, cap: int = 0, want: int = 0,
+                       s: int = 0) -> bool:
+    """Start, ONCE a geometry, S and process, the load of the rungs of
     the encode row that carries short blocks — in the background, on
     the boot-load pool: the codec calls this when a launch with a
     short block first routes to the device, so a store whose short
     blocks never reach it (or that stores none) never pays for them
     (ROADMAP A6 i: the rule for every program boot does not need).
-    `want`: the rung the launch that asks is about to wait for.
+    `want`: the rung the launch that asks is about to wait for; `s`:
+    the launch's S rung when it carries objects under one block (0:
+    the full block's; `trigger=first_subblock` below it).
     -> whether this call started it."""
-    key = (codec.k, codec.m, codec.shard_size, algo.value)
+    s = s or codec.shard_size
+    sub = s < codec.shard_size
+    key = (codec.k, codec.m, s, algo.value)
     with _RAGGED_MU:
         if key in _RAGGED:
             return False
-        demand = _RAGGED[key] = _Demand(rungs_of("encode", cap))
+        demand = _RAGGED[key] = _Demand(rungs_of("encode", cap, sub))
         demand.wanted.add(want)
 
     def run() -> None:
@@ -264,7 +326,8 @@ def load_encode_ragged(codec, algo, cap: int = 0, want: int = 0) -> bool:
             # a trace of its own: no request's, and boot's is closed
             with telemetry.trace("node.load_programs"):
                 load_encode(codec, algo, cap, ragged=True,
-                            trigger="first_short_block", demand=demand)
+                            trigger="first_subblock" if sub
+                            else "first_short_block", demand=demand, s=s)
         finally:
             for b in demand.events:
                 demand.met(b)
@@ -273,15 +336,15 @@ def load_encode_ragged(codec, algo, cap: int = 0, want: int = 0) -> bool:
     return True
 
 
-def await_ragged(codec, algo, blocks: int) -> None:
-    """A ragged launch at rung `blocks` whose program is still loading
-    waits for that load — which it moves to the head of the loader's
-    queue — as any jit call waits for its compile: a second compile of
-    the same program beside the loader's would take as long and twice
-    the cores. Nothing to wait for when no load was started (an engine
-    without a former) or the rung is off the ladder (it compiles in
-    the call)."""
+def await_ragged(codec, algo, blocks: int, s: int = 0) -> None:
+    """A ragged launch at rung `blocks` (and S rung `s`; 0: the full
+    block's) whose program is still loading waits for that load —
+    which it moves to the head of the loader's queue — as any jit call
+    waits for its compile: a second compile of the same program beside
+    the loader's would take as long and twice the cores. Nothing to
+    wait for when no load was started (an engine without a former) or
+    the rung is off the ladder (it compiles in the call)."""
     demand = _RAGGED.get(
-        (codec.k, codec.m, codec.shard_size, algo.value))
+        (codec.k, codec.m, s or codec.shard_size, algo.value))
     if demand is not None:
         demand.wait(blocks)

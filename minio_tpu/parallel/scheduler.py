@@ -65,7 +65,7 @@ from typing import Optional
 
 import numpy as np
 
-from ..object.codec import FUSED, Codec
+from ..object.codec import FUSED, Codec, subblock_on_device
 from ..utils import eventlog, knobs, lockcheck, telemetry
 from . import ladder
 
@@ -104,7 +104,13 @@ _COALESCED_TOTAL = telemetry.REGISTRY.counter(
 _SHORT_BLOCKS_TOTAL = telemetry.REGISTRY.counter(
     "minio_tpu_encode_short_blocks_total",
     "Short last blocks of objects that rode an encode launch on the "
-    "device, in the group of their object's whole blocks")
+    "device: in the group of their object's whole blocks, or, for an "
+    "object under one block, at its S rung")
+_SUBBLOCK_BLOCKS_TOTAL = telemetry.REGISTRY.counter(
+    "minio_tpu_encode_subblock_blocks_total",
+    "Objects under one block handed to the batch former, by route: "
+    "device (a launch at their S rung) or host (their S rung is the "
+    "host's, or the device declined)")
 _RAGGED_LAUNCHES_TOTAL = telemetry.REGISTRY.counter(
     "minio_tpu_encode_ragged_launches_total",
     "Encode launches on the device that carried a short block (the "
@@ -252,7 +258,11 @@ class BatchScheduler:
         # pad_bytes: zeros (pad blocks, and a short block's columns
         # past its own length); ragged_batches: device launches that
         # carried short_blocks short blocks of short_shard_bytes shard
-        # bytes in all (the encode verb alone has them)
+        # bytes in all (the encode verb alone has them); of objects
+        # under one block: subblock_blocks handed to the former,
+        # subblock_device_blocks of them launched on the device, in
+        # subblock_launches launches at their S rungs (they are short
+        # blocks too)
         self.verb_stats = {v: {"groups": 0, "batches": 0, "coalesced": 0,
                                "blocks": 0, "pad_blocks": 0,
                                "cpu_routed": 0, "errors": 0,
@@ -260,7 +270,10 @@ class BatchScheduler:
                                "fetch_seconds": 0.0,
                                "uploaded_bytes": 0, "pad_bytes": 0,
                                "ragged_batches": 0, "short_blocks": 0,
-                               "short_shard_bytes": 0}
+                               "short_shard_bytes": 0,
+                               "subblock_blocks": 0,
+                               "subblock_device_blocks": 0,
+                               "subblock_launches": 0}
                            for v in VERBS}
         # stage attribution (queue/transfer/compute/fetch histograms +
         # per-dispatch child spans); `off` is the overhead-A/B escape
@@ -350,7 +363,7 @@ class BatchScheduler:
 
     def _enqueue(self, entry: str, codec, data: np.ndarray, algo,
                  static: tuple = (), row_arrays: tuple = (),
-                 lengths=None) -> DispatchFuture:
+                 lengths=None, subblock: bool = False) -> DispatchFuture:
         """One (B, k, S) group for the fused program the Codec method
         `entry` enters (codec.FUSED), with that method's static
         arguments and the per-row arrays that ride beside the data.
@@ -359,15 +372,19 @@ class BatchScheduler:
         under different keys, coalesce into one launch. `lengths`
         (encode): each block's own shard length, of a group that ends
         in a short block — the group arrives at the full S, so the key
-        is that of a whole group and the two fuse."""
+        is that of a whole group and the two fuse. `subblock`: objects
+        under one block at their S rung — the key's last element is
+        the geometry's full S (0 for every other group), so they fuse
+        with each other at their rung and never with a full-S group."""
         if self._declined(codec, algo):
             return DispatchFuture()
         if lengths is not None:
             lengths = np.ascontiguousarray(lengths, np.int32)
-            if not (lengths < data.shape[-1]).any():
+            if not subblock and not (lengths < data.shape[-1]).any():
                 lengths = None
         key = (FUSED[entry].verb, entry, codec.k, codec.m, data.shape[-1],
-               algo.value, static, tuple(a.shape[1:] for a in row_arrays))
+               algo.value, static, tuple(a.shape[1:] for a in row_arrays),
+               codec.shard_size if subblock else 0)
         return self._enqueue_pending(key, _Pending(
             np.ascontiguousarray(data, np.uint8),
             payload=tuple(np.ascontiguousarray(a, np.uint32)
@@ -386,7 +403,8 @@ class BatchScheduler:
         return DispatchFuture(p)
 
     def submit(self, codec, data: np.ndarray, algo,
-               sse=None, lengths=None) -> DispatchFuture:
+               sse=None, lengths=None, subblock: bool = False
+               ) -> DispatchFuture:
         """Non-blocking fused encode+digest dispatch: enqueue the
         (B, k, S) group on the batch former and return immediately. The
         future resolves to (parity (B, m, S), digests (B, k+m, 32)) —
@@ -406,10 +424,29 @@ class BatchScheduler:
         first lengths[b] columns of its rows, zero beyond): the group
         still arrives at the full S and fuses with whole groups; the
         digests cover lengths[b] bytes a row and the caller keeps
-        parity[b, :, :lengths[b]] (codec.encode_and_hash_batch)."""
+        parity[b, :, :lengths[b]] (codec.encode_and_hash_batch).
+
+        subblock: every block is an object under one block, laid at the
+        S rung `data.shape[2]` (parallel/ladder.s_rungs) with its
+        `lengths`. Such groups coalesce at their rung alone; a rung the
+        host wins at (codec.subblock_on_device) goes to the host here,
+        with no grace wait."""
+        if subblock:
+            nb = int(data.shape[0])
+            host = not subblock_on_device(data.shape[-1]) \
+                or self._declined(codec, algo)
+            with self._mu:
+                vs = self.verb_stats["encode"]
+                vs["subblock_blocks"] += nb
+                if host:
+                    vs["groups"] += 1
+                    vs["cpu_routed"] += 1
+            if host:
+                _SUBBLOCK_BLOCKS_TOTAL.inc(nb, route="host")
+                return DispatchFuture()
         if sse is None:
             return self._enqueue("encode_and_hash_batch", codec, data, algo,
-                                 lengths=lengths)
+                                 lengths=lengths, subblock=subblock)
         keys, nonces, pkg_bytes = sse
         return self._enqueue("encrypt_encode_and_hash_batch", codec, data,
                              algo, (pkg_bytes,), (keys, nonces))
@@ -463,12 +500,13 @@ class BatchScheduler:
         return self._enqueue_pending(key, p)
 
     def encode_and_hash(self, codec, data: np.ndarray, algo, sse=None,
-                        lengths=None
+                        lengths=None, subblock: bool = False
                         ) -> Optional[tuple[np.ndarray, np.ndarray]]:
         """Blocking fused encode+digest via the shared batch former
-        (submit + wait); `sse` and `lengths` as in submit()."""
-        return self.submit(codec, data, algo, sse=sse,
-                           lengths=lengths).result()
+        (submit + wait); `sse`, `lengths` and `subblock` as in
+        submit()."""
+        return self.submit(codec, data, algo, sse=sse, lengths=lengths,
+                           subblock=subblock).result()
 
     # -- collector ---------------------------------------------------------
 
@@ -596,6 +634,8 @@ class BatchScheduler:
         staged = fetched = pad = uploaded = pad_bytes = 0
         short: list[int] = []       # shard lengths of the short blocks
         nb = sum(p.blocks for p in group)
+        # objects under one block at their S rung (the key's last element)
+        sub = verb != "scan" and bool(key[8])
         # what moved, on the erasure stages' spans
         stage_attrs: dict[str, dict] = {}
         if verb == "scan":
@@ -620,15 +660,18 @@ class BatchScheduler:
                               if isinstance(a, np.ndarray)) \
                     * (nb + pad) // nb
             k, s = key[2], key[4]
+            # every block of a sub-block launch is short, even one whose
+            # shard length is its S rung's
             short = [int(n) for p in group if p.lengths is not None
-                     for n in p.lengths[p.lengths < s]]
+                     for n in (p.lengths if sub
+                               else p.lengths[p.lengths < s])]
             uploaded = (nb + pad) * k * s
             pad_bytes = k * (pad * s + len(short) * s - sum(short))
             stage_attrs = {
                 "transfer": {"groups": len(group), "bytes": staged,
                              "rung": nb + pad, "pad_blocks": pad,
                              "short_blocks": len(short),
-                             "pad_bytes": pad_bytes},
+                             "pad_bytes": pad_bytes, "S": s},
                 "compute": {"ragged": int(bool(short))},
                 "fetch": {"bytes": fetched, **said.get("fetch", {})}}
         t1_ns = time.perf_counter_ns()
@@ -656,8 +699,13 @@ class BatchScheduler:
                 vs["ragged_batches"] += bool(short)
                 vs["short_blocks"] += len(short)
                 vs["short_shard_bytes"] += sum(short)
+                if sub:
+                    vs["subblock_device_blocks"] += nb
+                    vs["subblock_launches"] += 1
             else:
                 vs["cpu_routed"] += 1
+        if sub:
+            _SUBBLOCK_BLOCKS_TOTAL.inc(nb, route="device" if ran else "host")
         if ran:
             _BATCHES_TOTAL.inc(verb=verb)
             if len(group) > 1:
@@ -730,7 +778,9 @@ class BatchScheduler:
         its batches itself and is handed them unpadded."""
         t0 = time.perf_counter()
         buf = None
-        rung = ladder.rung(key[0], nb, self.max_batch) \
+        # a launch at an S rung below the full S pads on its own ladder
+        rung = ladder.rung(key[0], nb, self.max_batch,
+                           0 < key[4] < key[8]) \
             if _mesh_dp() == 1 else nb
         if len(group) == 1 and rung == nb:
             data, staged = group[0].data, 0
@@ -761,7 +811,7 @@ class BatchScheduler:
     def _run_codec(key: tuple, group: list, data: np.ndarray, nb: int,
                    stage_cb=None):
         from .. import bitrot as bitrot_mod
-        _verb, entry, k, m, s, algo_value, static, _shapes = key
+        _verb, entry, k, m, s, algo_value, static, _shapes, full = key
         # per-row arrays concatenate across the group exactly like the
         # shard data does, and go where the program's method takes them
         arrays = tuple(cols[0] if len(cols) == 1 else np.concatenate(cols)
@@ -775,9 +825,13 @@ class BatchScheduler:
             ragged["lengths"] = np.concatenate(
                 [np.full(p.blocks, s, np.int32) if p.lengths is None
                  else p.lengths for p in group])
+        if full:
+            # objects under one block at their S rung: the geometry's
+            # codec (its full S) is told so
+            ragged["subblock"] = True
         # the method is looked up on the codec NOW: a wrapper planted
         # on the class (a fault, a test) is the one that runs
-        return getattr(Codec(k, m, s * k), entry)(
+        return getattr(Codec(k, m, (full or s) * k), entry)(
             data, *static[:at], *arrays, *static[at:],
             bitrot_mod.BitrotAlgorithm.from_string(algo_value),
             stage_cb=stage_cb, blocks=nb, **ragged)

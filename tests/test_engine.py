@@ -522,7 +522,9 @@ def test_object_ending_in_a_short_block_matches_the_reference(
     device route (a former, XLA-CPU standing in for the chip): every
     drive's part file is the plain reference's byte for byte, a GET
     returns the body, and the short block rode the group of the whole
-    blocks before it - one submission a group of at most 8 blocks."""
+    blocks before it - one submission a group of at most 8 blocks. A
+    body under one block is one block at its S rung, which the device
+    takes here at every rung."""
     import glob
 
     from minio_tpu.object import codec as codec_mod
@@ -534,6 +536,7 @@ def test_object_ending_in_a_short_block_matches_the_reference(
     if route == "device":
         monkeypatch.setattr(codec_mod, "_device_is_tpu", lambda: True)
         monkeypatch.setattr(codec_mod, "DEVICE_MIN_BYTES", 0)
+        monkeypatch.setattr(codec_mod, "SUBBLOCK_HOST_MAX_S", 0)
         sched = BatchScheduler(max_wait=0.001)
     nbytes = _SIZES[size]
     body = payload(nbytes, seed=nbytes)
@@ -561,8 +564,9 @@ def test_object_ending_in_a_short_block_matches_the_reference(
         s_t = -(-(nbytes % BLOCK) // K)
         assert st["groups"] == -(-blocks // 8) and st["errors"] == 0
         assert st["blocks"] == blocks and st["cpu_routed"] == 0
-        # bs-1 is a short block whose shard length is the full one
-        short = int(0 < s_t < BLOCK // K)
+        # bs-1's shard length is the full one: a body under one
+        # block, short all the same
+        short = int(0 < s_t < BLOCK // K or nbytes < BLOCK)
         assert (st["short_blocks"], st["short_shard_bytes"]) \
             == (short, short * s_t)
         assert st["ragged_batches"] == short

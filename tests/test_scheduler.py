@@ -44,7 +44,10 @@ def device_codec(monkeypatch):
 
 
 def test_scheduler_coalesces_concurrent_streams(device_codec):
-    sched = BatchScheduler(max_batch=64, max_wait=0.05)
+    # the cap is the six streams' 12 blocks and the grace window longer
+    # than any thread start: the bucket dispatches the moment the last
+    # stream is in, however late the threads start on a loaded host
+    sched = BatchScheduler(max_batch=12, max_wait=60)
     codec = Codec(4, 2, 4 * 512)
     rng = np.random.default_rng(0)
     inputs = [rng.integers(0, 256, (2, 4, 512), dtype=np.uint8)
