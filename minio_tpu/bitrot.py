@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import enum
 import hashlib
-from typing import Protocol
+from typing import Protocol, Sequence
 
 import numpy as np
 
@@ -145,6 +145,24 @@ def hash_shards_batch(shards: np.ndarray,
     out = np.zeros((shards.shape[0], algo.digest_size), dtype=np.uint8)
     for i in range(shards.shape[0]):
         out[i] = np.frombuffer(hash_shard(shards[i], algo), dtype=np.uint8)
+    return out
+
+
+def hash_rows(rows: Sequence[np.ndarray],
+              algo: BitrotAlgorithm = DEFAULT_BITROT_ALGORITHM
+              ) -> np.ndarray:
+    """hash_shards_batch's digests of n equal-length shards, each hashed
+    where it lies -> (n, digest_size): no (n, L) array is stacked first.
+    One native call for HighwayHash; a per-row loop otherwise."""
+    rows = [np.ascontiguousarray(r, np.uint8).reshape(-1) for r in rows]
+    if algo in (BitrotAlgorithm.HIGHWAYHASH256,
+                BitrotAlgorithm.HIGHWAYHASH256S):
+        from .utils import native
+        if native.rows_available():
+            return native.hh256_rows(MAGIC_HIGHWAYHASH_KEY, rows)
+    out = np.zeros((len(rows), algo.digest_size), dtype=np.uint8)
+    for i, r in enumerate(rows):
+        out[i] = np.frombuffer(hash_shard(r, algo), dtype=np.uint8)
     return out
 
 

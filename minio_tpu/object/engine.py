@@ -31,7 +31,7 @@ import numpy as np
 
 from .. import bitrot as bitrot_mod
 from ..storage import errors as serr
-from ..utils import crashpoint, healthtrack, knobs, telemetry
+from ..utils import crashpoint, healthtrack, knobs, native, telemetry
 from ..storage.api import StorageAPI
 from ..storage.datatypes import (BLOCK_SIZE_V1, RESTORE_EXPIRY_KEY,
                                  RESTORE_KEY, TRANSITION_COMPLETE,
@@ -109,7 +109,28 @@ _PUT_CLOSE_FANOUTS = telemetry.REGISTRY.counter(
     "fan-out of their own: no group was known to be the last (a 0-byte "
     "object, or a stream of unknown length that ended on a group)")
 
+# where a degraded GET's decode launch input was gathered: a buffer
+# of the geometry's staging ring, or a fresh one when the ring was empty
+_GET_GATHERS = telemetry.REGISTRY.counter(
+    "minio_tpu_get_gather_total",
+    "Degraded-GET decode launch inputs gathered, by buffer: pooled (the "
+    "geometry's staging ring) or fresh (the ring was empty)")
+
 _GET_STREAMS = None
+
+
+def _gather_rows(rows: list, out: np.ndarray) -> None:
+    """Copy equal-length shard rows, each from where the read left it,
+    into the consecutive rows of the flat array `out`: one native call
+    (the interpreter lock released once, not once a row), or a row at a
+    time without the library."""
+    if native.rows_available() and all(r.flags.c_contiguous
+                                       for r in rows):
+        native.gather_rows(rows, out)
+        return
+    out = out.reshape(len(rows), -1)
+    for i, r in enumerate(rows):
+        out[i] = r
 
 
 def _get_streams_counter():
@@ -1463,18 +1484,25 @@ class ErasureObjects:
                                       reader_gen: Optional[tuple]
                                       = None,
                                       benign_missing: frozenset
-                                      = frozenset()) -> bool:
+                                      = frozenset(),
+                                      span: Optional[telemetry.Span]
+                                      = None) -> bool:
         """Verify deferred frame digests AND reconstruct the degraded
         blocks of a read group. Degraded blocks sharing one
         (present-mask, shard-length) pattern go through a single fused
-        verify+decode device dispatch (models/pipeline.get_step); shards
-        the fused program didn't cover batch-verify in one host call. A
-        digest mismatch (rare) drops the corrupt shard's reader and
-        re-reads the affected block with inline verification. Group
-        entries are [b, off, blen, shard_len, shards, digests] lists,
-        mutated in place. Returns True when any block needed
-        reconstruction or had bitrot."""
+        verify+decode device dispatch (models/pipeline.get_step), their
+        survivors copied once into a buffer of the geometry's staging
+        ring; shards the fused program didn't cover are hashed where
+        the read left them, in one host call. A digest mismatch (rare)
+        drops the corrupt shard's reader and re-reads the affected
+        block with inline verification. Group entries are [b, off,
+        blen, shard_len, shards, digests] lists, mutated in place.
+        `span` (the caller's `pipeline.verify_decode`) is told the
+        bytes gathered for launches and whether every gather was
+        pooled. Returns True when any block needed reconstruction or
+        had bitrot."""
         from ..ops import rs_matrix
+        from ..parallel import pipeline as pl
         heal = False
         corrupt: set[int] = set()
         if io_lock is None:
@@ -1505,76 +1533,119 @@ class ErasureObjects:
         # same-pattern buckets (same former key -> one fused launch)
         # instead of opening only after the previous bucket resolved
         staged: list[tuple] = []
-        for (mask, shard_len), idxs in buckets.items():
-            # a reconstruct forced by the READ PLAN (quarantine skip /
-            # latency-hedge loser) is not damage: the shards are on
-            # disk, nothing needs healing — only a miss the plan can't
-            # account for flags the degraded-read heal
-            if not {i for i in range(k)
-                    if not (mask >> i) & 1} <= benign_missing:
-                heal = True
-            _dm, used, _missing = rs_matrix.missing_data_matrix(
-                k, codec.m, mask)
-            stacked = np.stack([
-                np.stack([group[gi][4][u] for u in used])
-                for gi in idxs])                       # (G', k, S)
-            # fuse hashing only when digests were actually deferred;
-            # inline-verified shards need just the decode matmul
-            want_fused = any(group[gi][5][u] is not None
-                             for gi in idxs for u in used)
-            fut = None
-            if want_fused and self.scheduler is not None:
-                fut = self.scheduler.submit_decode(
-                    codec, stacked, mask, shard_len, algo)
-            staged.append((mask, shard_len, idxs, used, stacked,
-                           want_fused, fut))
-        for mask, shard_len, idxs, used, stacked, want_fused, fut \
-                in staged:
-            if fut is not None:
-                try:
-                    with telemetry.span("get.decode_wait"):
-                        # check: allow(deadline) device dispatch; scheduler close() flushes waiters
-                        fused = fut.result()
-                except Exception:  # noqa: BLE001 — a shared-dispatch
-                    # failure must not kill a GET the host can still
-                    # serve: fall back to the local decode + step-2
-                    # host verification of the deferred digests
+        # one ring a geometry, shared with its PUTs' staging (same
+        # width): each side writes every byte it reads back
+        ring = pl.staging_pool(GET_BATCH_BLOCKS * k * codec.shard_size) \
+            if buckets else None
+        gathered, fresh = 0, 0
+        held: dict[int, bytearray] = {}   # ring buffers not yet back
+        try:
+            for (mask, shard_len), idxs in buckets.items():
+                # a reconstruct forced by the READ PLAN (quarantine
+                # skip / latency-hedge loser) is not damage: the shards
+                # are on disk, nothing needs healing — only a miss the
+                # plan can't account for flags the degraded-read heal
+                if not {i for i in range(k)
+                        if not (mask >> i) & 1} <= benign_missing:
+                    heal = True
+                _dm, used, _missing = rs_matrix.missing_data_matrix(
+                    k, codec.m, mask)
+                # (G', k, S): each survivor frame copied once, into its
+                # row of a ring buffer (a short bucket: the buffer's
+                # head); a GET never waits for the ring — when it is
+                # empty the input is a fresh array
+                size = len(idxs) * k * shard_len
+                buf = ring.try_get() if size <= ring.width else None
+                if buf is None:
+                    fresh += 1
+                    stacked = np.empty(size, dtype=np.uint8)
+                else:
+                    held[id(buf)] = buf
+                    stacked = np.frombuffer(buf, dtype=np.uint8,
+                                            count=size)
+                _gather_rows([group[gi][4][u] for gi in idxs
+                              for u in used], stacked)
+                stacked = stacked.reshape(len(idxs), k, shard_len)
+                gathered += size
+                _GET_GATHERS.inc(
+                    buffer="fresh" if buf is None else "pooled")
+                # fuse hashing only when digests were actually deferred;
+                # inline-verified shards need just the decode matmul
+                want_fused = any(group[gi][5][u] is not None
+                                 for gi in idxs for u in used)
+                fut = None
+                if want_fused and self.scheduler is not None:
+                    fut = self.scheduler.submit_decode(
+                        codec, stacked, mask, shard_len, algo)
+                staged.append((mask, shard_len, idxs, used, stacked,
+                               buf, want_fused, fut))
+            if span is not None:
+                span.attrs["gathered_bytes"] = gathered
+                if gathered:
+                    span.attrs["pooled"] = int(not fresh)
+            for mask, shard_len, idxs, used, stacked, buf, want_fused, \
+                    fut in staged:
+                if fut is not None:
+                    try:
+                        with telemetry.span("get.decode_wait"):
+                            # check: allow(deadline) device dispatch; scheduler close() flushes waiters
+                            fused = fut.result()
+                    except Exception:  # noqa: BLE001 — a shared-
+                        # dispatch failure must not kill a GET the host
+                        # can still serve: fall back to the local decode
+                        # + step-2 host verification of the deferred
+                        # digests
+                        fused = None
+                        # the failed launch may still read its input:
+                        # left in `held`, the buffer never goes back
+                        buf = None
+                elif want_fused:
+                    fused = codec.verify_and_decode_batch(
+                        stacked, mask, shard_len, algo)
+                else:
                     fused = None
-            elif want_fused:
-                fused = codec.verify_and_decode_batch(
-                    stacked, mask, shard_len, algo)
-            else:
-                fused = None
-            if fused is not None:
-                out, missing_idx, sdig = fused
-                for row, gi in enumerate(idxs):
-                    shards, digests = group[gi][4], group[gi][5]
-                    bad = False
-                    for col, u in enumerate(used):
-                        exp = digests[u]
-                        if exp is None:
-                            continue
-                        if sdig[row, col].tobytes() != exp:
-                            shards[u] = None
-                            drop_reader(u)
-                            bad = True
+                if fused is not None:
+                    out, missing_idx, sdig = fused
+                    for row, gi in enumerate(idxs):
+                        shards, digests = group[gi][4], group[gi][5]
+                        bad = False
+                        for col, u in enumerate(used):
+                            exp = digests[u]
+                            if exp is None:
+                                continue
+                            if sdig[row, col].tobytes() != exp:
+                                shards[u] = None
+                                drop_reader(u)
+                                bad = True
+                            else:
+                                digests[u] = None  # verified on device
+                        if bad:
+                            corrupt.add(gi)
                         else:
-                            digests[u] = None  # verified on device
-                    if bad:
-                        corrupt.add(gi)
-                    else:
-                        for r_i, mi in enumerate(missing_idx):
+                            for r_i, mi in enumerate(missing_idx):
+                                shards[mi] = out[row][r_i]
+                else:
+                    out, idxs_rows = codec.recover_stacked(
+                        stacked, mask, set(range(k)))
+                    for row, gi in enumerate(idxs):
+                        shards = group[gi][4]
+                        for r_i, mi in enumerate(idxs_rows):
                             shards[mi] = out[row][r_i]
-            else:
-                out, idxs_rows = codec.recover_stacked(
-                    stacked, mask, set(range(k)))
-                for row, gi in enumerate(idxs):
-                    shards = group[gi][4]
-                    for r_i, mi in enumerate(idxs_rows):
-                        shards[mi] = out[row][r_i]
+                if buf is not None:
+                    # the launch (or the host decode) has returned:
+                    # nothing reads the input again — the rebuilt rows
+                    # above are views of the fetched result, never of
+                    # this buffer
+                    ring.put(held.pop(id(buf)))
+        finally:
+            # what a failed launch or a raise on the way left checked
+            # out: the ring may allocate others in their place
+            for _ in held:
+                ring.discard()
 
-        # 2) batch-verify every shard the fused program didn't cover
-        #    (healthy blocks, hedged extras, CPU-routed buckets)
+        # 2) verify every shard the fused program didn't cover
+        #    (healthy blocks, hedged extras, CPU-routed buckets), each
+        #    hashed where the read left it
         pend: dict[int, list[tuple[int, int]]] = {}
         for gi, entry in enumerate(group):
             if gi in corrupt:
@@ -1584,13 +1655,17 @@ class ErasureObjects:
                 if digests[i] is not None and shards[i] is not None:
                     pend.setdefault(len(shards[i]), []).append((gi, i))
         if pend:
-            with telemetry.span(
+            with telemetry.timed(
                     "get.host_verify",
                     shards=sum(len(it) for it in pend.values()),
-                    bytes=sum(sl * len(it) for sl, it in pend.items())):
+                    bytes=sum(sl * len(it) for sl, it in pend.items())) \
+                    as hv:
+                copied = 0
                 for _sl, items in pend.items():
-                    stacked = np.stack([group[gi][4][i] for gi, i in items])
-                    got = bitrot_mod.hash_shards_batch(stacked, algo)
+                    rows = [group[gi][4][i] for gi, i in items]
+                    copied += sum(r.nbytes for r in rows
+                                  if not r.flags.c_contiguous)
+                    got = bitrot_mod.hash_rows(rows, algo)
                     for row, (gi, i) in enumerate(items):
                         if got[row].tobytes() != group[gi][5][i]:
                             group[gi][4][i] = None
@@ -1598,6 +1673,7 @@ class ErasureObjects:
                             corrupt.add(gi)
                         else:
                             group[gi][5][i] = None
+                hv.annotate(copied_bytes=copied)
 
         # 3) corrupt blocks (bitrot found after deferral): re-read with
         #    inline verification and host reconstruct — the corrupt
@@ -2448,14 +2524,14 @@ class _PartReadPlan:
                 group.append([b, block_off, block_len, shard_len,
                               shards, digests])
             with telemetry.span("pipeline.verify_decode",
-                                blocks=len(blocks)):
+                                blocks=len(blocks)) as vd:
                 if self.eng._verify_and_reconstruct_group(
                         self.codec, group, k, n, readers,
                         self.shard_size,
                         self.part_algo or self.eng.bitrot_algo,
                         io_lock=self.io_lock,
                         reader_gen=(self.reader_gen, gen_at_read),
-                        benign_missing=benign):
+                        benign_missing=benign, span=vd):
                     self.heal_required = True
             with telemetry.span("get.join"):
                 out = []

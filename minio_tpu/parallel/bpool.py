@@ -57,6 +57,25 @@ class BytePool:
                 f"no {self.width}-byte staging buffer freed within "
                 f"{timeout}s (capacity {self.capacity})") from None
 
+    def try_get(self) -> Optional[bytearray]:
+        """A pooled buffer, or None when every one is checked out: for a
+        caller that would rather allocate than wait."""
+        try:
+            return self._q.get_nowait()
+        except queue.Empty:
+            pass
+        with self._mu:
+            if self._created < self.capacity:
+                self._created += 1
+                return bytearray(self.width)
+        return None
+
+    def discard(self) -> None:
+        """A checked-out buffer will not come back (something may still
+        read it): the ring may allocate another in its place."""
+        with self._mu:
+            self._created = max(self._created - 1, 0)
+
     def put(self, buf: bytearray) -> None:
         if len(buf) != self.width:
             # a foreign-width buffer returned here would poison a later

@@ -61,6 +61,19 @@ def get_lib() -> ctypes.CDLL | None:
         lib.hh256_batch.restype = None
         lib.hh256_batch.argtypes = [u8p, u8p, ctypes.c_size_t,
                                     ctypes.c_size_t, ctypes.c_size_t, u8p]
+        # absent in a stale .so that make could not rebuild: the
+        # callers then take a row at a time
+        vpp = ctypes.POINTER(ctypes.c_void_p)
+        try:
+            lib.hh256_rows.restype = None
+            lib.hh256_rows.argtypes = [u8p, vpp, ctypes.c_size_t,
+                                       ctypes.c_size_t, u8p]
+            lib.gather_rows.restype = None
+            lib.gather_rows.argtypes = [vpp, ctypes.c_size_t,
+                                        ctypes.c_size_t, u8p]
+            lib.rows_ok = True
+        except AttributeError:
+            lib.rows_ok = False
         lib.hh_init.restype = None
         lib.hh_init.argtypes = [u8p, u8p]
         lib.hh_update_packets.restype = None
@@ -137,6 +150,56 @@ def hh256_batch(key: bytes, shards: np.ndarray) -> np.ndarray:
     lib.hh256_batch(_u8p(k), _u8p(shards), n, length, shards.strides[0],
                     _u8p(out))
     return out
+
+
+def rows_available() -> bool:
+    """hh256_rows and gather_rows are in the loaded library."""
+    lib = get_lib()
+    return bool(lib and getattr(lib, "rows_ok", False))
+
+
+def _row_pointers(rows: list, size: int):
+    """The addresses of n equal-length 1-D C-contiguous uint8 arrays
+    (read-only `bytes`-backed views included), for one native call."""
+    for r in rows:
+        if r.dtype != np.uint8 or r.ndim != 1 or r.size != size \
+                or not r.flags.c_contiguous:
+            raise ValueError("rows must be 1-D contiguous uint8 of one "
+                             "length")
+    return (ctypes.c_void_p * max(len(rows), 1))(
+        *(r.ctypes.data for r in rows))
+
+
+def hh256_rows(key: bytes, rows: list) -> np.ndarray:
+    """Hash n equal-length rows, each where it lies -> (n, 32); one
+    native call, the interpreter lock released once for all of them."""
+    lib = get_lib()
+    assert lib is not None and lib.rows_ok
+    n = len(rows)
+    size = rows[0].size if n else 0
+    ptrs = _row_pointers(rows, size)
+    k = np.frombuffer(key, dtype=np.uint8)
+    out = np.zeros((n, 32), dtype=np.uint8)
+    if n:
+        lib.hh256_rows(_u8p(k), ptrs, n, size, _u8p(out))
+    return out
+
+
+def gather_rows(rows: list, out: np.ndarray) -> None:
+    """Copy n equal-length rows, each from where it lies, into the
+    consecutive rows of the C-contiguous uint8 array `out` (n·size
+    bytes); one native call, the interpreter lock released once."""
+    lib = get_lib()
+    assert lib is not None and lib.rows_ok
+    n = len(rows)
+    size = rows[0].size if n else 0
+    if out.dtype != np.uint8 or not out.flags.c_contiguous \
+            or not out.flags.writeable or out.size != n * size:
+        raise ValueError("out must be a writable contiguous uint8 array "
+                         "of n·size bytes")
+    ptrs = _row_pointers(rows, size)
+    if n:
+        lib.gather_rows(ptrs, n, size, _u8p(out))
 
 
 def gf_matmul(matrix: np.ndarray, data: np.ndarray,

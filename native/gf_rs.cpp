@@ -198,6 +198,16 @@ void gf_matmul(const uint8_t* matrix, size_t r, size_t k,
 
 int gf_has_gfni() { return g_has_gfni ? 1 : 0; }
 
+// Gather n buffers of `size` bytes, each at its own address (rows[j]),
+// into consecutive rows of out (n x size): a decode launch's survivor
+// input assembled in ONE call, the interpreter lock released once.
+void gather_rows(const uint8_t* const* rows, size_t n, size_t size,
+                 uint8_t* out) {
+  for (size_t j = 0; j < n; ++j) {
+    std::memcpy(out + j * size, rows[j], size);
+  }
+}
+
 uint8_t gf_mul_one(uint8_t a, uint8_t b) { return g_mul[a][b]; }
 
 }  // extern "C"

@@ -267,6 +267,16 @@ void hh256_batch(const uint8_t* key32, const uint8_t* data, size_t n,
   }
 }
 
+// Digests of n buffers of equal length `size`, each at its own address
+// (rows[j]); out = n x 32 bytes. One FFI call hashes shards where a
+// reader left them, with no copy into a contiguous (n, size) array.
+void hh256_rows(const uint8_t* key32, const uint8_t* const* rows, size_t n,
+                size_t size, uint8_t* out) {
+  for (size_t j = 0; j < n; ++j) {
+    hh256(key32, rows[j], size, out + j * 32);
+  }
+}
+
 // Streaming interface: caller owns an opaque 128-byte state blob.
 void hh_init(const uint8_t* key32, uint8_t* state128) {
   uint64_t key[4];

@@ -66,6 +66,16 @@ for i in range(5):
     h = HighwayHash(key); h.update(shards[i].tobytes())
     assert got[i].tobytes() == h.digest256(), f"batch row {i}"
 
+# rows by address (each its own bytes-backed buffer, odd length)
+rows = [np.frombuffer(rng.integers(0, 256, 1029, dtype=np.uint8).tobytes(),
+                      dtype=np.uint8) for _ in range(3)]
+got = native.hh256_rows(key, rows)
+for i, row in enumerate(rows):
+    assert got[i].tobytes() == native.hh256(key, row), f"rows row {i}"
+out = np.zeros(3 * 1029, dtype=np.uint8)
+native.gather_rows(rows, out)
+assert np.array_equal(out.reshape(3, 1029), np.stack(rows)), "gather"
+
 # streaming API consistency with single-shot (state layout: 128 bytes,
 # update size in bytes — see bitrot._NativeHH256)
 lib = native.get_lib()
